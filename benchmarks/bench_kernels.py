@@ -139,8 +139,8 @@ def _measured(quick: bool):
     cache_len = P * ps
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, Hq, D)).astype(jnp.bfloat16)
-    kf = jax.random.normal(ks[1], (PT, ps, Hkv, D), jnp.float32)
-    vf = jax.random.normal(ks[2], (PT, ps, Hkv, D), jnp.float32)
+    kf = jax.random.normal(ks[1], (PT, Hkv, ps, D), jnp.float32)
+    vf = jax.random.normal(ks[2], (PT, Hkv, ps, D), jnp.float32)
     kq, ksc = quantize_fp8(kf)
     vq, vsc = quantize_fp8(vf)
     kv, sc = jnp.stack([kq, vq]), jnp.stack([ksc, vsc])
@@ -176,7 +176,7 @@ def _measured(quick: bool):
     }
     us_lane = _time(kern, False)
     us_vis = _time(kern, True)
-    if ops.INTERPRET:
+    if ops.interpret_mode():
         # emulator timings: recorded for completeness, never as kernel
         # wall-clock, never with a throughput number
         out["kernel"] = {
@@ -256,8 +256,6 @@ def main(argv=None):
                     help="committed BENCH_kernels.json to gate analytic "
                          "bytes/token columns against (>5%% fails)")
     args = ap.parse_args(argv)
-    from repro.kernels import ops
-    ops.configure_for_backend()
     _, out = run(quick=args.quick)
     if args.compare_baseline:
         return compare_baseline(out, args.compare_baseline)

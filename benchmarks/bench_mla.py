@@ -34,8 +34,7 @@ def run(quick: bool = False):
     out = {"arch": ARCH + "-reduced", "mode": "coopt",
            "note": ("CPU container: kernel path runs in Pallas interpret "
                     "mode (emulated) — compare hbm_bytes_per_call, not "
-                    "wall-clock; on TPU configure_for_backend() compiles "
-                    "the kernels."),
+                    "wall-clock; on TPU the kernels run compiled."),
            "serve": {}}
     from repro.kernels import ops
     for label, uk in (("jnp", False), ("kernel", True)):
@@ -45,7 +44,7 @@ def run(quick: bool = False):
         out["serve"][label] = {k: r[k] for k in SERVE_KEYS}
         # wall-clock honesty: interpret-mode kernel timings are emulator
         # timings, never comparable to the compiled jnp path
-        out["serve"][label]["timing"] = ("interpret" if uk and ops.INTERPRET
+        out["serve"][label]["timing"] = ("interpret" if uk and ops.interpret_mode()
                                          else "compiled-xla")
         print(f"bench_mla serve[{label}]: "
               f"{r['throughput_tok_s']} tok/s, "

@@ -169,8 +169,8 @@ def run(quick: bool = False):
     PT = B * P                    # global pool, lane-identity partitioned
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, Hq, D)).astype(jnp.bfloat16)
-    kf = jax.random.normal(ks[1], (PT, ps, Hkv, D), jnp.float32)
-    vf = jax.random.normal(ks[2], (PT, ps, Hkv, D), jnp.float32)
+    kf = jax.random.normal(ks[1], (PT, Hkv, ps, D), jnp.float32)
+    vf = jax.random.normal(ks[2], (PT, Hkv, ps, D), jnp.float32)
     cl = jnp.full((B,), cache_len, jnp.int32)
     phys = identity_page_table(B, PT)
     log = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None], (B, P))

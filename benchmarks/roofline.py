@@ -17,7 +17,7 @@ import os
 from typing import Dict, List, Optional
 
 from repro.configs import ARCH_IDS, SHAPES, get_config
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import TARGET_DEVICE_KIND, chip_peaks
 
 from benchmarks.common import RESULTS_DIR, write_csv
 
@@ -55,9 +55,10 @@ def analyze(rec: Dict) -> Optional[Dict]:
     flops = rec["cost"].get("flops", 0.0)
     mem_bytes = rec["cost"].get("bytes accessed", 0.0)
     coll = rec.get("collective_bytes", 0.0)
-    t_c = flops / PEAK_FLOPS_BF16
-    t_m = mem_bytes / HBM_BW
-    t_x = coll / ICI_BW
+    pk = chip_peaks(TARGET_DEVICE_KIND)
+    t_c = flops / pk.bf16_flops
+    t_m = mem_bytes / pk.hbm_bw
+    t_x = coll / pk.ici_bw
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     dom = max(terms, key=terms.get)
     mf = model_flops(rec["arch"], rec["shape"])

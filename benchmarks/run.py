@@ -24,10 +24,8 @@ def main(argv=None):
                     help="comma-separated subset of " + ",".join(ALL))
     args = ap.parse_args(argv)
     which = args.only.split(",") if args.only else list(ALL)
-
-    # Pallas kernels run compiled on TPU, interpret-mode elsewhere
-    from repro.kernels import ops
-    ops.configure_for_backend()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     t0 = time.time()
     failures = []

@@ -12,9 +12,9 @@ Lineage: the paged kernels (PRs 3-5) share three load-bearing conventions:
     dereferences a table without clamping (``jnp.maximum(phys[b, s], 0)``)
     turns ``-1`` into a wrap-around DMA of the pool's LAST page — exactly
     the PR 5 slot-wrap incident class, where an unhandled sentinel let a
-    write land on a live pool line. (The write kernel instead pre-maps
-    ``-1`` to a reserved sentinel line before the call; its index_maps
-    carry inline allows citing that.)
+    write land on a live pool line. (The write kernel instead clamps its
+    page indices before the call; its index_maps carry inline allows
+    citing that.)
   * Every block named by the specs is resident in VMEM (~16 MiB/core),
     double-buffered, alongside the scratch accumulators. The estimator
     below computes worst-case residency from the BlockSpec shapes and

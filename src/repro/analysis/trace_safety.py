@@ -5,9 +5,9 @@ retraces at serve time (``warmup()`` pre-compiles every bucketed shape);
 that guarantee only holds if jitted impls never read state that mutates
 between traces — a closed-over mutable ``self`` attribute or a module
 global silently bakes its TRACE-TIME value into the cached executable
-(the ``ops.INTERPRET`` flag is the canonical hazard: it is flipped by
-``configure_for_backend()`` AFTER import, so a jitted body that reads it
-directly freezes whichever value import-time happened to see). (2) PR 4
+(the retired ``ops.INTERPRET`` flag was the canonical hazard: it was
+flipped by a launcher AFTER import, so a jitted body that read it directly
+froze whichever value import-time happened to see). (2) PR 4
 replaced the ``jnp.take`` full-pool gather in the MLA decode path with
 paged Pallas kernels precisely because a full-pool gather materialises
 the ENTIRE KV pool per step — re-introducing one inside ``kernels/``

@@ -3,7 +3,7 @@ spill tier (host-side, pure Python).
 
 XLA wants static shapes, so the device cache is ONE preallocated paged pool
 shared by every sequence (``repro.core.opt_kv.make_layer_cache`` / model
-``init_cache`` — leaves shaped ``(2, P_total, ps, Hkv, D)`` with no batch
+``init_cache`` — leaves shaped ``(2, P_total, Hkv, ps, D)`` with no batch
 dimension) and all dynamic paging happens here as *indices*: each sequence
 owns a logical-ordered list of physical pages; token slot =
 page_table[pos // ps] * ps + pos % ps, a *global* flat slot.
@@ -106,9 +106,9 @@ def shard_page_ranges(num_pages: int,
     mirror of the device pages-axis sharding. Splits like
     ``np.array_split``: the first ``num_pages % num_shards`` shards get one
     extra page. When the device pool is ``padded_pool_pages`` wide and the
-    final page is reserved (write-kernel SkipSet sentinel), the usable
+    final page is reserved (see ``Scheduler``), the usable
     ``num_pages = P_dev - 1`` splits so every boundary coincides with a
-    device shard boundary and only the LAST shard loses the sentinel page.
+    device shard boundary and only the LAST shard loses the reserved page.
     """
     s = max(int(num_shards), 1)
     base, rem = divmod(num_pages, s)
@@ -245,7 +245,7 @@ class BlockManager:
 
     Preferred construction is a resolved ``CacheConfig`` (``num_pages``
     here is the USABLE device page count — the caller has already padded
-    the pool and reserved the write sentinel); the legacy
+    the pool and reserved its final page); the legacy
     ``BlockManager(num_pages, page_size, ...)`` positional form keeps
     working as a deprecation shim.
     """

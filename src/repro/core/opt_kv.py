@@ -14,7 +14,11 @@ shard_map layer, per shard of a GSPMD mesh; this module is the
 numerically-identical jnp parity reference used by tests.
 
 Cache layout (one layer) — GLOBAL POOL, no batch dimension:
-    kv (2, P_total, ps, Hkv, D) + scale (2, P_total, ps, Hkv).
+    kv (2, P_total, Hkv, ps, D) + scale (2, P_total, Hkv, ps).
+Heads come before tokens within a page, so one head's page is a (ps, D)
+tile: the block the Pallas kernels DMA (Mosaic needs the last two block
+dims to be multiples of (8, 128) or whole). Flat slot ``page * ps + off``
+names row ``off`` of page ``page`` in every head.
 All sequences share the pool; the host-side ``BlockManager`` hands each
 sequence a disjoint set of pages (refcounted, prefix-cache shareable) and the
 per-step batch carries *global* flat slot indices and per-lane page tables.
@@ -24,9 +28,9 @@ construction), so lane isolation needs no device-side masking.
 Direct (non-engine) callers get a static lane-identity layout: pool =
 ``batch * pages(max_len)`` pages, lane b owning the contiguous range
 ``[b * P_lane, (b+1) * P_lane)`` — see ``identity_page_table`` /
-``identity_slots``. When the Pallas write kernel is used, the pool's very
-last cache line doubles as the SkipSet sentinel; the engine's BlockManager
-never allocates the final page, so skipped tokens land in reserved space.
+``identity_slots``. Skipped tokens are not written anywhere. The engine's
+BlockManager still never allocates the pool's last page, which once took
+the write kernel's skipped tokens and no writer needs any more.
 """
 from __future__ import annotations
 
@@ -62,8 +66,8 @@ def pool_layout(batch: int, max_len: int, coopt, num_shards: int = 1,
     BlockManager must agree on: ``P`` is the requested pool size —
     ``CacheConfig.num_pages`` when set, else ``batch * pages(max_len)`` —
     padded so the pages axis tiles evenly over the KV shards. (The engine
-    reserves the final padded page as the write kernel's SkipSet sentinel,
-    so the host allocator sees ``P - 1`` usable pages.)"""
+    reserves the final padded page, so the host allocator sees ``P - 1``
+    usable pages.)"""
     ps = coopt.page_size
     pages = 0
     if cache_cfg is not None:
@@ -73,6 +77,23 @@ def pool_layout(batch: int, max_len: int, coopt, num_shards: int = 1,
     if not pages:
         pages = batch * (-(-max_len // ps))
     return padded_pool_pages(pages, num_shards), ps
+
+
+def kv_pool_shapes(num_layers: int, batch: int, max_len: int,
+                   num_kv_heads: int, head_dim: int, coopt, num_shards: int = 1,
+                   cache_cfg=None):
+    """Cache-shape entries ``{"kv", "scale"}`` — (shape, dtype, logical
+    axes) — of a paged K/V pool over ``num_layers`` layers: THE pool layout
+    every model's ``cache_shape`` uses. ``scale`` is present under
+    Opt-KV."""
+    P, ps = pool_layout(batch, max_len, coopt, num_shards, cache_cfg)
+    L, H, D = num_layers, num_kv_heads, head_dim
+    out = {"kv": ((L, 2, P, H, ps, D), coopt.kv_dtype,
+                  ("layers", None, "pages", "kv_heads", None, "head_dim"))}
+    if coopt.opt_kv:
+        out["scale"] = ((L, 2, P, H, ps), jnp.float32,
+                        ("layers", None, "pages", "kv_heads", None))
+    return out
 
 
 def global_to_local_pages(phys_table, first_page, num_local: int):
@@ -93,8 +114,7 @@ def global_to_local_slots(slot_idx, first_slot, num_local: int):
     num_local)`` slot range (or already -1 / SkipSet) become ``num_local`` —
     one PAST the shard's last line, so a ``mode='drop'`` scatter discards
     them as out of bounds (Eq. 5 semantics per shard). -1 would WRAP to the
-    shard's last line (only the global pool reserves a sentinel there; a
-    mid-pool shard's last line is live data)."""
+    shard's last line, which holds live data."""
     local = slot_idx - first_slot
     owned = (slot_idx >= 0) & (local >= 0) & (local < num_local)
     return jnp.where(owned, local, num_local).astype(jnp.int32)
@@ -103,10 +123,26 @@ def global_to_local_slots(slot_idx, first_slot, num_local: int):
 def make_layer_cache(num_pages: int, page_size: int, num_kv_heads: int,
                      head_dim: int, coopt: CoOptConfig):
     """Zero-initialised single-layer GLOBAL paged cache (kv, scale|None)."""
-    kv = jnp.zeros((2, num_pages, page_size, num_kv_heads, head_dim),
+    kv = jnp.zeros((2, num_pages, num_kv_heads, page_size, head_dim),
                    coopt.kv_dtype)
-    scale = (jnp.zeros((2, num_pages, page_size, num_kv_heads), jnp.float32)
+    scale = (jnp.zeros((2, num_pages, num_kv_heads, page_size), jnp.float32)
              if coopt.opt_kv else None)
+    return kv, scale
+
+
+def scatter_kv(kv, scale, vals, scl, slots):
+    """Scatter new tokens into one layer's pool: kv (2, P, H, ps, D), scale
+    (2, P, H, ps) | None; vals (2, B, S, H, D) in the pool dtype; scl
+    (2, B, S, H) | None; slots (B, S) flat ``page * ps + off``. Slots that
+    are negative or past the pool are dropped."""
+    _, P, _, ps, _ = kv.shape
+    page = jnp.where(slots < 0, P, slots // ps)
+    off = slots % ps
+    # advanced indices split by a slice put (B, S) first: (B, S, 2, H[, D])
+    kv = kv.at[:, page, :, off].set(jnp.moveaxis(vals, 0, 2), mode="drop")
+    if scale is not None:
+        scale = scale.at[:, page, :, off].set(jnp.moveaxis(scl, 0, 2),
+                                              mode="drop")
     return kv, scale
 
 
@@ -136,29 +172,22 @@ def identity_slots(batch: int, positions, total_pages: int,
 def write_kv(kv_cache, scale_cache, k_new, v_new, slot_idx, coopt: CoOptConfig):
     """Write new tokens' K/V into the global paged cache.
 
-    kv_cache: (2, P, ps, Hkv, D); k_new/v_new: (B, S, Hkv, D);
+    kv_cache: (2, P, Hkv, ps, D); k_new/v_new: (B, S, Hkv, D);
     slot_idx: (B, S) int32 — GLOBAL flat slot (= page * page_size + offset)
     in the shared pool; -1/SkipSet => skip. Returns updated
     (kv_cache, scale_cache).
     """
-    _, P, ps, H, D = kv_cache.shape
     if coopt.use_kernel:
         from repro.kernels import ops
         return ops.kv_cache_write(kv_cache, scale_cache, k_new, v_new,
                                   slot_idx, opt_kv=coopt.opt_kv)
-    flat = kv_cache.reshape(2, P * ps, H, D)
     new = jnp.stack([k_new, v_new])                      # (2,B,S,H,D)
-    clipped = jnp.where(slot_idx < 0, -1, slot_idx)      # keep skip sentinel
-
     if coopt.opt_kv:
         q, s = quantize_fp8(new, axis=-1)                # (2,B,S,H,D),(2,B,S,H)
-        flat = flat.at[:, clipped].set(q.astype(flat.dtype), mode="drop")
-        sflat = scale_cache.reshape(2, P * ps, H)
-        sflat = sflat.at[:, clipped].set(s, mode="drop")
-        scale_cache = sflat.reshape(2, P, ps, H)
-    else:
-        flat = flat.at[:, clipped].set(new.astype(flat.dtype), mode="drop")
-    return flat.reshape(2, P, ps, H, D), scale_cache
+        return scatter_kv(kv_cache, scale_cache, q.astype(kv_cache.dtype), s,
+                          slot_idx)
+    return scatter_kv(kv_cache, scale_cache, new.astype(kv_cache.dtype),
+                      None, slot_idx)
 
 
 def dequant_pages(kv_pages, scale_pages, coopt: CoOptConfig, dtype=jnp.bfloat16):
@@ -172,17 +201,18 @@ def gather_cached_kv(kv_cache, scale_cache, page_table, coopt: CoOptConfig,
                      dtype=jnp.bfloat16):
     """Reference of the paper's dedicated ``gather_cached_kv`` kernel.
 
-    kv_cache: (2, P, ps, Hkv, D) global pool; page_table: (B, Psel) int32
+    kv_cache: (2, P, Hkv, ps, D) global pool; page_table: (B, Psel) int32
     physical page ids in logical order (negative => zero page). Returns
     (2, B, Psel*ps, Hkv, D) dequantized — token j of the output is the lane's
     logical position j, so downstream masks index by position directly.
     """
-    _, P, ps, H, D = kv_cache.shape
+    _, P, H, ps, D = kv_cache.shape
     B, Psel = page_table.shape
     pt = jnp.maximum(page_table, 0)
-    gathered = jnp.take(kv_cache, pt, axis=1)            # (2,B,Psel,ps,H,D)
+    # (2,B,Psel,H,ps,D) -> token-major (2,B,Psel,ps,H,D)
+    gathered = jnp.take(kv_cache, pt, axis=1).swapaxes(3, 4)
     if coopt.opt_kv:
-        sg = jnp.take(scale_cache, pt, axis=1)
+        sg = jnp.take(scale_cache, pt, axis=1).swapaxes(3, 4)
         out = dequantize_fp8(gathered, sg, axis=-1, dtype=dtype)
     else:
         out = gathered.astype(dtype)

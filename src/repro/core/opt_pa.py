@@ -1,7 +1,7 @@
 """Opt-Pa — paged attention for long sequences (paper §3.3, Alg. 3).
 
 Decode-phase attention of ONE query token per lane against the GLOBAL paged
-KV pool: ``kv_pages (2, P_total, ps, Hkv, D)`` shared by every lane, with a
+KV pool: ``kv_pages (2, P_total, Hkv, ps, D)`` shared by every lane, with a
 per-lane ``page_table (B, P_lane)`` naming the lane's physical pages in
 logical order (-1 = unallocated). Lanes never alias pages they can write
 (refcounted pool, CoW prefix sharing), so the gather is race-free.
@@ -81,14 +81,14 @@ def paged_decode_attention(q, kv_pages, scale_pages, cache_len, *,
                            coopt: CoOptConfig, window: int = 0,
                            sink_pages: int = 1,
                            page_table: Optional[jax.Array] = None) -> jax.Array:
-    """q: (B, Hq, D); kv_pages: (2, P_total, ps, Hkv, D) global pool;
+    """q: (B, Hq, D); kv_pages: (2, P_total, Hkv, ps, D) global pool;
     cache_len: (B,) tokens valid per lane (the current token must already be
     written); page_table: (B, P_lane) physical pages in logical order
     (default: static lane-identity partition of the pool).
     Returns (B, Hq, D) in q.dtype.
     """
     B, Hq, D = q.shape
-    _, P_total, ps, Hkv, _ = kv_pages.shape
+    _, P_total, Hkv, ps, _ = kv_pages.shape
     if page_table is None:
         page_table = identity_page_table(B, P_total)
 
@@ -152,7 +152,7 @@ def paged_chunk_attention(q, kv_pages, scale_pages, positions, page_table,
     (byte-identical to the pre-packing math).
     Returns (B, S, Hq, D) in q.dtype."""
     B, S, Hq, D = q.shape
-    _, P_total, ps, Hkv, _ = kv_pages.shape
+    _, P_total, Hkv, ps, _ = kv_pages.shape
     if page_table is None:
         page_table = identity_page_table(B, P_total)
 
@@ -283,7 +283,7 @@ def _blockwise(q, kv_pages, scale_pages, cache_len, coopt, valid):
 def _windowed(q, kv_pages, scale_pages, cache_len, phys_table, logical_table,
               window, sink_pages, coopt):
     B, Hq, D = q.shape
-    _, P, ps, Hkv, _ = kv_pages.shape
+    ps = kv_pages.shape[3]
     flat = gather_cached_kv(kv_pages, scale_pages, phys_table, coopt)
     k, v = flat                                              # (B,Ts,H,D)
     pos = jnp.maximum(logical_table, 0)[:, :, None] * ps + \

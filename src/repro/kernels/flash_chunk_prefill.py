@@ -56,8 +56,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 _NEG = -1e30
 
 # VMEM-resident query-group row budget: sized so the group's q/out/state
@@ -69,26 +67,28 @@ RESIDENT_ROWS = 1024
 
 def resident_rows(R: int, G: int, cap: int = 0) -> int:
     """Rows per VMEM-resident query group: the largest divisor of ``R``
-    that is <= cap (default ``RESIDENT_ROWS``) and keeps a sequence row's G
-    grouped heads together. ``G`` always qualifies, so the search
-    terminates. The page re-stream factor of the chunk kernel is
-    ``R // resident_rows(R, G)``."""
+    that is <= cap (default ``RESIDENT_ROWS``), keeps a sequence row's G
+    grouped heads together, and is a multiple of 128 (the positions block
+    puts the rows in the lane place, where Mosaic needs 128-aligned blocks).
+    When no divisor qualifies the whole of ``R`` is one group. The page
+    re-stream factor of the chunk kernel is ``R // resident_rows(R, G)``."""
     rq = min(cap or RESIDENT_ROWS, R)
-    while R % rq or rq % G:
+    while rq and (R % rq or rq % G or rq % 128):
         rq -= 1
-    return rq
+    return rq or R
 
 
 def _chunk_kernel(phys_ref,                          # scalar prefetch
                   q_ref, pos_ref, k_ref, v_ref, ks_ref, vs_ref,
                   o_ref, *refs,
-                  ps: int, opt_kv: bool, window: int, sink: int,
+                  ps: int, rep: int, opt_kv: bool, window: int, sink: int,
                   num_pages: int, return_state: bool):
     if return_state:
         mo_ref, lo_ref, m_ref, l_ref, acc_ref = refs
     else:
         m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
+    kvh = pl.program_id(1) // rep
     j = pl.program_id(3)                             # page-table slot
     rq, D = q_ref.shape[2], q_ref.shape[3]
     page = phys_ref[0, b, j]                         # physical page to DMA
@@ -111,17 +111,13 @@ def _chunk_kernel(phys_ref,                          # scalar prefetch
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # (rq, D)
-        k = k_ref[0, :, 0, :]                        # (ps, D)
-        v = v_ref[0, :, 0, :]
-        if opt_kv:                                   # Eq. 6 fused dequant
-            k = k.astype(jnp.float32) * ks_ref[0].reshape(ps, 1)
-            v = v.astype(jnp.float32) * vs_ref[0].reshape(ps, 1)
-        else:
-            k = k.astype(jnp.float32)
-            v = v.astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)          # (ps, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * (1.0 / math.sqrt(D))                 # (rq, ps)
+        if opt_kv:                                   # Eq. 6 fused dequant
+            s = s * ks_ref[0, pl.ds(kvh, 1), :]      # per-key scale row
         kpos = base * ps + jax.lax.broadcasted_iota(jnp.int32, (rq, ps), 1)
         qp = jnp.broadcast_to(qpos[:, None], (rq, ps))
         mask = (kpos <= qp) & (qseg[:, None] == pseg)
@@ -136,8 +132,9 @@ def _chunk_kernel(phys_ref,                          # scalar prefetch
         # exp(s - m_new) alone would yield 1.0 while m_new is still _NEG
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         l_new = l_ref[:, 0:1] * corr + jnp.sum(p, -1, keepdims=True)
+        pv = p * vs_ref[0, pl.ds(kvh, 1), :] if opt_kv else p
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -156,11 +153,11 @@ def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
                         phys_table, *, opt_kv: bool, opt_gqa: bool = True,
                         window: int = 0, sink_pages: int = 0,
                         block_q: int = 0, return_state: bool = False,
-                        interpret: bool = True, seg_q=None, page_seg=None,
+                        interpret: bool = False, seg_q=None, page_seg=None,
                         page_base=None):
     """q: (B, S, Hq, D) chunk queries; positions: (B, S) absolute per-row
-    positions; k/v_pages: (P_total, ps, Hkv, D) GLOBAL pool [fp8 if opt_kv];
-    k/v_scale: (P_total, ps, Hkv) f32 or None; phys_table: (B, NP) int32
+    positions; k/v_pages: (P_total, Hkv, ps, D) GLOBAL pool [fp8 if opt_kv];
+    k/v_scale: (P_total, Hkv, ps) f32 or None; phys_table: (B, NP) int32
     physical pages in logical order (-1 = skip, never DMA'd). The chunk's
     own K/V must already be written to the pool. Returns (B, S, Hq, D); with
     ``return_state`` also the final online-softmax (m, l) as (B, S, Hq) f32
@@ -173,7 +170,7 @@ def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
     (key positions are ``page_base * ps + iota``). Defaults reproduce the
     unpacked layout exactly: one segment 0 per row, base == slot index."""
     B, S, Hq, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     NP = phys_table.shape[1]
     if seg_q is None:
         seg_q = jnp.zeros((B, S), jnp.int32)
@@ -183,12 +180,10 @@ def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
         page_base = jnp.broadcast_to(jnp.arange(NP, dtype=jnp.int32),
                                      (B, NP))
     if opt_gqa:
-        G = Hq // Hkv
-        heads, kv_of_head = Hkv, lambda h: h
+        G, heads, rep = Hq // Hkv, Hkv, 1
     else:
         # Original MHA semantics: every query head re-streams its KV head.
-        G = 1
-        heads, kv_of_head = Hq, lambda h: h // max(Hq // Hkv, 1)
+        G, heads, rep = 1, Hq, max(Hq // Hkv, 1)
     R = S * G
 
     # resident-group sizing: rows stay VMEM-resident across the whole inner
@@ -210,14 +205,14 @@ def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
                         page_seg.astype(jnp.int32)])              # (3, B, NP)
 
     if k_scale is None:
-        k_scale = jnp.zeros((P, ps, Hkv), jnp.float32)
+        k_scale = jnp.zeros((P, Hkv, ps), jnp.float32)
         v_scale = k_scale
 
     def kv_idx(b, h, i, j, phys):
-        return (jnp.maximum(phys[0, b, j], 0), 0, kv_of_head(h), 0)
+        return (jnp.maximum(phys[0, b, j], 0), h // rep, 0, 0)
 
     def sc_idx(b, h, i, j, phys):
-        return (jnp.maximum(phys[0, b, j], 0), 0, kv_of_head(h))
+        return (jnp.maximum(phys[0, b, j], 0), 0, 0)
 
     out_blk = pl.BlockSpec((1, 1, rq, D),
                            lambda b, h, i, j, phys: (b, h, i, 0))
@@ -230,7 +225,7 @@ def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
         out_shape += [jax.ShapeDtypeStruct((B, heads, R, 128),
                                            jnp.float32)] * 2
 
-    kern = functools.partial(_chunk_kernel, ps=ps, opt_kv=opt_kv,
+    kern = functools.partial(_chunk_kernel, ps=ps, rep=rep, opt_kv=opt_kv,
                              window=window, sink=sink_pages, num_pages=NP,
                              return_state=return_state)
     res = pl.pallas_call(
@@ -243,10 +238,10 @@ def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
                              lambda b, h, i, j, phys: (b, h, i, 0)),
                 pl.BlockSpec((1, 2, rq),
                              lambda b, h, i, j, phys: (b, 0, i)),
-                pl.BlockSpec((1, ps, 1, D), kv_idx),
-                pl.BlockSpec((1, ps, 1, D), kv_idx),
-                pl.BlockSpec((1, ps, 1), sc_idx),
-                pl.BlockSpec((1, ps, 1), sc_idx),
+                pl.BlockSpec((1, 1, ps, D), kv_idx),
+                pl.BlockSpec((1, 1, ps, D), kv_idx),
+                pl.BlockSpec((1, Hkv, ps), sc_idx),
+                pl.BlockSpec((1, Hkv, ps), sc_idx),
             ],
             out_specs=out_specs,
             scratch_shapes=[
@@ -256,7 +251,7 @@ def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
             ],
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 _NEG = -1e30
 
@@ -83,7 +82,7 @@ def _prefill_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 def flash_prefill(q, k, v, *, window: int = 0, block_q: int = 256,
                   block_k: int = 256, q_offset: int = 0,
-                  interpret: bool = True):
+                  interpret: bool = False):
     """q: (B, S, Hq, D); k, v: (B, T, Hkv, D). Causal (optionally windowed)
     grouped-query flash attention. Returns (B, S, Hq, D) in q.dtype."""
     B, S, Hq, D = q.shape
@@ -124,7 +123,7 @@ def flash_prefill(q, k, v, *, window: int = 0, block_q: int = 256,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

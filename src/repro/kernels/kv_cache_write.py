@@ -2,24 +2,24 @@
 scattering into the GLOBAL paged-KV pool.
 
 Scatters new tokens' K/V into the shared pool with (a) SkipSet filtering —
-tokens whose slot is negative are routed to a sentinel cache line and never
-touch live pages ("skip caching of K_i, V_i"; padding, prefix-cache hits),
-and (b) fused FP8 e4m3 quantization: amax-per-(token, head) scale computed in
-VREGs, quantized tile written in the same pass, so the unquantized K/V never
-round-trip to HBM.
+tokens whose slot is negative are never written ("skip caching of K_i,
+V_i"; padding, prefix-cache hits), and (b) fused FP8 e4m3 quantization:
+amax-per-(token, head) scale computed in VREGs, quantized row written in
+the same pass, so the unquantized K/V never round-trip to HBM.
 
-Mechanics: the GLOBAL flat slot index (B, S) is scalar-prefetched and
-dereferenced inside the output BlockSpec index_map — the line written by grid
-step (b, s) IS the cache line of lane b's token s (or the sentinel line for
-SkipSet tokens). Because the refcounted BlockManager hands lanes disjoint
-writable pages (shared prefix pages are read-only by construction), lanes
-never race on a line. The cache is passed aliased (donated), so unwritten
-lines keep their contents — this is the TPU analogue of an in-place scatter
-with ``mode='drop'``.
-
-Sentinel convention: the pool's very last cache line (flat slot NSlot-1) is
-reserved — the engine's BlockManager never allocates the final page, so the
-line only ever absorbs skipped tokens.
+Mechanics: the pool is head-major within a page, ``(2, P, Hkv, ps, D)``,
+so one token's line is a row of every head's (ps, D) tile — a block Mosaic
+can only move whole. The grid walks the B*S new tokens sorted by slot
+(stable, so a later write of the same slot still wins); the block at each
+step is the WHOLE page (k and v, all heads) that holds the token, named by
+the scalar-prefetched page index. The first step of a run of tokens in the
+same page copies the page in; each step then replaces its token's row in
+VMEM with a select on the row index; the page is written back once, when
+the run ends (Pallas writes an output block back when its block index
+changes). Sorting makes each page one run, so no page is read back after
+it was written. SkipSet tokens sort last and keep the last page; they write
+nothing. The pool is passed aliased (donated), so pages no token touches
+keep their contents.
 """
 from __future__ import annotations
 
@@ -33,78 +33,87 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.cache.quant import FP8_MAX
 
 
-def _write_kernel(slot_ref, k_ref, v_ref,
-                  kc_in, vc_in, ks_in, vs_in,          # aliased cache (unused)
-                  kc_ref, vc_ref, ks_ref, vs_ref,      # outputs
-                  *, opt_kv: bool):
-    # k_ref/v_ref: (1, 1, Hkv, D) — one token, all kv heads.
-    k = k_ref[0, 0].astype(jnp.float32)                 # (Hkv, D)
-    v = v_ref[0, 0].astype(jnp.float32)
-    if opt_kv:
-        k_amax = jnp.max(jnp.abs(k), axis=-1, keepdims=True)
-        v_amax = jnp.max(jnp.abs(v), axis=-1, keepdims=True)
-        k_s = jnp.maximum(k_amax, 1e-12) / FP8_MAX
-        v_s = jnp.maximum(v_amax, 1e-12) / FP8_MAX
-        kc_ref[0] = (k / k_s).astype(kc_ref.dtype)
-        vc_ref[0] = (v / v_s).astype(vc_ref.dtype)
-        ks_ref[0] = k_s[:, 0]
-        vs_ref[0] = v_s[:, 0]
-    else:
-        kc_ref[0] = k.astype(kc_ref.dtype)
-        vc_ref[0] = v.astype(vc_ref.dtype)
-        ks_ref[0] = jnp.zeros(ks_ref.shape[1:], jnp.float32)
-        vs_ref[0] = jnp.zeros(vs_ref.shape[1:], jnp.float32)
+def _write_kernel(page_ref, off_ref, tok_ref, new_ref, kv_in, sc_in,
+                  kv_ref, sc_ref, *, opt_kv: bool):
+    # new_ref: (2, 1, Hkv, D) — one token's k and v, all kv heads;
+    # kv_ref: (2, 1, Hkv, ps, D) — the page holding it; sc_ref (2, 1, Hkv, ps)
+    i = pl.program_id(0)
+
+    @pl.when((i == 0) | (page_ref[jnp.maximum(i - 1, 0)] != page_ref[i]))
+    def _load():                    # first token of a run: start from HBM
+        kv_ref[...] = kv_in[...]
+        sc_ref[...] = sc_in[...]
+
+    off = off_ref[i]
+
+    @pl.when(off >= 0)
+    def _write():
+        _, _, Hkv, ps, D = kv_ref.shape
+        row = jax.lax.broadcasted_iota(jnp.int32, (Hkv, ps, D), 1) == off
+        col = jax.lax.broadcasted_iota(jnp.int32, (Hkv, ps), 1) == off
+        for c in range(2):                              # k, then v
+            x = new_ref[c, 0].astype(jnp.float32)       # (Hkv, D)
+            if opt_kv:
+                amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
+                scale = jnp.maximum(amax, 1e-12) / FP8_MAX     # (Hkv, 1)
+                x = x / scale
+            else:
+                scale = jnp.zeros((Hkv, 1), jnp.float32)
+            page = kv_ref[c, 0].astype(jnp.float32)      # (Hkv, ps, D)
+            kv_ref[c, 0] = jnp.where(
+                row, jnp.broadcast_to(x[:, None, :], (Hkv, ps, D)),
+                page).astype(kv_ref.dtype)
+            sc_ref[c, 0] = jnp.where(
+                col, jnp.broadcast_to(scale, (Hkv, ps)), sc_ref[c, 0])
 
 
-def kv_cache_write(k_new, v_new, slot_idx, k_cache, v_cache, k_scale, v_scale,
-                   *, opt_kv: bool, interpret: bool = True):
+def kv_cache_write(k_new, v_new, slot_idx, kv_pages, kv_scale, *,
+                   opt_kv: bool, interpret: bool = False):
     """k/v_new: (B, S, Hkv, D); slot_idx: (B, S) int32 GLOBAL flat slots
-    (-1 / SkipSet => drop); k/v_cache: (NSlot, Hkv, D) flat GLOBAL pool whose
-    last line is the reserved sentinel; k/v_scale: (NSlot, Hkv) f32 (zeros ok
-    if !opt_kv). Returns updated (k_cache, v_cache, k_scale, v_scale)."""
+    (page * ps + offset; negative => SkipSet, not written); kv_pages:
+    (2, P, Hkv, ps, D) one layer's pool [fp8 if opt_kv]; kv_scale:
+    (2, P, Hkv, ps) f32 (zeros ok if !opt_kv). Returns the updated
+    (kv_pages, kv_scale)."""
     B, S, Hkv, D = k_new.shape
-    NS = k_cache.shape[0]          # includes the sentinel line
-    sentinel = NS - 1
-    slots = jnp.where(slot_idx < 0, sentinel, slot_idx).astype(jnp.int32)
+    _, P, _, ps, _ = kv_pages.shape
+    N = B * S
+    slots = slot_idx.reshape(N).astype(jnp.int32)
+    tok = jnp.argsort(jnp.where(slots >= 0, slots, jnp.iinfo(jnp.int32).max),
+                      stable=True).astype(jnp.int32)
+    slots = slots[tok]
+    # pages ascend along the sorted slots; the trailing SkipSet tokens keep
+    # the last page (page 0 when nothing is written)
+    page = jax.lax.cummax(jnp.maximum(slots, 0) // ps)
+    off = jnp.where(slots >= 0, slots % ps, -1)
+    new = jnp.stack([k_new, v_new]).reshape(2, N, Hkv, D)
 
-    # no jnp.maximum clamp needed: -1 slots were pre-mapped to the pool's
-    # reserved sentinel line (`slots = jnp.where(slot_idx < 0, sentinel,
-    # ...)` above), so -1 can never reach these index_maps
-    def cache_idx(b, s, slot):
-        return (slot[b, s], 0, 0)  # coopt: allow[COOPT005]
-
-    def scale_idx(b, s, slot):
-        return (slot[b, s], 0)  # coopt: allow[COOPT005]
-
+    # no -1 reaches these maps: pages are clamped to >= 0 above and ``tok``
+    # is a permutation of the token indices
+    kv_blk = pl.BlockSpec(
+        (2, 1, Hkv, ps, D),
+        lambda i, pg, of, tk: (0, pg[i], 0, 0, 0))  # coopt: allow[COOPT005]
+    sc_blk = pl.BlockSpec(
+        (2, 1, Hkv, ps),
+        lambda i, pg, of, tk: (0, pg[i], 0, 0))  # coopt: allow[COOPT005]
     kern = functools.partial(_write_kernel, opt_kv=opt_kv)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(B, S),
+            num_scalar_prefetch=3,
+            grid=(N,),
             in_specs=[
-                pl.BlockSpec((1, 1, Hkv, D), lambda b, s, slot: (b, s, 0, 0)),
-                pl.BlockSpec((1, 1, Hkv, D), lambda b, s, slot: (b, s, 0, 0)),
-                pl.BlockSpec((1, Hkv, D), cache_idx),
-                pl.BlockSpec((1, Hkv, D), cache_idx),
-                pl.BlockSpec((1, Hkv), scale_idx),
-                pl.BlockSpec((1, Hkv), scale_idx),
+                pl.BlockSpec(
+                    (2, 1, Hkv, D),
+                    lambda i, pg, of, tk: (0, tk[i], 0, 0)),  # coopt: allow[COOPT005]
+                kv_blk, sc_blk,
             ],
-            out_specs=[
-                pl.BlockSpec((1, Hkv, D), cache_idx),
-                pl.BlockSpec((1, Hkv, D), cache_idx),
-                pl.BlockSpec((1, Hkv), scale_idx),
-                pl.BlockSpec((1, Hkv), scale_idx),
-            ],
+            out_specs=[kv_blk, sc_blk],
         ),
-        out_shape=[
-            jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-            jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
-            jax.ShapeDtypeStruct(k_scale.shape, jnp.float32),
-            jax.ShapeDtypeStruct(v_scale.shape, jnp.float32),
-        ],
-        # aliased: unwritten cache lines keep their contents (scatter 'drop')
-        input_output_aliases={3: 0, 4: 1, 5: 2, 6: 3},
+        out_shape=[jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype),
+                   jax.ShapeDtypeStruct(kv_scale.shape, jnp.float32)],
+        # aliased: pages no token touches keep their contents
+        input_output_aliases={4: 0, 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(slots, k_new, v_new, k_cache, v_cache, k_scale, v_scale)
-    return out
+    )(page, off, tok, new, kv_pages, kv_scale)

@@ -48,7 +48,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 _NEG = -1e30
 
@@ -151,7 +150,7 @@ def latent_chunk_prefill(q_lat, q_rope, positions, lat_pages, scale_pages,
                          phys_table, *, sm_scale: float, opt_kv: bool,
                          window: int = 0, sink_pages: int = 0,
                          block_q: int = 0, return_state: bool = False,
-                         interpret: bool = True, seg_q=None, page_seg=None,
+                         interpret: bool = False, seg_q=None, page_seg=None,
                          page_base=None):
     """q_lat: (B, S, H, R) W_uk-absorbed chunk queries; q_rope: (B, S, H, dr);
     positions: (B, S) absolute per-row positions; lat_pages: (P_total, ps,
@@ -235,7 +234,7 @@ def latent_chunk_prefill(q_lat, q_rope, positions, lat_pages, scale_pages,
             ],
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(table3, qlf, qrf, pos_rep, lat_pages, scale_pages)

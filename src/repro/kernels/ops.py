@@ -1,13 +1,14 @@
 """jit'd public wrappers around the Pallas kernels + cache-layout adapters.
 
 The engine-facing cache layout is the GLOBAL paged pool — per-layer leaves
-``(2, P_total, ps, Hkv, D)`` with NO batch dimension, shared by every lane;
-these wrappers slice it into the kernels' (P_total, ps, Hkv, D) k/v views
-(zero-copy) and plug into ``repro.core`` when ``CoOptConfig.use_kernel``.
+``(2, P_total, Hkv, ps, D)`` with NO batch dimension, shared by every lane
+(heads before tokens within a page, so a head's page is one (ps, D) tile);
+these wrappers slice it into the kernels' (P_total, Hkv, ps, D) k/v views
+and plug into ``repro.core`` when ``CoOptConfig.use_kernel``.
 Lanes address the pool through scalar-prefetched page tables (physical page
 to DMA + logical page for positions) dereferenced inside BlockSpec
-index_maps, and the write path scatters to global flat slots (the pool's
-last cache line is the reserved SkipSet sentinel).
+index_maps, and the write path scatters to global flat slots (negative
+slots — the SkipSet — are not written).
 
 ONE hot path, single-host AND distributed: when a ``sharded.ShardCtx`` is
 installed (``set_mesh_ctx`` — the engine and ``launch.steps`` bind it at
@@ -17,10 +18,11 @@ their owned page range, partial softmax states are lse-merged across the
 pages axes, and writes stay shard-local. With no ctx (no mesh, or a mesh
 whose pages axes have extent 1) the single-device kernels run unchanged.
 
-On this container the kernels run in interpret mode (CPU); on TPU hardware
-``configure_for_backend()`` flips ``INTERPRET`` off — the launchers
-(``launch.serve.serve_workload``, ``launch.steps.make_step`` engine setup,
-``benchmarks.run``) call it at startup.
+Interpret mode is decided by the platform, at every call: the unjitted
+dispatchers below run the kernels compiled when JAX's default backend is a
+TPU and in the Pallas interpreter otherwise (``interpret_mode``). Nothing
+has to be configured first, so an ``Engine`` built by any script runs the
+compiled kernels on the chip.
 """
 from __future__ import annotations
 
@@ -40,17 +42,16 @@ from repro.kernels import paged_latent_decode as _ld
 from repro.kernels import sharded as _sh
 from repro.kernels import visits as _vs
 
-INTERPRET = True
-
 # pages-axis shard_map context — None = single-device hot path. Installed at
 # TRACE time by whoever owns the mesh (serving.Engine step impls,
 # launch.steps step fns), so jit-cached traces can never leak a stale mesh.
 _MESH_CTX: Optional[_sh.ShardCtx] = None
 
 
-def configure_for_backend() -> None:
-    global INTERPRET
-    INTERPRET = jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """True where the Pallas kernels must run in the interpreter: on every
+    backend but a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def make_mesh_ctx(mesh) -> Optional[_sh.ShardCtx]:
@@ -86,10 +87,9 @@ def mesh_ctx_scope(ctx: Optional[_sh.ShardCtx]):
 
 # ---------------------------------------------------------------------------
 # NOTE: every jitted wrapper below takes `interpret` as a STATIC argument
-# fed from the unjitted public dispatcher at call time. Reading the module
-# global INTERPRET inside the jitted body would bake its trace-time value
-# into the cached executable, so configure_for_backend()'s post-import flip
-# would be silently ignored (COOPT004, `python -m repro.analysis`).
+# fed from the unjitted public dispatcher at call time (interpret_mode()),
+# so the jit cache keys on it and no module state is read inside a trace
+# (COOPT004, `python -m repro.analysis`).
 def _use_visits(share_visits: bool, B: int) -> bool:
     # the batched-visit grid pays off only with >1 lane, and its int32 lane
     # bitmask caps membership at MAX_VISIT_LANES; beyond either bound the
@@ -125,7 +125,7 @@ def paged_pool_decode(q, kv_pages, scale_pages, cache_len, phys_table,
                       window: int = 0, sink_pages: int = 0,
                       share_visits: bool = False):
     """Fused decode over the global pool. q (B,Hq,D); kv_pages
-    (2,P_total,ps,Hkv,D); scale_pages (2,P_total,ps,Hkv)|None; phys/log_table
+    (2,P_total,Hkv,ps,D); scale_pages (2,P_total,Hkv,ps)|None; phys/log_table
     (B,NSel) int32 (-1 = never DMA'd). ``share_visits`` batches cross-lane
     shared pages through the deduplicated visit grid
     (``kernels.visits.plan_visits``); with no sharing present the result is
@@ -135,50 +135,38 @@ def paged_pool_decode(q, kv_pages, scale_pages, cache_len, phys_table,
             _MESH_CTX, q, kv_pages, scale_pages, cache_len, phys_table,
             log_table, opt_kv=opt_kv, opt_gqa=opt_gqa, window=window,
             sink_pages=sink_pages, share_visits=share_visits,
-            interpret=INTERPRET)
+            interpret=interpret_mode())
     return _paged_pool_decode_single(
         q, kv_pages, scale_pages, cache_len, phys_table, log_table,
         opt_kv=opt_kv, opt_gqa=opt_gqa, window=window,
         sink_pages=sink_pages, share_visits=share_visits,
-        interpret=INTERPRET)
+        interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("opt_kv", "interpret"))
 def _kv_cache_write_single(kv_cache, scale_cache, k_new, v_new, slot_idx, *,
                            opt_kv: bool, interpret: bool):
-    _, Pt, ps, Hkv, D = kv_cache.shape
-    flat_k = kv_cache[0].reshape(Pt * ps, Hkv, D)
-    flat_v = kv_cache[1].reshape(Pt * ps, Hkv, D)
-    if scale_cache is not None:
-        s_k = scale_cache[0].reshape(Pt * ps, Hkv)
-        s_v = scale_cache[1].reshape(Pt * ps, Hkv)
-    else:
-        s_k = jnp.zeros((Pt * ps, Hkv), jnp.float32)
-        s_v = s_k
-    k_c, v_c, ks_c, vs_c = _kw.kv_cache_write(
-        k_new, v_new, slot_idx.astype(jnp.int32), flat_k, flat_v, s_k, s_v,
-        opt_kv=opt_kv, interpret=interpret)
-    kv = jnp.stack([k_c.reshape(Pt, ps, Hkv, D),
-                    v_c.reshape(Pt, ps, Hkv, D)])
-    if scale_cache is not None:
-        scale_cache = jnp.stack([ks_c.reshape(Pt, ps, Hkv),
-                                 vs_c.reshape(Pt, ps, Hkv)])
-    return kv, scale_cache
+    sc = scale_cache
+    if sc is None:
+        sc = jnp.zeros(kv_cache.shape[:-1], jnp.float32)
+    kv, sc = _kw.kv_cache_write(k_new, v_new, slot_idx, kv_cache, sc,
+                                opt_kv=opt_kv, interpret=interpret)
+    return kv, (sc if scale_cache is not None else None)
 
 
 def kv_cache_write(kv_cache, scale_cache, k_new, v_new, slot_idx, *,
                    opt_kv: bool):
     """Engine-layout adapter for the write kernel. kv_cache
-    (2,P_total,ps,Hkv,D) global pool (its LAST flat line is the SkipSet
-    sentinel — the BlockManager never allocates the final page); returns
-    updated (kv_cache, scale_cache). Under a mesh ctx the scatter runs
-    shard-local (no sentinel needed: out-of-range slots simply drop)."""
+    (2,P_total,Hkv,ps,D) global pool, scale_cache (2,P_total,Hkv,ps) | None;
+    negative slots are not written. Returns updated (kv_cache,
+    scale_cache). Under a mesh ctx the scatter runs shard-local."""
     if _MESH_CTX is not None:
         return _sh.kv_pool_write(_MESH_CTX, kv_cache, scale_cache, k_new,
-                                 v_new, slot_idx, opt_kv=opt_kv)
+                                 v_new, slot_idx, opt_kv=opt_kv,
+                                 interpret=interpret_mode())
     return _kv_cache_write_single(kv_cache, scale_cache, k_new, v_new,
                                   slot_idx, opt_kv=opt_kv,
-                                  interpret=INTERPRET)
+                                  interpret=interpret_mode())
 
 
 def latent_pool_write(lat_cache, scale_cache, latent, slot_idx, *,
@@ -221,7 +209,7 @@ def flash_prefill(q, k, v, *, window: int = 0, block_q: int = 256,
     """Self-attention prefill over in-chunk K/V (no pool paging)."""
     return _flash_prefill_single(q, k, v, window=window, block_q=block_q,
                                  block_k=block_k, q_offset=q_offset,
-                                 interpret=INTERPRET)
+                                 interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("sm_scale", "opt_kv", "window",
@@ -260,12 +248,12 @@ def paged_latent_decode(q_lat, q_rope, lat_pages, scale_pages, cache_len,
             _MESH_CTX, q_lat, q_rope, lat_pages, scale_pages, cache_len,
             phys_table, log_table, sm_scale=sm_scale, opt_kv=opt_kv,
             window=window, sink_pages=sink_pages,
-            share_visits=share_visits, interpret=INTERPRET)
+            share_visits=share_visits, interpret=interpret_mode())
     return _paged_latent_decode_single(
         q_lat, q_rope, lat_pages, scale_pages, cache_len, phys_table,
         log_table, sm_scale=sm_scale, opt_kv=opt_kv, window=window,
         sink_pages=sink_pages, share_visits=share_visits,
-        interpret=INTERPRET)
+        interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("sm_scale", "opt_kv", "window",
@@ -298,12 +286,12 @@ def latent_chunk_prefill(q_lat, q_rope, positions, lat_pages, scale_pages,
         return _sh.latent_chunk_prefill(
             _MESH_CTX, q_lat, q_rope, positions, lat_pages, scale_pages,
             phys_table, sm_scale=sm_scale, opt_kv=opt_kv, window=window,
-            sink_pages=sink_pages, interpret=INTERPRET, seg_q=seg_q,
+            sink_pages=sink_pages, interpret=interpret_mode(), seg_q=seg_q,
             page_seg=page_seg, page_base=page_base)
     return _latent_chunk_prefill_single(
         q_lat, q_rope, positions, lat_pages, scale_pages, phys_table,
         seg_q, page_seg, page_base, sm_scale=sm_scale, opt_kv=opt_kv,
-        window=window, sink_pages=sink_pages, interpret=INTERPRET)
+        window=window, sink_pages=sink_pages, interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("opt_kv", "opt_gqa", "window",
@@ -336,9 +324,9 @@ def paged_chunk_prefill(q, positions, kv_pages, scale_pages, phys_table, *,
         return _sh.paged_chunk_prefill(
             _MESH_CTX, q, positions, kv_pages, scale_pages, phys_table,
             opt_kv=opt_kv, opt_gqa=opt_gqa, window=window,
-            sink_pages=sink_pages, interpret=INTERPRET, seg_q=seg_q,
+            sink_pages=sink_pages, interpret=interpret_mode(), seg_q=seg_q,
             page_seg=page_seg, page_base=page_base)
     return _paged_chunk_prefill_single(
         q, positions, kv_pages, scale_pages, phys_table, seg_q, page_seg,
         page_base, opt_kv=opt_kv, opt_gqa=opt_gqa, window=window,
-        sink_pages=sink_pages, interpret=INTERPRET)
+        sink_pages=sink_pages, interpret=interpret_mode())

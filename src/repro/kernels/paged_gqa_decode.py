@@ -16,16 +16,22 @@ One kernel fuses all three techniques (DESIGN.md §2):
             running (max, sum, acc) carried across the page grid dim.
 
 Pool addressing: the cache has NO batch dimension — ``k/v_pages`` are
-``(P_total, ps, Hkv, D)`` shared by every lane. Each lane's *physical* page
+``(P_total, Hkv, ps, D)`` shared by every lane. Each lane's *physical* page
 table is scalar-prefetched and dereferenced inside the BlockSpec index_map,
 so the block DMA'd at grid step (b, h, i) IS lane b's i-th logical page —
 the paper's "lazy memory mapping" realised as data-dependent prefetch. A
 parallel *logical* table supplies token positions (logical page id) for the
 causal / sliding-window masks; for dense decode it is simply ``arange``.
 
-TPU adaptation notes (DESIGN.md §3): grid = (batch, kv_head, page); page
-tiles are (page_size, head_dim) — lane dim = head_dim (128-aligned for every
-assigned arch), sublane = tokens. Scratch lives in VMEM; (m, l) are kept
+TPU layout: grid = (batch, kv_head, page). Heads come before tokens within
+a page, so one grid step DMAs the (page_size, head_dim) tile of one KV head
+— lane dim = head_dim, sublane = tokens, which satisfies Mosaic's (8, 128)
+block rule (a token-major ``(ps, Hkv, D)`` page would need a block of 1 in
+the sublane place). The per-token fp8 scales ``(P_total, Hkv, ps)`` are
+DMA'd a page at a time for all heads (a (Hkv, ps) block spans the array's
+last two dims) and the kernel reads its head's row; they are applied to the
+(G, ps) score and probability tiles as a row vector, which equals
+dequantizing the K/V rows. Scratch lives in VMEM; (m, l) are kept
 lane-replicated (G, 128) as on-chip reduction tiles.
 
 The windowed variant (block-sparse long-context policy, DESIGN.md §5) is the
@@ -45,19 +51,18 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _pool_kernel(len_ref, phys_ref, log_ref,     # scalar prefetch
                  q_ref, k_ref, v_ref, ks_ref, vs_ref,
                  o_ref, *refs,
-                 ps: int, opt_kv: bool, window: int, sink: int,
+                 ps: int, rep: int, opt_kv: bool, window: int, sink: int,
                  num_sel: int, return_state: bool):
     if return_state:
         mo_ref, lo_ref, m_ref, l_ref, acc_ref = refs
     else:
         m_ref, l_ref, acc_ref = refs
     b = pl.program_id(0)
+    kvh = pl.program_id(1) // rep
     s_i = pl.program_id(2)
     G, D = q_ref.shape[2], q_ref.shape[3]
     length = len_ref[b]
@@ -76,17 +81,13 @@ def _pool_kernel(len_ref, phys_ref, log_ref,     # scalar prefetch
     @pl.when(page >= 0)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                  # (G, D)
-        k = k_ref[0, :, 0, :]                                # (ps, D)
-        v = v_ref[0, :, 0, :]
-        if opt_kv:  # Opt-KV Eq. 6: fused dequant at the VMEM boundary
-            k = k.astype(jnp.float32) * ks_ref[0].reshape(ps, 1)
-            v = v.astype(jnp.float32) * vs_ref[0].reshape(ps, 1)
-        else:
-            k = k.astype(jnp.float32)
-            v = v.astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (ps, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * (1.0 / math.sqrt(D))                         # (G, ps)
+        if opt_kv:  # Opt-KV Eq. 6: fused dequant at the VMEM boundary
+            s = s * ks_ref[0, pl.ds(kvh, 1), :]              # (1, ps) row
         pos = lpage * ps + jax.lax.broadcasted_iota(jnp.int32, (G, ps), 1)
         mask = pos < length
         if window:
@@ -101,8 +102,9 @@ def _pool_kernel(len_ref, phys_ref, log_ref,     # scalar prefetch
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)                               # (G, ps)
         l_new = l_ref[:, 0:1] * corr + jnp.sum(p, -1, keepdims=True)
+        pv = p * vs_ref[0, pl.ds(kvh, 1), :] if opt_kv else p
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -122,9 +124,9 @@ def _pool_kernel(len_ref, phys_ref, log_ref,     # scalar prefetch
 def paged_pool_decode(q, k_pages, v_pages, k_scale, v_scale, cache_len,
                       phys_table, log_table, *, opt_kv: bool, opt_gqa: bool,
                       window: int = 0, sink_pages: int = 0,
-                      return_state: bool = False, interpret: bool = True):
-    """q: (B, Hq, D); k/v_pages: (P_total, ps, Hkv, D) GLOBAL pool [fp8 if
-    opt_kv]; k/v_scale: (P_total, ps, Hkv) f32 or None; cache_len: (B,) int32;
+                      return_state: bool = False, interpret: bool = False):
+    """q: (B, Hq, D); k/v_pages: (P_total, Hkv, ps, D) GLOBAL pool [fp8 if
+    opt_kv]; k/v_scale: (P_total, Hkv, ps) f32 or None; cache_len: (B,) int32;
     phys_table/log_table: (B, NSel) int32 — physical page to DMA / logical
     page id for positions; -1 = skip (never DMA'd). Returns (B, Hq, D);
     with ``return_state`` also the final online-softmax (m, l) as (B, Hq)
@@ -132,27 +134,25 @@ def paged_pool_decode(q, k_pages, v_pages, k_scale, v_scale, cache_len,
     its contribution vanishes in the cross-shard log-sum-exp merge
     (``kernels.sharded``)."""
     B, Hq, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     NSel = phys_table.shape[1]
 
     if opt_gqa:
-        G = Hq // Hkv
-        heads, kv_of_head = Hkv, lambda h: h
+        G, heads, rep = Hq // Hkv, Hkv, 1
     else:
         # Original MHA semantics: every query head re-streams its KV head.
-        G = 1
-        heads, kv_of_head = Hq, lambda h: h // max(Hq // Hkv, 1)
+        G, heads, rep = 1, Hq, max(Hq // Hkv, 1)
     qf = q.reshape(B, heads, G, D)
 
     if k_scale is None:
-        k_scale = jnp.zeros((P, ps, Hkv), jnp.float32)
+        k_scale = jnp.zeros((P, Hkv, ps), jnp.float32)
         v_scale = k_scale
 
     def kv_idx(b, h, s, L, phys, log):
-        return (jnp.maximum(phys[b, s], 0), 0, kv_of_head(h), 0)
+        return (jnp.maximum(phys[b, s], 0), h // rep, 0, 0)
 
     def sc_idx(b, h, s, L, phys, log):
-        return (jnp.maximum(phys[b, s], 0), 0, kv_of_head(h))
+        return (jnp.maximum(phys[b, s], 0), 0, 0)
 
     out_blk = pl.BlockSpec((1, 1, G, D),
                            lambda b, h, s, L, phys, log: (b, h, 0, 0))
@@ -164,7 +164,7 @@ def paged_pool_decode(q, k_pages, v_pages, k_scale, v_scale, cache_len,
         out_specs += [st_blk, st_blk]
         out_shape += [jax.ShapeDtypeStruct((B, heads, G, 128), jnp.float32)] * 2
 
-    kern = functools.partial(_pool_kernel, ps=ps, opt_kv=opt_kv,
+    kern = functools.partial(_pool_kernel, ps=ps, rep=rep, opt_kv=opt_kv,
                              window=window, sink=sink_pages, num_sel=NSel,
                              return_state=return_state)
     res = pl.pallas_call(
@@ -175,10 +175,10 @@ def paged_pool_decode(q, k_pages, v_pages, k_scale, v_scale, cache_len,
             in_specs=[
                 pl.BlockSpec((1, 1, G, D),
                              lambda b, h, s, L, phys, log: (b, h, 0, 0)),
-                pl.BlockSpec((1, ps, 1, D), kv_idx),
-                pl.BlockSpec((1, ps, 1, D), kv_idx),
-                pl.BlockSpec((1, ps, 1), sc_idx),
-                pl.BlockSpec((1, ps, 1), sc_idx),
+                pl.BlockSpec((1, 1, ps, D), kv_idx),
+                pl.BlockSpec((1, 1, ps, D), kv_idx),
+                pl.BlockSpec((1, Hkv, ps), sc_idx),
+                pl.BlockSpec((1, Hkv, ps), sc_idx),
             ],
             out_specs=out_specs,
             scratch_shapes=[
@@ -188,7 +188,7 @@ def paged_pool_decode(q, k_pages, v_pages, k_scale, v_scale, cache_len,
             ],
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len, phys_table, log_table, qf, k_pages, v_pages,
@@ -204,8 +204,8 @@ def paged_pool_decode(q, k_pages, v_pages, k_scale, v_scale, cache_len,
 def _visit_kernel(vp_ref, vm_ref, vl_ref,            # scalar prefetch
                   q_ref, len_ref, k_ref, v_ref, ks_ref, vs_ref,
                   o_ref, *refs,
-                  ps: int, G: int, opt_kv: bool, window: int, sink: int,
-                  num_visits: int, return_state: bool):
+                  ps: int, G: int, rep: int, opt_kv: bool, window: int,
+                  sink: int, num_visits: int, return_state: bool):
     """Cross-lane visit grid: one step per deduplicated (page, lane-set).
 
     Query rows of ALL lanes ride VMEM-resident as one (BG, D) tile
@@ -222,6 +222,7 @@ def _visit_kernel(vp_ref, vm_ref, vl_ref,            # scalar prefetch
         mo_ref, lo_ref, m_ref, l_ref, acc_ref = refs
     else:
         m_ref, l_ref, acc_ref = refs
+    kvh = pl.program_id(0) // rep
     v_i = pl.program_id(1)
     BG = q_ref.shape[1]
     page = vp_ref[v_i]
@@ -237,17 +238,13 @@ def _visit_kernel(vp_ref, vm_ref, vl_ref,            # scalar prefetch
     @pl.when(page >= 0)
     def _compute():
         q = q_ref[0].astype(jnp.float32)                     # (BG, D)
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
-        if opt_kv:  # Opt-KV Eq. 6: fused dequant — ONCE per visit, not per lane
-            k = k.astype(jnp.float32) * ks_ref[0].reshape(ps, 1)
-            v = v.astype(jnp.float32) * vs_ref[0].reshape(ps, 1)
-        else:
-            k = k.astype(jnp.float32)
-            v = v.astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (ps, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * (1.0 / math.sqrt(q_ref.shape[2]))            # (BG, ps)
+        if opt_kv:  # Opt-KV Eq. 6: fused dequant — ONCE per visit, not per lane
+            s = s * ks_ref[0, pl.ds(kvh, 1), :]
         # row r belongs to lane r // G; membership = lane's bit in the mask
         lane_r = jax.lax.broadcasted_iota(jnp.int32, (BG, 1), 0) // G
         member = jnp.equal(
@@ -269,8 +266,9 @@ def _visit_kernel(vp_ref, vm_ref, vl_ref,            # scalar prefetch
         # non-member rows hard-zero so their (m, l, acc) are untouched
         p = jnp.where(member, jnp.exp(s - m_new), 0.0)       # (BG, ps)
         l_new = l_ref[:, 0:1] * corr + jnp.sum(p, -1, keepdims=True)
+        pv = p * vs_ref[0, pl.ds(kvh, 1), :] if opt_kv else p
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -289,7 +287,7 @@ def paged_pool_decode_visits(q, k_pages, v_pages, k_scale, v_scale,
                              cache_len, visit_page, visit_lanes, visit_log,
                              *, opt_kv: bool, opt_gqa: bool, window: int = 0,
                              sink_pages: int = 0, return_state: bool = False,
-                             interpret: bool = True):
+                             interpret: bool = False):
     """Batched-visit twin of ``paged_pool_decode``: same pool/query/window
     semantics, but the page grid dim iterates a deduplicated cross-lane
     visit list (``kernels.visits.plan_visits``) instead of (lane x page) —
@@ -298,15 +296,13 @@ def paged_pool_decode_visits(q, k_pages, v_pages, k_scale, v_scale,
     B <= visits.MAX_VISIT_LANES (int32 lane bitmask); ``ops`` dispatches
     back to the per-lane grid beyond that."""
     B, Hq, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     NV = visit_page.shape[0]
 
     if opt_gqa:
-        G = Hq // Hkv
-        heads, kv_of_head = Hkv, lambda h: h
+        G, heads, rep = Hq // Hkv, Hkv, 1
     else:
-        G = 1
-        heads, kv_of_head = Hq, lambda h: h // max(Hq // Hkv, 1)
+        G, heads, rep = 1, Hq, max(Hq // Hkv, 1)
     BG = B * G
     # rows r = b * G + g per head plane: lane-contiguous row blocks
     qf = q.reshape(B, heads, G, D).transpose(1, 0, 2, 3).reshape(heads, BG, D)
@@ -315,14 +311,14 @@ def paged_pool_decode_visits(q, k_pages, v_pages, k_scale, v_scale,
     ).reshape(BG, 128)
 
     if k_scale is None:
-        k_scale = jnp.zeros((P, ps, Hkv), jnp.float32)
+        k_scale = jnp.zeros((P, Hkv, ps), jnp.float32)
         v_scale = k_scale
 
     def kv_idx(h, v, vp, vl, vm):
-        return (jnp.maximum(vp[v], 0), 0, kv_of_head(h), 0)
+        return (jnp.maximum(vp[v], 0), h // rep, 0, 0)
 
     def sc_idx(h, v, vp, vl, vm):
-        return (jnp.maximum(vp[v], 0), 0, kv_of_head(h))
+        return (jnp.maximum(vp[v], 0), 0, 0)
 
     out_blk = pl.BlockSpec((1, BG, D), lambda h, v, vp, vl, vm: (h, 0, 0))
     st_blk = pl.BlockSpec((1, BG, 128), lambda h, v, vp, vl, vm: (h, 0, 0))
@@ -332,7 +328,7 @@ def paged_pool_decode_visits(q, k_pages, v_pages, k_scale, v_scale,
         out_specs += [st_blk, st_blk]
         out_shape += [jax.ShapeDtypeStruct((heads, BG, 128), jnp.float32)] * 2
 
-    kern = functools.partial(_visit_kernel, ps=ps, G=G, opt_kv=opt_kv,
+    kern = functools.partial(_visit_kernel, ps=ps, G=G, rep=rep, opt_kv=opt_kv,
                              window=window, sink=sink_pages, num_visits=NV,
                              return_state=return_state)
     res = pl.pallas_call(
@@ -343,10 +339,10 @@ def paged_pool_decode_visits(q, k_pages, v_pages, k_scale, v_scale,
             in_specs=[
                 pl.BlockSpec((1, BG, D), lambda h, v, vp, vl, vm: (h, 0, 0)),
                 pl.BlockSpec((BG, 128), lambda h, v, vp, vl, vm: (0, 0)),
-                pl.BlockSpec((1, ps, 1, D), kv_idx),
-                pl.BlockSpec((1, ps, 1, D), kv_idx),
-                pl.BlockSpec((1, ps, 1), sc_idx),
-                pl.BlockSpec((1, ps, 1), sc_idx),
+                pl.BlockSpec((1, 1, ps, D), kv_idx),
+                pl.BlockSpec((1, 1, ps, D), kv_idx),
+                pl.BlockSpec((1, Hkv, ps), sc_idx),
+                pl.BlockSpec((1, Hkv, ps), sc_idx),
             ],
             out_specs=out_specs,
             scratch_shapes=[
@@ -356,7 +352,7 @@ def paged_pool_decode_visits(q, k_pages, v_pages, k_scale, v_scale,
             ],
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(visit_page, visit_lanes, visit_log, qf, len_rows,

@@ -49,7 +49,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 _NEG = -1e30
 
@@ -132,7 +131,7 @@ def _latent_kernel(len_ref, phys_ref, log_ref,       # scalar prefetch
 def paged_latent_decode(q_lat, q_rope, lat_pages, scale_pages, cache_len,
                         phys_table, log_table, *, sm_scale: float,
                         opt_kv: bool, window: int = 0, sink_pages: int = 0,
-                        return_state: bool = False, interpret: bool = True):
+                        return_state: bool = False, interpret: bool = False):
     """q_lat: (B, H, R) W_uk-absorbed queries; q_rope: (B, H, dr); lat_pages:
     (P_total, ps, R+dr) GLOBAL latent pool [fp8 if opt_kv]; scale_pages:
     (P_total, ps, 2) f32 dual c/k_rope scales or None; cache_len: (B,) int32;
@@ -184,7 +183,7 @@ def paged_latent_decode(q_lat, q_rope, lat_pages, scale_pages, cache_len,
             ],
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cache_len, phys_table, log_table, q_lat, q_rope, lat_pages,
@@ -279,7 +278,7 @@ def paged_latent_decode_visits(q_lat, q_rope, lat_pages, scale_pages,
                                *, sm_scale: float, opt_kv: bool,
                                window: int = 0, sink_pages: int = 0,
                                return_state: bool = False,
-                               interpret: bool = True):
+                               interpret: bool = False):
     """Batched-visit twin of ``paged_latent_decode``: the page grid dim
     iterates a deduplicated cross-lane visit list (``kernels.visits``) so a
     latent page shared by N lanes is streamed/dequantized once per step.
@@ -335,7 +334,7 @@ def paged_latent_decode_visits(q_lat, q_rope, lat_pages, scale_pages,
             ],
         ),
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(visit_page, visit_lanes, visit_log, qlf, qrf, len_rows,

@@ -27,22 +27,22 @@ def paged_pool_decode_ref(q, k_pages, v_pages, k_scale, v_scale, cache_len,
                           window: int = 0, sink_pages: int = 0):
     """Flat-softmax oracle of the fused pooled decode kernel.
 
-    q (B,Hq,D); k/v_pages (P_total, ps, Hkv, D); phys/log_table (B, NSel),
+    q (B,Hq,D); k/v_pages (P_total, Hkv, ps, D); k/v_scale (P_total, Hkv,
+    ps) | None; phys/log_table (B, NSel),
     -1 = skipped. Gathers each lane's selected pages, places token j of
     logical page L at position L*ps+j, and reduces with one flat softmax —
     the kernel's online accumulation must match this exactly (modes agree
     numerically; Opt-Pa/Opt-GQA only change the compute schedule).
     """
     B, Hq, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
+    P, Hkv, ps, _ = k_pages.shape
     G = Hq // Hkv
     pt = jnp.maximum(phys_table, 0)
-    k = _dq(jnp.take(k_pages, pt, axis=0),
-            None if k_scale is None else jnp.take(k_scale, pt, axis=0),
-            opt_kv)                                     # (B,NSel,ps,Hkv,D)
-    v = _dq(jnp.take(v_pages, pt, axis=0),
-            None if v_scale is None else jnp.take(v_scale, pt, axis=0),
-            opt_kv)
+
+    def take(x):    # gathered (B, NSel, Hkv, ps[, D]) -> token-major
+        return None if x is None else jnp.take(x, pt, axis=0).swapaxes(2, 3)
+    k = _dq(take(k_pages), take(k_scale), opt_kv)       # (B,NSel,ps,Hkv,D)
+    v = _dq(take(v_pages), take(v_scale), opt_kv)
     NSel = phys_table.shape[1]
     k = k.reshape(B, NSel * ps, Hkv, D)
     v = v.reshape(B, NSel * ps, Hkv, D)
@@ -136,11 +136,10 @@ def latent_chunk_prefill_ref(q_lat, q_rope, positions, lat_pages,
 
 def kv_cache_write_ref(k_new, v_new, slot_idx, k_cache, v_cache, k_scale,
                        v_scale, *, opt_kv: bool):
-    """Scatter-with-drop oracle over the GLOBAL flat pool (NSlot, Hkv, D)
-    (sentinel line NSlot-1 is dont-care — the kernel routes SkipSet tokens
-    there; callers must compare only real lines)."""
-    B, S, Hkv, D = k_new.shape
-    slots = jnp.where(slot_idx < 0, -1, slot_idx)       # (B, S)
+    """Scatter-with-drop oracle over the GLOBAL flat pool (NSlot, Hkv, D):
+    SkipSet tokens (negative slots) write no line."""
+    # out of range, so dropped (a -1 would wrap onto the last line)
+    slots = jnp.where(slot_idx < 0, k_cache.shape[0], slot_idx)   # (B, S)
 
     def put(cache, scale, new):
         newf = new.astype(jnp.float32)

@@ -18,9 +18,9 @@ single-host kernel against only its owned contiguous page range:
     out = psum(w_s * o_s) / psum(w_s).  A shard holding none of a lane's
     pages reports (m = -1e30, l = 0) and so contributes nothing;
   * the write path stays shard-local too: global flat slots are translated
-    to the shard's slot range (others dropped via ``mode='drop'``), so the
-    pool is scattered into in place with NO cross-shard traffic and no
-    sentinel-line aliasing (a -1 simply never lands).
+    to the shard's slot range and the same write kernel runs per shard
+    (other shards' slots become SkipSet -1s), so the pool is written in
+    place with NO cross-shard traffic.
 
 The engine-facing contract is unchanged: callers pass GLOBAL pools, GLOBAL
 tables/slots, and get replicated outputs — ``kernels.ops`` dispatches here
@@ -37,16 +37,16 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.cache.quant import quantize_fp8, quantize_latent
+from repro.cache.quant import quantize_latent
 # PAGES_AXES — the mesh axes the pages axis is sharded over — lives with
 # the shard-ownership math in core.opt_kv (re-exported here for kernel-side
 # callers); host tooling reads it without importing the Pallas stack.
 from repro.core.opt_kv import (PAGES_AXES,                  # noqa: F401
                                global_to_local_pages, global_to_local_slots)
 from repro.kernels import flash_chunk_prefill as _fc
+from repro.kernels import kv_cache_write as _kw
 from repro.kernels import latent_chunk_prefill as _lc
 from repro.kernels import paged_gqa_decode as _pd
 from repro.kernels import paged_latent_decode as _ld
@@ -86,12 +86,18 @@ def _shard_index(ctx: ShardCtx):
 
 def _lse_merge(ctx: ShardCtx, o, m, l, out_dtype):
     """Combine per-shard normalized partials across the pages axes.
-    o (..., D) f32-able; m/l (...,) f32. Standard log-sum-exp merge."""
+    o (..., D) f32-able; m/l (...,) f32. Standard log-sum-exp merge, with
+    each shard's weight normalized BEFORE the sum: when one shard holds all
+    of a row's pages its share is exactly 1 and the others' exactly 0, so
+    the row comes out bit-identical to the single-device kernel's. (The
+    TPU's f32 division need not return exactly 1 for w / w, hence the
+    select.)"""
     m_all = jax.lax.pmax(m, ctx.axes)
     w = jnp.exp(m - m_all) * l                     # 0 for page-less shards
     den = jax.lax.psum(w, ctx.axes)
-    num = jax.lax.psum(o.astype(jnp.float32) * w[..., None], ctx.axes)
-    return (num / jnp.maximum(den, 1e-30)[..., None]).astype(out_dtype)
+    share = jnp.where(w == den, 1.0, w / jnp.maximum(den, 1e-30))
+    return jax.lax.psum(o.astype(jnp.float32) * share[..., None],
+                        ctx.axes).astype(out_dtype)
 
 
 def _pages_spec(ndim: int, pages_dim: int, ctx: ShardCtx) -> P:
@@ -106,8 +112,8 @@ def _pages_spec(ndim: int, pages_dim: int, ctx: ShardCtx) -> P:
 def paged_pool_decode(ctx: ShardCtx, q, kv_pages, scale_pages, cache_len,
                       phys_table, log_table, *, opt_kv: bool, opt_gqa: bool,
                       window: int = 0, sink_pages: int = 0,
-                      share_visits: bool = False, interpret: bool = True):
-    """Distributed ``paged_gqa_decode``: kv_pages (2, P_total, ps, Hkv, D)
+                      share_visits: bool = False, interpret: bool = False):
+    """Distributed ``paged_gqa_decode``: kv_pages (2, P_total, Hkv, ps, D)
     pages-sharded over ``ctx.axes``; q/tables/cache_len replicated; returns
     the replicated (B, Hq, D) attention output. With ``share_visits`` each
     shard plans its visit list AFTER the global->local page translation, so
@@ -115,9 +121,8 @@ def paged_pool_decode(ctx: ShardCtx, q, kv_pages, scale_pages, cache_len,
     range."""
     P_total = kv_pages.shape[1]
     P_local = P_total // ctx.num_shards
-    _, _, ps, Hkv, _ = kv_pages.shape
     if scale_pages is None:
-        scale_pages = jnp.zeros((2, P_total, ps, Hkv), jnp.float32)
+        scale_pages = jnp.zeros(kv_pages.shape[:-1], jnp.float32)
     use_visits = share_visits and 1 < q.shape[0] <= _vs.MAX_VISIT_LANES
 
     def body(q, kv, sc, cl, phys, log):
@@ -138,11 +143,11 @@ def paged_pool_decode(ctx: ShardCtx, q, kv_pages, scale_pages, cache_len,
                 interpret=interpret)
         return _lse_merge(ctx, o, m, l, q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(), _pages_spec(5, 1, ctx), _pages_spec(4, 1, ctx),
                   P(), P(), P()),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(q, kv_pages, scale_pages, cache_len.astype(jnp.int32),
       phys_table.astype(jnp.int32), log_table.astype(jnp.int32))
 
@@ -152,7 +157,7 @@ def paged_pool_decode(ctx: ShardCtx, q, kv_pages, scale_pages, cache_len,
 def paged_chunk_prefill(ctx: ShardCtx, q, positions, kv_pages, scale_pages,
                         phys_table, *, opt_kv: bool, opt_gqa: bool,
                         window: int = 0, sink_pages: int = 0,
-                        interpret: bool = True, seg_q=None, page_seg=None,
+                        interpret: bool = False, seg_q=None, page_seg=None,
                         page_base=None):
     """Distributed ``flash_chunk_prefill``: chunk queries (B, S, Hq, D)
     replicated, pool pages-sharded; per-shard partials lse-merged. The
@@ -163,9 +168,8 @@ def paged_chunk_prefill(ctx: ShardCtx, q, positions, kv_pages, scale_pages,
     P_total = kv_pages.shape[1]
     NP = phys_table.shape[1]
     P_local = P_total // ctx.num_shards
-    _, _, ps, Hkv, _ = kv_pages.shape
     if scale_pages is None:
-        scale_pages = jnp.zeros((2, P_total, ps, Hkv), jnp.float32)
+        scale_pages = jnp.zeros(kv_pages.shape[:-1], jnp.float32)
     if seg_q is None:
         seg_q = jnp.zeros((B, S), jnp.int32)
     if page_seg is None:
@@ -183,11 +187,11 @@ def paged_chunk_prefill(ctx: ShardCtx, q, positions, kv_pages, scale_pages,
             seg_q=sq, page_seg=pseg, page_base=pbase)
         return _lse_merge(ctx, o, m, l, q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(), P(), _pages_spec(5, 1, ctx), _pages_spec(4, 1, ctx),
                   P(), P(), P(), P()),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(q, positions.astype(jnp.int32), kv_pages, scale_pages,
       phys_table.astype(jnp.int32), seg_q.astype(jnp.int32),
       page_seg.astype(jnp.int32), page_base.astype(jnp.int32))
@@ -198,7 +202,7 @@ def paged_chunk_prefill(ctx: ShardCtx, q, positions, kv_pages, scale_pages,
 def paged_latent_decode(ctx: ShardCtx, q_lat, q_rope, lat_pages, scale_pages,
                         cache_len, phys_table, log_table, *, sm_scale: float,
                         opt_kv: bool, window: int = 0, sink_pages: int = 0,
-                        share_visits: bool = False, interpret: bool = True):
+                        share_visits: bool = False, interpret: bool = False):
     """Distributed ``paged_latent_decode``: latent pool (P_total, ps, R+dr)
     pages-sharded; absorbed queries replicated; returns o_lat (B, H, R) f32.
     With ``share_visits`` each shard plans its visit list AFTER the
@@ -226,11 +230,11 @@ def paged_latent_decode(ctx: ShardCtx, q_lat, q_rope, lat_pages, scale_pages,
                 return_state=True, interpret=interpret)
         return _lse_merge(ctx, o, m, l, jnp.float32)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(), P(), _pages_spec(3, 0, ctx), _pages_spec(3, 0, ctx),
                   P(), P(), P()),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(q_lat, q_rope, lat_pages, scale_pages, cache_len.astype(jnp.int32),
       phys_table.astype(jnp.int32), log_table.astype(jnp.int32))
 
@@ -240,7 +244,7 @@ def paged_latent_decode(ctx: ShardCtx, q_lat, q_rope, lat_pages, scale_pages,
 def latent_chunk_prefill(ctx: ShardCtx, q_lat, q_rope, positions, lat_pages,
                          scale_pages, phys_table, *, sm_scale: float,
                          opt_kv: bool, window: int = 0, sink_pages: int = 0,
-                         interpret: bool = True, seg_q=None, page_seg=None,
+                         interpret: bool = False, seg_q=None, page_seg=None,
                          page_base=None):
     """Distributed ``latent_chunk_prefill``: chunk of absorbed queries
     (B, S, H, R) replicated, latent pool pages-sharded; returns o_lat
@@ -268,55 +272,45 @@ def latent_chunk_prefill(ctx: ShardCtx, q_lat, q_rope, positions, lat_pages,
             interpret=interpret, seg_q=sq, page_seg=pseg, page_base=pbase)
         return _lse_merge(ctx, o, m, l, jnp.float32)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(), P(), P(), _pages_spec(3, 0, ctx),
                   _pages_spec(3, 0, ctx), P(), P(), P(), P()),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(q_lat, q_rope, positions.astype(jnp.int32), lat_pages, scale_pages,
       phys_table.astype(jnp.int32), seg_q.astype(jnp.int32),
       page_seg.astype(jnp.int32), page_base.astype(jnp.int32))
 
 
 # ------------------------------------------------------------ write path --
-@partial(jax.jit, static_argnames=("ctx", "opt_kv"))
+@partial(jax.jit, static_argnames=("ctx", "opt_kv", "interpret"))
 def kv_pool_write(ctx: ShardCtx, kv_cache, scale_cache, k_new, v_new,
-                  slot_idx, *, opt_kv: bool):
-    """Shard-local write into the pages-sharded KV pool: quantization runs
-    replicated on the (small) new tokens, then each shard scatters only the
-    slots inside its own page range (others mapped one PAST the shard's
-    range by ``global_to_local_slots`` and OOB-dropped — never -1, which
-    would wrap onto the shard's live last line). No cross-shard traffic, no
-    sentinel line needed — live lines match ``opt_kv.write_kv``'s jnp
-    scatter exactly. Returns updated (kv_cache, scale_cache)."""
-    _, Pt, ps, H, D = kv_cache.shape
-    P_local = Pt // ctx.num_shards
-    new = jnp.stack([k_new, v_new])                      # (2,B,S,H,D)
-    if opt_kv:
-        vals, scl = quantize_fp8(new, axis=-1)
-    else:
-        vals, scl = new, jnp.zeros(new.shape[:-1], jnp.float32)
+                  slot_idx, *, opt_kv: bool, interpret: bool = False):
+    """Shard-local write into the pages-sharded KV pool: every shard runs
+    the single-device write kernel (``kernels.kv_cache_write``) on its own
+    page range, with the slots of other shards turned into SkipSet -1s. No
+    cross-shard traffic, and each line is quantized by the same kernel as
+    on one device. Returns updated (kv_cache, scale_cache)."""
+    _, Pt, _, ps, _ = kv_cache.shape
+    n_local = Pt // ctx.num_shards * ps
     has_scale = scale_cache is not None
     if not has_scale:
-        scale_cache = jnp.zeros((2, Pt, ps, H), jnp.float32)
+        scale_cache = jnp.zeros(kv_cache.shape[:-1], jnp.float32)
 
-    def body(kv, sc, vals, scl, slots):
-        first = _shard_index(ctx) * (P_local * ps)
-        ls = global_to_local_slots(slots, first, P_local * ps)
-        flat = kv.reshape(2, P_local * ps, H, D)
-        flat = flat.at[:, ls].set(vals.astype(flat.dtype), mode="drop")
-        sflat = sc.reshape(2, P_local * ps, H)
-        sflat = sflat.at[:, ls].set(scl, mode="drop")
-        return (flat.reshape(2, P_local, ps, H, D),
-                sflat.reshape(2, P_local, ps, H))
+    def body(kv, sc, k, v, slots):
+        # flat slots map like page ids: owned -> local, anything else -> -1
+        local = global_to_local_pages(slots, _shard_index(ctx) * n_local,
+                                      n_local)
+        return _kw.kv_cache_write(k, v, local, kv, sc, opt_kv=opt_kv,
+                                  interpret=interpret)
 
-    kv, sc = shard_map(
+    kv, sc = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(_pages_spec(5, 1, ctx), _pages_spec(4, 1, ctx),
                   P(), P(), P()),
         out_specs=(_pages_spec(5, 1, ctx), _pages_spec(4, 1, ctx)),
-        check_rep=False,
-    )(kv_cache, scale_cache, vals, scl, slot_idx.astype(jnp.int32))
+        check_vma=False,
+    )(kv_cache, scale_cache, k_new, v_new, slot_idx.astype(jnp.int32))
     return kv, (sc if has_scale else None)
 
 
@@ -345,11 +339,11 @@ def latent_pool_write(ctx: ShardCtx, lat_cache, scale_cache, latent,
         sflat = sflat.at[ls].set(scl, mode="drop")
         return flat.reshape(P_local, ps, W), sflat.reshape(P_local, ps, 2)
 
-    lat, sc = shard_map(
+    lat, sc = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(_pages_spec(3, 0, ctx), _pages_spec(3, 0, ctx), P(), P(),
                   P()),
         out_specs=(_pages_spec(3, 0, ctx), _pages_spec(3, 0, ctx)),
-        check_rep=False,
+        check_vma=False,
     )(lat_cache, scale_cache, vals, scl, slot_idx.astype(jnp.int32))
     return lat, (sc if has_scale else None)

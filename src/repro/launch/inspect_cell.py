@@ -35,9 +35,10 @@ def main(argv=None):
     s = model.summary()
     print(f"flops/dev={s['flops']:.3e}  bytes/dev={s['bytes']:.3e}  "
           f"coll/dev={s['collective_bytes']:.3e}")
-    print(f"terms: C={s['flops']/mesh_lib.PEAK_FLOPS_BF16:.2e}s "
-          f"M={s['bytes']/mesh_lib.HBM_BW:.2e}s "
-          f"X={s['collective_bytes']/mesh_lib.ICI_BW:.2e}s")
+    pk = mesh_lib.chip_peaks(mesh_lib.TARGET_DEVICE_KIND)
+    print(f"terms: C={s['flops']/pk.bf16_flops:.2e}s "
+          f"M={s['bytes']/pk.hbm_bw:.2e}s "
+          f"X={s['collective_bytes']/pk.ici_bw:.2e}s")
     mem = compiled.memory_analysis()
     print(f"temp/dev={mem.temp_size_in_bytes/2**30:.1f}GiB")
     print(f"\ntop {args.top} collectives by wire bytes:")

@@ -12,28 +12,38 @@ state (the dry-run sets XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import math
+from dataclasses import dataclass
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    # Auto axes: the models place activations with with_sharding_constraint
+    # and the kernels run under shard_map, both of which want GSPMD-style
+    # axes (jax.make_mesh defaults to Explicit ones)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1-device mesh for smoke tests on the CPU container."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
 
 
 def make_sim_mesh(data: int = 4, model: int = 2, pod: int = 1):
-    """Simulated small mesh for CPU verification of the sharded KV pool
-    (needs ``XLA_FLAGS=--xla_force_host_platform_device_count>=pod*data*model``
+    """Small mesh: (data, model) over the first data*model devices — four
+    chips of one host, or simulated CPU devices (which need
+    ``XLA_FLAGS=--xla_force_host_platform_device_count>=pod*data*model``
     set before the first jax import — see the CI mesh-matrix job)."""
     if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _mesh((pod, data, model), ("pod", "data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def kv_shard_count(mesh) -> int:
@@ -48,9 +58,36 @@ def kv_shard_count(mesh) -> int:
     return math.prod(mesh.shape[a] for a in PAGES_AXES if a in mesh.shape)
 
 
-# TPU v5e hardware constants (per chip) — roofline denominators.
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link (~ per-chip usable)
-VMEM_BYTES = 128 * 2 ** 20
-HBM_BYTES = 16 * 2 ** 30
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks — the roofline denominators."""
+    bf16_flops: float               # FLOP/s
+    hbm_bw: float                   # B/s
+    hbm_bytes: float
+    ici_bw: float                   # B/s per chip-to-chip link
+    source: str
+
+
+# keyed by jax ``Device.device_kind``; a kind not listed has no peaks
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        bf16_flops=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+        ici_bw=1600e9 / 8 / 4,      # 1,600 Gbit/s over the chip's 4 links
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip"),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of a chip by its ``device_kind``; raises for an unknown kind
+    (a roofline against a guessed chip is no roofline)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") \
+            from None
+
+
+# the chip the production meshes and dry-run cells are sized for
+TARGET_DEVICE_KIND = "TPU v5 lite"

@@ -70,10 +70,11 @@ class ServeRunner:
                  assert_aot: bool = False, warmup_pass: bool = False,
                  deadline_s: float = 0.0, max_queue_depth=None,
                  max_queued_tokens=None, pool_pages: int = 0,
-                 host_pages: int = 0, prefetch_depth: int = 2):
-        # Pallas kernels run compiled on TPU, interpret-mode elsewhere
-        from repro.kernels import ops
-        ops.configure_for_backend()
+                 host_pages: int = 0, prefetch_depth: int = 2,
+                 prefill_buckets=None, params=None):
+        """``prefill_buckets`` (default ``(32, 64, 128, 256, max_len)``)
+        fixes the prefill step shapes that get compiled; ``params`` serves
+        given weights (default: the model's seeded init)."""
         cfg = get_config(arch)
         coopt = MODES[mode].replace(use_kernel=use_kernel)
         # all cache knobs travel through ONE CacheConfig (shard count
@@ -81,13 +82,14 @@ class ServeRunner:
         # conflict); pool_pages=0 keeps the derived num_lanes*pages(max_len)
         ecfg = EngineConfig(
             num_lanes=num_lanes, max_len=max_len,
-            prefill_buckets=(32, 64, 128, 256, max_len),
+            prefill_buckets=tuple(prefill_buckets
+                                  or (32, 64, 128, 256, max_len)),
             sampling=SamplingParams(temperature=temperature), seed=seed,
             pack_prefill=pack,
             cache=CacheConfig(num_pages=pool_pages, num_shards=num_shards,
                               host_pages=host_pages,
                               prefetch_depth=prefetch_depth))
-        self.engine = Engine(cfg, coopt, ecfg, mesh=mesh)
+        self.engine = Engine(cfg, coopt, ecfg, params=params, mesh=mesh)
         stream = RequestStream(cfg.vocab_size, seed=seed, scale=scale)
         self.reqs = stream.take(requests, max_new_tokens=max_new_tokens)
         self.offsets = poisson_offsets(requests, arrival_rate, seed)
@@ -104,6 +106,7 @@ class ServeRunner:
                      "host_tier_pages": host_pages}
         self.frontend = None
         self.last_streams = []          # TokenStreams of the last async pass
+        self.last_requests = []         # Requests of the last sync pass
         if use_async:
             from repro.launch.steps import serving_warmup
             self.frontend = AsyncEngine(self.engine, warmup=False,
@@ -201,6 +204,7 @@ class ServeRunner:
         engine = self.engine
         pending = [(off, copy.deepcopy(r))
                    for off, r in zip(self.offsets, self.reqs)]
+        self.last_requests = [r for _, r in pending]
         t0 = time.perf_counter()
 
         def _add_due():
@@ -356,6 +360,8 @@ def main(argv=None):
     ap.add_argument("--repeats", type=int, default=1,
                     help="measured passes (best wall reported)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     mesh = None
     if args.mesh:

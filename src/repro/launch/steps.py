@@ -176,10 +176,8 @@ def make_step(arch_id: str, shape_name: str, mesh: Mesh,
               num_microbatches: Optional[int] = None) -> StepBundle:
     kctx = None
     if coopt.use_kernel:
-        # Pallas kernels run compiled on TPU, interpret-mode elsewhere;
         # a mesh with sharded pages axes gets the shard_map kernel layer
         from repro.kernels import ops
-        ops.configure_for_backend()
         kctx = ops.make_mesh_ctx(mesh)
     cfg = get_config(arch_id)
     shape = get_shape(shape_name)

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.coopt import CoOptConfig, COOPT
 from repro.core.opt_kv import (identity_page_table, identity_slots,
-                               pool_layout, write_kv)
+                               kv_pool_shapes, write_kv)
 from repro.core.opt_pa import paged_chunk_attention, paged_decode_attention
 from repro.models.layers import (Spec, apply_rope, causal_attention, init_tree,
                                  linear, repeat_kv, rmsnorm, shard_act)
@@ -389,22 +389,16 @@ class GriffinModel:
         # transformer.TransformerModel.cache_shape), pages padded to tile
         # over the KV shards; recurrent state (conv taps, RG-LRU h) is O(1)
         # per lane and stays batch-major.
-        P, ps = pool_layout(batch, max_len, coopt, num_shards, cache_cfg)
         Hkv, D, W = cfg.num_kv_heads, cfg.head_dim, cfg.lru_width
         out = {
             "conv": ((self.n_rec, batch, cfg.conv1d_width - 1, W), jnp.bfloat16,
                      ("layers", "batch", None, "d_model")),
             "lru": ((self.n_rec, batch, W), jnp.float32,
                     ("layers", "batch", "d_model")),
-            "kv": ((self.n_attn, 2, P, ps, Hkv, D), coopt.kv_dtype,
-                   ("layers", None, "pages", None, "kv_heads",
-                    "head_dim")),
+            **kv_pool_shapes(self.n_attn, batch, max_len, Hkv, D, coopt,
+                             num_shards, cache_cfg),
             "length": ((batch,), jnp.int32, ("batch",)),
         }
-        if coopt.opt_kv:
-            out["scale"] = ((self.n_attn, 2, P, ps, Hkv), jnp.float32,
-                            ("layers", None, "pages", None,
-                             "kv_heads"))
         return out
 
     def init_cache(self, batch: int, max_len: int, coopt: CoOptConfig,

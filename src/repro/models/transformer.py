@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.coopt import CoOptConfig, COOPT
 from repro.core.opt_kv import (identity_page_table, identity_slots,
-                               pool_layout, write_kv)
+                               kv_pool_shapes, pool_layout, write_kv)
 from repro.core.opt_pa import paged_chunk_attention, paged_decode_attention
 from repro.models import mla as mla_mod
 from repro.models.layers import (Spec, apply_rope, causal_attention, init_tree,
@@ -285,8 +285,7 @@ class TransformerModel:
         (CACHE_RULES: pages -> (pod, data)). A ``CacheConfig`` overrides
         the pool size / page size / shard count (opt_kv.pool_layout is the
         shared sizing rule). Direct callers fall back to the static
-        lane-identity partition; the engine reserves the final page so its
-        last line can serve as the Pallas write kernel's SkipSet sentinel.
+        lane-identity partition; the engine reserves the final page.
         ``length`` stays per-lane."""
         cfg = self.cfg
         P, ps = pool_layout(batch, max_len, coopt, num_shards, cache_cfg)
@@ -303,16 +302,9 @@ class TransformerModel:
                                 jnp.float32,
                                 ("layers", "pages", None, None))
         else:
-            Hkv, D = cfg.num_kv_heads, cfg.head_dim
-            out["kv"] = ((cfg.num_layers, 2, P, ps, Hkv, D),
-                         coopt.kv_dtype,
-                         ("layers", None, "pages", None, "kv_heads",
-                          "head_dim"))
-            if coopt.opt_kv:
-                out["scale"] = ((cfg.num_layers, 2, P, ps, Hkv),
-                                jnp.float32,
-                                ("layers", None, "pages", None,
-                                 "kv_heads"))
+            out.update(kv_pool_shapes(cfg.num_layers, batch, max_len,
+                                      cfg.num_kv_heads, cfg.head_dim, coopt,
+                                      num_shards, cache_cfg))
         out["length"] = ((batch,), jnp.int32, ("batch",))
         return out
 

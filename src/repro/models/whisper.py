@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.coopt import CoOptConfig, COOPT
 from repro.core.opt_kv import (identity_page_table, identity_slots,
-                               pool_layout, write_kv)
+                               kv_pool_shapes, write_kv)
 from repro.core.opt_pa import paged_chunk_attention, paged_decode_attention
 from repro.cache.quant import quantize_fp8, dequantize_fp8
 from repro.models.layers import (Spec, causal_attention, gelu_mlp, init_tree,
@@ -337,16 +337,14 @@ class WhisperModel:
     def cache_shape(self, batch: int, max_len: int, coopt: CoOptConfig,
                     num_shards: int = 1, cache_cfg=None):
         cfg = self.cfg
-        P, ps = pool_layout(batch, max_len, coopt, num_shards, cache_cfg)
         L, H, D, F = cfg.num_layers, cfg.num_heads, cfg.head_dim, \
             cfg.num_frames
         out = {
             # decoder self-attn KV: GLOBAL pool (no batch dim); cross-attn
             # K/V are static per-lane encoder projections and stay
             # batch-major (quantized once — DESIGN.md §5).
-            "kv": ((L, 2, P, ps, H, D), coopt.kv_dtype,
-                   ("layers", None, "pages", None, "kv_heads",
-                    "head_dim")),
+            **kv_pool_shapes(L, batch, max_len, H, D, coopt, num_shards,
+                             cache_cfg),
             "xk": ((L, batch, F, H, D), coopt.kv_dtype,
                    ("layers", "batch", None, "kv_heads", "head_dim")),
             "xv": ((L, batch, F, H, D), coopt.kv_dtype,
@@ -354,9 +352,6 @@ class WhisperModel:
             "length": ((batch,), jnp.int32, ("batch",)),
         }
         if coopt.opt_kv:
-            out["scale"] = ((L, 2, P, ps, H), jnp.float32,
-                            ("layers", None, "pages", None,
-                             "kv_heads"))
             out["xscale"] = ((L, 2, batch, F, H), jnp.float32,
                              ("layers", None, "batch", None, "kv_heads"))
         return out

@@ -8,12 +8,12 @@ loaded, per-head KV expansion) and each Opt-* flag turns on one technique,
 so Figs. 6-7's five modes are one constructor argument apart.
 
 Design (hardware adaptation, DESIGN.md §3): the device cache is a GLOBAL
-paged pool — per-layer leaves ``(2, P_total, ps, Hkv, D)`` with no batch
+paged pool — per-layer leaves ``(2, P_total, Hkv, ps, D)`` with no batch
 dimension, ``P_total = num_lanes * pages(max_len)`` padded to tile evenly
-over ``num_shards`` KV shards (the final page reserved as the write
-kernel's SkipSet sentinel). The pool's page range is partitioned along the
-mesh ``(pod, data)`` axes — the axes CACHE_RULES shard the pages axis over —
-and every request is pinned to ONE shard at admission, so its page gathers
+over ``num_shards`` KV shards (the final page reserved). The pool's page
+range is partitioned along the mesh ``(pod, data)`` axes — the axes
+CACHE_RULES shard the pages axis over — and every request is pinned to
+ONE shard at admission, so its page gathers
 stay shard-local. All dynamic paging state (per-shard free lists, refcounts,
 per-shard prefix-cache hash tables, slot indices, SkipSets) lives host-side
 in the Scheduler/BlockManager; the device sees only static-shape index
@@ -93,6 +93,22 @@ def _write_pool_page_q(leaf, q, scale, page, axis: int):
     during the staging write."""
     data = dequantize_fp8(q, scale, axis=-1, dtype=leaf.dtype)
     return lax.dynamic_update_index_in_dim(leaf, data, page, axis)
+
+
+def place_cache(cache, shapes, mesh, use_kernel: bool):
+    """Shard the device cache leaves onto the mesh (``shapes``: the model's
+    ``cache_shape``): the kernel path partitions the pool ONLY along its
+    pages axes (the shard_map layer's layout — heads/latent replicated);
+    the jnp reference path uses the full CACHE_RULES (GSPMD handles the
+    rest)."""
+    from jax.sharding import NamedSharding
+    from repro.launch.steps import (CACHE_RULES, KERNEL_CACHE_RULES,
+                                    axes_pspec)
+    rules = KERNEL_CACHE_RULES if use_kernel else CACHE_RULES
+    return {k: jax.device_put(
+                leaf, NamedSharding(mesh, axes_pspec(
+                    shapes[k][0], shapes[k][2], mesh, rules)))
+            for k, leaf in cache.items()}
 
 
 @dataclass
@@ -360,7 +376,8 @@ class Engine:
         (``launch.mesh.kv_shard_count``) — a default ``num_shards=1`` config
         is upgraded to match, and a conflicting explicit value raises (the
         host page ranges and the device pages-axis partition must coincide).
-        The cache leaves are placed on the mesh, and with
+        The parameters are placed on the mesh once, replicated; the cache
+        leaves are sharded along their pages axis, and with
         ``coopt.use_kernel`` the pooled Pallas kernels run through the
         ``kernels.sharded`` shard_map layer — one kernel hot path, single-
         host and distributed."""
@@ -393,6 +410,12 @@ class Engine:
         self.model = get_model(model_cfg)
         if params is None:
             params = self.model.init(jax.random.PRNGKey(engine_cfg.seed))
+        if mesh is not None:
+            # once, here: uncommitted weights would be copied to the mesh
+            # again by every step
+            from jax.sharding import NamedSharding, PartitionSpec
+            params = jax.device_put(params,
+                                    NamedSharding(mesh, PartitionSpec()))
         self.params = params
         self.key = jax.random.PRNGKey(engine_cfg.seed + 1)
 
@@ -456,9 +479,11 @@ class Engine:
         if ccfg.host_pages > 0 and self._pool_axis:
             try:
                 self._host_dev = jax.devices("cpu")[0]
-            except RuntimeError:
-                self._host_dev = None   # no CPU backend: keep pages where
-                                        # device_put default places them
+            except RuntimeError as e:
+                raise RuntimeError(
+                    "CacheConfig.host_pages needs JAX's CPU backend to hold "
+                    "spilled pages in host memory; none is available (is "
+                    "JAX_PLATFORMS set without 'cpu'?)") from e
             mgr = self.scheduler.manager
             mgr.spill_sink = self._spill_page
             self.scheduler.prefetcher = self._start_prefetch
@@ -502,23 +527,11 @@ class Engine:
 
     # ------------------------------------------------------- mesh placement --
     def _place_cache(self, cache, mesh):
-        """Shard the device cache leaves onto the mesh: the kernel path
-        partitions the pool ONLY along its pages axes (the shard_map
-        layer's layout — heads/latent replicated); the jnp reference path
-        uses the full CACHE_RULES (GSPMD handles the rest)."""
-        from jax.sharding import NamedSharding
-        from repro.launch.steps import (CACHE_RULES, KERNEL_CACHE_RULES,
-                                        axes_pspec)
-        rules = (KERNEL_CACHE_RULES if self.coopt.use_kernel
-                 else CACHE_RULES)
         shapes = self.model.cache_shape(self.ecfg.num_lanes,
                                         self.ecfg.max_len, self.coopt,
                                         num_shards=self.ecfg.num_shards,
                                         cache_cfg=self.ccfg)
-        return {k: jax.device_put(
-                    leaf, NamedSharding(mesh, axes_pspec(
-                        shapes[k][0], shapes[k][2], mesh, rules)))
-                for k, leaf in cache.items()}
+        return place_cache(cache, shapes, mesh, self.coopt.use_kernel)
 
     # ---------------------------------------------------------- jit bodies --
     def _mask_lanes(self, new_cache, old_cache, lane_mask):

@@ -143,9 +143,9 @@ class Scheduler:
         self.pages_per_lane = \
             (max_len + self.page_size - 1) // self.page_size
         # ONE pool for all lanes, page range padded so it tiles evenly over
-        # the shards; the final device page is reserved so its last line can
-        # serve as the Pallas write kernel's SkipSet sentinel (it belongs to
-        # the LAST shard's device range, which therefore owns one page less).
+        # the shards; the final device page is reserved (it once took the
+        # write kernel's skipped tokens; it belongs to the LAST shard's
+        # device range, which therefore owns one page less).
         self.cache_cfg = cache_cfg.resolve(
             page_size=self.page_size,
             num_pages=num_lanes * self.pages_per_lane)

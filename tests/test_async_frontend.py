@@ -13,13 +13,11 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.coopt import MODES
-from repro.kernels import ops
 from repro.serving import AsyncEngine, Engine, EngineConfig, Request
 from repro.serving.request import RequestState
 from repro.serving.sampler import SamplingParams
 
 CFG = get_config("qwen3-4b-reduced")
-ops.configure_for_backend()
 
 
 def _engine(num_lanes=4, max_len=128, pack=False, seed=0):

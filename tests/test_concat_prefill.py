@@ -11,14 +11,12 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.coopt import MODES
-from repro.kernels import ops
 from repro.serving import Engine, EngineConfig, Request
 from repro.serving.sampler import SamplingParams
 from repro.serving.scheduler import (PackedRow, PrefillChunk, chunk_pages,
                                      pack_rows)
 
 CFG = get_config("qwen3-4b-reduced")
-ops.configure_for_backend()
 
 
 def _engine(pack, use_kernel=False, num_lanes=4, seed=0):

@@ -55,8 +55,8 @@ def _paged(B=2, P=8, ps=16, Hq=8, Hkv=2, D=32, opt_kv=False, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     PT = B * P
     q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
-    k = jax.random.normal(ks[1], (PT, ps, Hkv, D), jnp.float32)
-    v = jax.random.normal(ks[2], (PT, ps, Hkv, D), jnp.float32)
+    k = jax.random.normal(ks[1], (PT, Hkv, ps, D), jnp.float32)
+    v = jax.random.normal(ks[2], (PT, Hkv, ps, D), jnp.float32)
     if opt_kv:
         kq, ksc = quantize_fp8(k)
         vq, vsc = quantize_fp8(v)
@@ -134,11 +134,11 @@ def test_permuted_page_table_matches_contiguous():
     B, P, ps, Hq, Hkv, D = 1, 4, 16, 4, 2, 32
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
-    pages_k = jax.random.normal(ks[1], (P, ps, Hkv, D), jnp.float32)
-    pages_v = jax.random.normal(ks[2], (P, ps, Hkv, D), jnp.float32)
+    pages_k = jax.random.normal(ks[1], (P, Hkv, ps, D), jnp.float32)
+    pages_v = jax.random.normal(ks[2], (P, Hkv, ps, D), jnp.float32)
     perm = [2, 0, 3, 1]                       # physical placement
-    scat_k = jnp.zeros((8, ps, Hkv, D)).at[jnp.array(perm)].set(pages_k)
-    scat_v = jnp.zeros((8, ps, Hkv, D)).at[jnp.array(perm)].set(pages_v)
+    scat_k = jnp.zeros((8, Hkv, ps, D)).at[jnp.array(perm)].set(pages_k)
+    scat_v = jnp.zeros((8, Hkv, ps, D)).at[jnp.array(perm)].set(pages_v)
     cl = jnp.array([P * ps], jnp.int32)
     a = paged_decode_attention(
         q, jnp.stack([pages_k, pages_v]).astype(jnp.bfloat16), None, cl,
@@ -178,9 +178,9 @@ def test_window_policy_drops_middle_tokens():
     """With a small window, only {sink + recent window} tokens attend."""
     B, P, ps, Hq, Hkv, D = 1, 8, 16, 4, 1, 32
     q = jnp.ones((B, Hq, D), jnp.float32)
-    k = jnp.zeros((P, ps, Hkv, D))
+    k = jnp.zeros((P, Hkv, ps, D))
     # middle token with huge key would dominate IF not skipped
-    k = k.at[3, 0].set(100.0)
+    k = k.at[3, :, 0].set(100.0)
     v = jnp.ones_like(k)
     kv = jnp.stack([k, v]).astype(jnp.bfloat16)
     cl = jnp.array([128], jnp.int32)

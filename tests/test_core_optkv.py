@@ -24,7 +24,9 @@ def test_skipset_negative_slots_never_written():
     # lanes write DISJOINT global slots (refcounted pool invariant)
     slots = jnp.array([[0, -1, 2, -1, 4], [-1, 33, -1, 35, -1]], jnp.int32)
     kv2, sc2 = write_kv(kv, sc, k, v, slots, OPT_KV)
-    flat = np.asarray(kv2.reshape(2, -1, 2, 16).astype(jnp.float32))
+    # (2, P, H, ps, D) -> flat token lines (2, P*ps, H, D)
+    flat = np.asarray(jnp.swapaxes(kv2, 2, 3).reshape(2, -1, 2, 16)
+                      .astype(jnp.float32))
     # skipped slots stay zero
     assert np.all(flat[:, 1] == 0) and np.all(flat[:, 3] == 0)
     assert np.all(flat[:, 32] == 0) and np.all(flat[:, 34] == 0)

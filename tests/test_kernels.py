@@ -1,7 +1,7 @@
 """Per-kernel allclose sweeps vs the pure-jnp oracles (ref.py), across
 shapes, dtypes and mode flags — interpret=True on CPU. Layouts follow the
-GLOBAL paged pool (no batch dim on kv pages; lanes address the pool through
-scalar-prefetched page tables)."""
+GLOBAL paged pool (no batch dim on kv pages, heads before tokens within a
+page; lanes address the pool through scalar-prefetched page tables)."""
 import itertools
 
 import jax
@@ -23,8 +23,8 @@ def _pool_inputs(B, P, ps, Hkv, G, D, opt_kv, seed=0):
     Hq = Hkv * G
     PT = B * P
     q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32).astype(jnp.bfloat16)
-    k = jax.random.normal(ks[1], (PT, ps, Hkv, D), jnp.float32)
-    v = jax.random.normal(ks[2], (PT, ps, Hkv, D), jnp.float32)
+    k = jax.random.normal(ks[1], (PT, Hkv, ps, D), jnp.float32)
+    v = jax.random.normal(ks[2], (PT, Hkv, ps, D), jnp.float32)
     phys = identity_page_table(B, PT)
     log = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None], (B, P))
     if opt_kv:
@@ -36,6 +36,12 @@ def _pool_inputs(B, P, ps, Hkv, G, D, opt_kv, seed=0):
 
 def _scales(sc):
     return (sc[0], sc[1]) if sc is not None else (None, None)
+
+
+def _flat(pages):
+    """One pool plane (P, Hkv, ps[, D]) -> flat token lines (P*ps, Hkv[, D])."""
+    P, Hkv, ps = pages.shape[:3]
+    return jnp.swapaxes(pages, 1, 2).reshape((P * ps, Hkv) + pages.shape[3:])
 
 
 # ---------------------------------------------------------------------------
@@ -118,37 +124,37 @@ def test_cache_write_sweep(opt_kv, Hkv, D):
     slots = jnp.array([[0, 5, -1, 17, 33, -1, 62, 2],
                        [64, -1, 73, 74, 75, 104, -1, 125]], jnp.int32)
     dt = jnp.float8_e4m3fn if opt_kv else jnp.bfloat16
-    kv_c = jnp.zeros((2, P, ps, Hkv, D), dt)
-    sc_c = jnp.zeros((2, P, ps, Hkv), jnp.float32) if opt_kv else None
+    kv_c = jnp.zeros((2, P, Hkv, ps, D), dt)
+    sc_c = jnp.zeros((2, P, Hkv, ps), jnp.float32) if opt_kv else None
     kv2, sc2 = ops.kv_cache_write(kv_c, sc_c, kn, vn, slots, opt_kv=opt_kv)
 
     NS = P * ps
-    flat_k = kv_c[0].reshape(NS, Hkv, D)
-    flat_v = kv_c[1].reshape(NS, Hkv, D)
     zeros_s = jnp.zeros((NS, Hkv))
     ek, ev, esk, esv = ref.kv_cache_write_ref(
-        kn, vn, slots, flat_k, flat_v, zeros_s, zeros_s, opt_kv=opt_kv)
-    got = np.asarray(kv2[0].reshape(NS, Hkv, D)[:NS - 1], np.float32)
-    expd = np.asarray(ek[:NS - 1], np.float32)
+        kn, vn, slots, _flat(kv_c[0]), _flat(kv_c[1]), zeros_s, zeros_s,
+        opt_kv=opt_kv)
+    # every line, the last included: SkipSet tokens write nowhere
+    got = np.asarray(_flat(kv2[0]), np.float32)
+    expd = np.asarray(ek, np.float32)
     # fp8 e4m3 (3-bit mantissa): allow 1 ULP rounding skew vs the oracle
     tol = np.maximum(np.abs(expd), 1.0) * 2.0 ** -3 + 1e-6
     assert np.all(np.abs(got - expd) <= tol)
     if opt_kv:
-        np.testing.assert_allclose(
-            np.asarray(sc2[0].reshape(NS, Hkv)[:NS - 1]),
-            np.asarray(esk[:NS - 1]), atol=1e-7)
+        np.testing.assert_allclose(np.asarray(_flat(sc2[0])),
+                                   np.asarray(esk), atol=1e-7)
 
 
 def test_cache_write_preserves_other_lines():
-    """Aliasing semantics: unwritten cache lines keep their old contents."""
+    """Aliasing semantics: unwritten cache lines keep their old contents,
+    and a SkipSet token writes no line at all."""
     B, S, Hkv, D, P, ps = 1, 2, 1, 64, 2, 8
-    old = jnp.full((2, P, ps, Hkv, D), 7.0, jnp.bfloat16)
+    old = jnp.full((2, P, Hkv, ps, D), 7.0, jnp.bfloat16)
     kn = jnp.ones((B, S, Hkv, D), jnp.bfloat16)
     slots = jnp.array([[3, -1]], jnp.int32)
     kv2, _ = ops.kv_cache_write(old, None, kn, kn, slots, opt_kv=False)
-    flat = np.asarray(kv2[0].reshape(P * ps, Hkv, D), np.float32)
+    flat = np.asarray(_flat(kv2[0]), np.float32)
     assert np.all(flat[3] == 1.0)
-    untouched = [i for i in range(P * ps - 1) if i != 3]
+    untouched = [i for i in range(P * ps) if i != 3]
     assert np.all(flat[untouched] == 7.0)
 
 

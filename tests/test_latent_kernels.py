@@ -1,8 +1,8 @@
 """Fused MLA latent-attention kernels (absorbed decode + chunk prefill off
 the global FP8 latent pool) — parity sweeps vs the naive oracle AND vs the
 jnp model path they replace, across {fp8, bf16} x {windowed, dense} x ragged
-page tables with -1 holes; plus the launcher configure_for_backend wiring.
-interpret=True on CPU."""
+page tables with -1 holes; plus the rule that the platform, not a launcher,
+decides interpret mode. interpret=True on CPU."""
 import math
 
 import jax
@@ -151,39 +151,54 @@ def test_mla_chunk_attention_dispatch_parity(fp8, window):
                                np.asarray(b, np.float32), atol=2e-2)
 
 
-# --------------------------------------------------- backend configuration --
+# ------------------------------------------------ interpret mode by platform --
 def test_configure_for_backend_flips_interpret(monkeypatch):
-    """Under a (faked) TPU backend the launchers' configure_for_backend()
-    call must flip interpret mode OFF; any other backend keeps it on."""
-    monkeypatch.setattr(ops, "INTERPRET", ops.INTERPRET)  # restore on exit
+    """Interpret mode follows the backend at every dispatch: compiled
+    kernels under a (faked) TPU backend, the interpreter anywhere else —
+    with nothing to configure in between."""
+    seen = []
+
+    def spy(q, k, v, **kw):
+        seen.append(kw["interpret"])
+        return q
+
+    monkeypatch.setattr(ops, "_flash_prefill_single", spy)
+    x = jnp.zeros((1, 8, 2, 128), jnp.bfloat16)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    ops.configure_for_backend()
-    assert ops.INTERPRET is False
+    assert ops.interpret_mode() is False
+    ops.flash_prefill(x, x, x)
     monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
-    ops.configure_for_backend()
-    assert ops.INTERPRET is True
+    assert ops.interpret_mode() is True
+    ops.flash_prefill(x, x, x)
+    assert seen == [False, True]
 
 
 def test_launchers_call_configure_for_backend(monkeypatch):
-    """serve_workload, make_step (use_kernel engine setup) and
-    benchmarks.run must all invoke ops.configure_for_backend — the module
-    docstring promised it; now the launchers actually do it."""
-    calls = []
-    monkeypatch.setattr(ops, "configure_for_backend",
-                        lambda: calls.append(1))
+    """No launcher has to switch interpret mode off: an ``Engine`` built
+    directly (as examples and scripts do) dispatches every pooled kernel
+    compiled under a TPU backend. The faked backend only steers the
+    dispatch; the spies still run the kernels interpreted on this CPU."""
+    from repro.serving import Engine, EngineConfig
+    seen = []
 
-    from repro.launch.serve import serve_workload
-    serve_workload("qwen3-4b-reduced", "original", requests=1, num_lanes=1,
-                   max_len=64, max_new_tokens=1)
-    assert len(calls) == 1
+    def spying(name):
+        real = getattr(ops, name)
 
-    from repro.launch.mesh import make_host_mesh
-    from repro.launch.steps import make_step
-    from repro.core.coopt import COOPT
-    make_step("qwen3-4b-reduced", "decode_32k", make_host_mesh(),
-              COOPT.replace(use_kernel=True))
-    assert len(calls) == 2
+        def spy(*a, **kw):
+            seen.append((name, kw["interpret"]))
+            return real(*a, **{**kw, "interpret": True})
+        monkeypatch.setattr(ops, name, spy)
 
-    from benchmarks.run import main
-    main(["--only", "nosuchbench"])
-    assert len(calls) == 3
+    for name in ("_paged_chunk_prefill_single", "_paged_pool_decode_single",
+                 "_kv_cache_write_single"):
+        spying(name)
+    cfg = get_config("qwen3-4b-reduced")
+    eng = Engine(cfg, MODES["coopt"].replace(use_kernel=True),
+                 EngineConfig(num_lanes=1, max_len=64,
+                              prefill_buckets=(16,)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng.generate([np.arange(1, 9, dtype=np.int32)], max_new_tokens=2)
+    assert {n for n, _ in seen} == {"_paged_chunk_prefill_single",
+                                    "_paged_pool_decode_single",
+                                    "_kv_cache_write_single"}
+    assert not any(interp for _, interp in seen)

@@ -22,7 +22,6 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.coopt import MODES
-from repro.kernels import ops
 from repro.serving import (AsyncEngine, Engine, EngineConfig, FaultInjector,
                            FaultPlan, FinishReason, PipelineStallError,
                            Request, TokenStream)
@@ -31,7 +30,6 @@ from repro.serving.request import RequestState
 from repro.serving.sampler import SamplingParams
 
 CFG = get_config("qwen3-4b-reduced")
-ops.configure_for_backend()
 
 
 def _engine(num_lanes=4, max_len=128, seed=0, **kw):

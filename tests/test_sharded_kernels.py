@@ -80,15 +80,14 @@ def test_unsharded_mesh_is_identical_code_path():
 
 
 def test_configure_for_backend_composes_with_mesh_dispatch(monkeypatch):
-    """``configure_for_backend()`` (the launchers' interpret-mode switch)
-    and the mesh ctx dispatch compose: whatever INTERPRET resolves to is
-    forwarded into the shard_map layer, and with no ctx the single-device
-    wrapper runs instead — same flag, one dispatch point."""
+    """The platform's interpret rule (``ops.interpret_mode``) and the mesh
+    ctx dispatch compose: what the backend implies is forwarded into the
+    shard_map layer, and with no ctx the single-device wrapper runs instead
+    — same rule, one dispatch point."""
     import jax as _jax
     from repro.kernels import sharded as _sh
 
     seen = {}
-    monkeypatch.setattr(ops, "INTERPRET", ops.INTERPRET)  # restore on exit
     monkeypatch.setattr(
         ops._sh, "paged_pool_decode",
         lambda ctx, *a, **kw: seen.update(ctx=ctx, **kw) or "sharded")
@@ -96,8 +95,7 @@ def test_configure_for_backend_composes_with_mesh_dispatch(monkeypatch):
         ops, "_paged_pool_decode_single", lambda *a, **kw: "single")
 
     monkeypatch.setattr(_jax, "default_backend", lambda: "tpu")
-    ops.configure_for_backend()
-    assert ops.INTERPRET is False
+    assert ops.interpret_mode() is False
     ctx = _sh.ShardCtx(mesh=None, axes=("data",), num_shards=2)  # dummy
     ops.set_mesh_ctx(ctx)
     args = (jnp.zeros((1, 2, 4)), jnp.zeros((2, 4, 2, 2, 4)), None,
@@ -108,7 +106,7 @@ def test_configure_for_backend_composes_with_mesh_dispatch(monkeypatch):
     assert seen["ctx"] is ctx and seen["interpret"] is False
 
     monkeypatch.setattr(_jax, "default_backend", lambda: "cpu")
-    ops.configure_for_backend()
+    assert ops.interpret_mode() is True
     ops.set_mesh_ctx(None)
     assert ops.paged_pool_decode(*args, opt_kv=False, opt_gqa=True) \
         == "single"
@@ -157,7 +155,7 @@ def test_sharded_decode_kernel_matches_jnp_reference(mesh, opt_kv_on):
     B, Hq, Hkv, D, ps, P_total = 2, 8, 4, 128, 8, 16
     coopt = COOPT.replace(opt_kv=opt_kv_on, use_kernel=False)
     kv = (jax.random.normal(jax.random.PRNGKey(1),
-                            (2, P_total, ps, Hkv, D), jnp.float32) * 0.3)
+                            (2, P_total, Hkv, ps, D), jnp.float32) * 0.3)
     scale = None
     if opt_kv_on:
         from repro.cache.quant import quantize_fp8
@@ -192,7 +190,7 @@ def test_sharded_visit_grid_shard_local_and_matches_reference(mesh):
     from repro.cache.quant import quantize_fp8
     coopt = COOPT.replace(opt_kv=True, use_kernel=False)
     kv = (jax.random.normal(jax.random.PRNGKey(1),
-                            (2, P_total, ps, Hkv, D), jnp.float32) * 0.3)
+                            (2, P_total, Hkv, ps, D), jnp.float32) * 0.3)
     kv, scale = quantize_fp8(kv, axis=-1)
     q = jax.random.normal(jax.random.PRNGKey(0), (B, Hq, D), jnp.float32)
     # prefix pages 0 and 9 shared by ALL lanes (they land in different
@@ -226,7 +224,7 @@ def test_sharded_chunk_kernel_matches_jnp_reference(mesh):
     B, S, Hq, Hkv, D, ps, P_total = 2, 4, 8, 4, 128, 8, 16
     coopt = COOPT.replace(opt_kv=False, use_kernel=False)
     kv = (jax.random.normal(jax.random.PRNGKey(1),
-                            (2, P_total, ps, Hkv, D), jnp.float32) * 0.3)
+                            (2, P_total, Hkv, ps, D), jnp.float32) * 0.3)
     q = jax.random.normal(jax.random.PRNGKey(3), (B, S, Hq, D), jnp.float32)
     positions = jnp.stack([jnp.arange(33, 37),
                            jnp.arange(86, 90)]).astype(jnp.int32)
@@ -247,7 +245,7 @@ def test_sharded_write_stays_shard_local_and_drops_foreign_slots(mesh):
     dropped, never wrapped), matching the global jnp write bit-for-bit."""
     B, Hkv, D, ps, P_total = 2, 4, 16, 8, 16
     kv = (jax.random.normal(jax.random.PRNGKey(1),
-                            (2, P_total, ps, Hkv, D), jnp.float32))
+                            (2, P_total, Hkv, ps, D), jnp.float32))
     k_new = jnp.full((B, 1, Hkv, D), 7.0)
     v_new = jnp.full((B, 1, Hkv, D), 9.0)
     # one mid-pool slot + one SkipSet (-1) token
@@ -257,15 +255,16 @@ def test_sharded_write_stays_shard_local_and_drops_foreign_slots(mesh):
     ops.set_mesh_ctx(ops.make_mesh_ctx(mesh))
     out, _ = ops.kv_cache_write(_sharded_pool(mesh, kv, 1), None,
                                 k_new, v_new, slots, opt_kv=False)
-    # every LIVE line matches the global jnp write bit-for-bit; the global
-    # jnp write parks the -1 token in the reserved sentinel (last) line,
-    # the shard-local write simply DROPS it — assert the sentinel is the
-    # only divergence and that no mid-shard line absorbed the skip
-    o = np.asarray(out).reshape(2, P_total * ps, Hkv, D)
-    r = np.asarray(ref).reshape(2, P_total * ps, Hkv, D)
-    np.testing.assert_array_equal(o[:, :-1], r[:, :-1])
-    np.testing.assert_array_equal(
-        o[:, -1], np.asarray(kv).reshape(2, P_total * ps, Hkv, D)[:, -1])
+    # both writes DROP the -1 token: the pools match bit-for-bit, and no
+    # line — the last one included — absorbed the skip
+    def lines(pool):        # (2, P, Hkv, ps, D) -> (2, P*ps, Hkv, D)
+        return np.asarray(pool).swapaxes(2, 3).reshape(2, P_total * ps,
+                                                       Hkv, D)
+    o, r = lines(out), lines(ref)
+    np.testing.assert_array_equal(o, r)
+    keep = np.ones(P_total * ps, bool)
+    keep[37] = False
+    np.testing.assert_array_equal(o[:, keep], lines(kv)[:, keep])
 
 
 # ---------------------------------------------------- engine greedy parity --

@@ -44,8 +44,8 @@ def _gqa_inputs(B, P, shared, ps, Hkv, G, D, opt_kv, seed=0):
     phys, log, PT = _shared_tables(B, P, shared)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, Hkv * G, D)).astype(jnp.bfloat16)
-    k = jax.random.normal(ks[1], (PT, ps, Hkv, D), jnp.float32)
-    v = jax.random.normal(ks[2], (PT, ps, Hkv, D), jnp.float32)
+    k = jax.random.normal(ks[1], (PT, Hkv, ps, D), jnp.float32)
+    v = jax.random.normal(ks[2], (PT, Hkv, ps, D), jnp.float32)
     if opt_kv:
         kq, ksc = quantize_fp8(k)
         vq, vsc = quantize_fp8(v)
@@ -213,24 +213,25 @@ def test_chunk_prefill_multi_resident_block_parity():
     from repro.kernels.flash_chunk_prefill import (flash_chunk_prefill,
                                                    resident_rows)
 
-    B, P, ps, Hkv, G, D, S = 2, 4, 16, 2, 4, 64, 8
+    B, P, ps, Hkv, G, D, S = 2, 4, 16, 2, 4, 64, 64
     q = jax.random.normal(jax.random.PRNGKey(7),
                           (B, S, Hkv * G, D)).astype(jnp.bfloat16)
     phys = identity_page_table(B, B * P)
-    k = jax.random.normal(jax.random.PRNGKey(8), (B * P, ps, Hkv, D),
+    k = jax.random.normal(jax.random.PRNGKey(8), (B * P, Hkv, ps, D),
                           jnp.float32)
-    v = jax.random.normal(jax.random.PRNGKey(9), (B * P, ps, Hkv, D),
+    v = jax.random.normal(jax.random.PRNGKey(9), (B * P, Hkv, ps, D),
                           jnp.float32)
     kq, ksc = quantize_fp8(k)
     vq, vsc = quantize_fp8(v)
-    positions = jnp.stack([jnp.arange(24, 32),
-                           jnp.arange(56, 64)]).astype(jnp.int32)
+    positions = jnp.stack([jnp.arange(0, 64),
+                           jnp.arange(0, 64) // 2 + 32]).astype(jnp.int32)
     R = S * G
-    assert resident_rows(R, G, G) == G and R // G > 1   # forces NQ > 1
+    # row groups are 128-aligned (Mosaic lane tiling of the positions block)
+    assert resident_rows(R, G, 128) == 128 and R // 128 > 1  # forces NQ > 1
     tiled = flash_chunk_prefill(q, positions, kq, vq, ksc, vsc, phys,
-                                opt_kv=True, block_q=G)
+                                opt_kv=True, block_q=128, interpret=True)
     whole = flash_chunk_prefill(q, positions, kq, vq, ksc, vsc, phys,
-                                opt_kv=True)
+                                opt_kv=True, interpret=True)
     np.testing.assert_array_equal(np.asarray(tiled), np.asarray(whole))
     exp = paged_chunk_attention(
         q, jnp.stack([kq, vq]), jnp.stack([ksc, vsc]), positions, phys,
@@ -256,9 +257,10 @@ def test_latent_chunk_multi_resident_block_parity():
     RW = S * H
     assert resident_rows(RW, H, H) == H and RW // H > 1   # forces NQ > 1
     tiled = latent_chunk_prefill(ql, qr, positions, lat, sc, phys,
-                                 sm_scale=sm, opt_kv=True, block_q=H)
+                                 sm_scale=sm, opt_kv=True, block_q=H,
+                                 interpret=True)
     whole = latent_chunk_prefill(ql, qr, positions, lat, sc, phys,
-                                 sm_scale=sm, opt_kv=True)
+                                 sm_scale=sm, opt_kv=True, interpret=True)
     np.testing.assert_array_equal(np.asarray(tiled), np.asarray(whole))
     exp = ref.latent_chunk_prefill_ref(ql, qr, positions, lat, sc, phys,
                                        sm_scale=sm, opt_kv=True)
