@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.cache.block_manager import (OutOfBlocks, PageResidency,
                                        PrefixMatch, chain_hash_tokens,
@@ -63,6 +64,7 @@ from repro.kernels.visits import sharing_stats
 from repro.configs.base import CacheConfig, ModelConfig
 from repro.core.coopt import CoOptConfig, COOPT
 from repro.models import get_model
+from repro.serving import steplog
 from repro.serving.request import FinishReason, Request, RequestState
 from repro.serving.sampler import SamplingParams, sample
 from repro.serving.scheduler import (DecodeItem, PrefillChunk, Scheduler,
@@ -365,6 +367,7 @@ class StepBatch:
     feed: np.ndarray               # (R,) int32 col-0 token source
     row_lane: np.ndarray           # (R,) int32 lane backing each row
     scatter_lane: np.ndarray       # (n_slots,) int32 lane per sample slot
+    record: Optional[steplog.StepRecord] = None   # set once dispatched
 
 
 class Engine:
@@ -498,19 +501,26 @@ class Engine:
         self._decode_fn = jax.jit(self._decode_impl, donate_argnums=(2,))
         self._packed_fn = jax.jit(self._prefill_packed_impl,
                                   donate_argnums=(2,))
-        # async two-stage pipeline step (sample-on-device); lazily traced,
+        # async two-stage pipeline step (sample-on-device), one jit per
+        # step kind so each kind's program carries its own name in a
+        # device trace (jit_serve_step_<kind>); lazily traced,
         # AOT-compiled by AsyncEngine.warmup over the step-shape lattice.
         # lane_tok (argnum 4) is donated too — it is device-resident state
         # owned by the pipeline, rebound on every dispatch.
-        self._async_fn = jax.jit(self._async_step_impl,
-                                 static_argnames=("kind",),
-                                 donate_argnums=(2, 4))
+        self._step_fns = {
+            "decode": jax.jit(self.serve_step_decode, donate_argnums=(2, 4)),
+            "prefill": jax.jit(self.serve_step_prefill,
+                               donate_argnums=(2, 4)),
+            "packed": jax.jit(self.serve_step_packed, donate_argnums=(2, 4)),
+        }
         self._aot: Dict[tuple, object] = {}   # shape key -> Compiled
         self._dev_cache: Dict[tuple, jnp.ndarray] = {}  # small recurring
         # host arrays (lane masks, token-feed plumbing) memoized on device
         # — steady-state decode reuses them every step, skipping the
         # per-step device_put that would otherwise eat the pipeline win
         self.aot_misses = 0                   # async steps that re-traced
+        self.engine_id = next(steplog.ENGINE_IDS)
+        self.steps_dispatched = 0             # step-log sequence numbers
         self.trace_counts: Dict[str, int] = {}  # impl traces (trace-time
                                                 # side effect — retraces
                                                 # show up here)
@@ -621,6 +631,15 @@ class Engine:
         lane_tok = lane_tok.at[scatter_lane].set(
             toks.reshape(-1).astype(jnp.int32), mode="drop")
         return toks, new_cache, lane_tok
+
+    def serve_step_decode(self, *args):
+        return self._async_step_impl(*args, kind="decode")
+
+    def serve_step_prefill(self, *args):
+        return self._async_step_impl(*args, kind="prefill")
+
+    def serve_step_packed(self, *args):
+        return self._async_step_impl(*args, kind="packed")
 
     # -------------------------------------------------------------- common --
     def _sample(self, logits) -> np.ndarray:
@@ -1105,28 +1124,58 @@ class Engine:
     def _execute(self, sb: StepBatch):
         """Synchronous dispatch: run the step, block, attribute wall time
         by planned token share (a prefill-heavy mixed step must not book
-        its whole wall time under decode — Eq. 12)."""
+        its whole wall time under decode — Eq. 12), and log the step (a
+        trace during the call means it compiled; fetched once
+        ``block_until_ready`` returns)."""
         if self.faults is not None:
             self.faults.before_execute(sb)
         fn = {"prefill": self._prefill_fn, "decode": self._decode_fn,
               "packed": self._packed_fn}[sb.kind]
+        traces = sum(self.trace_counts.values())
         t0 = time.perf_counter()
         logits, self.cache = fn(self.params, sb.batch, self.cache,
                                 self._dev_const(sb.lane_mask))
+        t_dispatched = time.perf_counter()
         logits.block_until_ready()
-        self._book_time(sb, time.perf_counter() - t0)
+        t_fetched = time.perf_counter()
+        self._count_step(sb)
+        self._book_time(sb, t_fetched - t0)
+        rec = self._log_step(
+            sb, sum(self.trace_counts.values()) != traces, t_dispatched)
+        rec.t_fetched = t_fetched
         return logits
 
-    def _book_time(self, sb: StepBatch, dt: float) -> None:
-        share = dt / max(sb.tp + sb.td, 1)
+    def _count_step(self, sb: StepBatch) -> None:
         if sb.tp:
-            self.stats.prefill_time += share * sb.tp
             self.stats.prefill_calls += 1
         if sb.td:
-            self.stats.decode_time += share * sb.td
             self.stats.decode_steps += 1
         if sb.tp and sb.td:
             self.stats.mixed_steps += 1
+
+    def _book_time(self, sb: StepBatch, dt: float) -> None:
+        share = dt / max(sb.tp + sb.td, 1)
+        self.stats.prefill_time += share * sb.tp
+        self.stats.decode_time += share * sb.td
+
+    def _log_step(self, sb: StepBatch, compiled: bool,
+                  t_dispatched: float) -> steplog.StepRecord:
+        """Append the step's record to the step log; host values only."""
+        rows, bucket = sb.batch["tokens" if "tokens" in sb.batch
+                                else "token"].shape
+        plan = sb.plan
+        rec = steplog.StepRecord(
+            engine=self.engine_id, seq=self.steps_dispatched, kind=sb.kind,
+            rows=rows, bucket=bucket, real_tokens=sb.tp + sb.td,
+            chunks=tuple((c.start, c.n) for c in plan.prefill),
+            decode_ctx=tuple(d.pos + 1 for d in plan.decode),
+            req_ids=tuple(c.req.req_id for c in plan.prefill)
+            + tuple(d.req.req_id for d in plan.decode),
+            compiled=compiled, t_dispatched=t_dispatched)
+        self.steps_dispatched += 1
+        sb.record = rec
+        steplog.RECENT.append(rec)
+        return rec
 
     def _note_executed(self, sb: StepBatch) -> None:
         """Host metadata updates that must land before the NEXT plan is
@@ -1146,7 +1195,7 @@ class Engine:
             self._emit(req, int(toks[idx]), now, first=first)
         self._finish_done([req for req, _, _ in sb.samples])
 
-    def _run_mixed(self, plan: StepPlan) -> None:
+    def _run_mixed(self, plan: StepPlan, schedule_s: float = 0.0) -> None:
         """One device call for the whole step, for EVERY model family:
         prefill chunks + decode tokens through the chunked-continuation
         path (a decode lane is a chunk of length 1). A step with only
@@ -1154,11 +1203,21 @@ class Engine:
         S == 1, with the block-sparse ``long_window`` policy available.
         With ``pack_prefill`` the prefill chunks run through the packed
         concat-prefill layout instead."""
-        sb = self._build_step(plan)
-        logits = self._execute(sb)
-        toks = self._sample(logits)
-        self._note_executed(sb)
-        self._postprocess(sb, toks, time.perf_counter())
+        t0 = time.perf_counter()
+        with TraceAnnotation("serve.build"):
+            sb = self._build_step(plan)
+        t1 = time.perf_counter()
+        with TraceAnnotation("serve.dispatch", seq=self.steps_dispatched):
+            logits = self._execute(sb)
+        t2 = time.perf_counter()
+        with TraceAnnotation("serve.apply"):
+            toks = self._sample(logits)
+            self._note_executed(sb)
+            self._postprocess(sb, toks, time.perf_counter())
+        rec = sb.record
+        rec.schedule_s, rec.build_s = schedule_s, t1 - t0
+        rec.dispatch_s = rec.t_dispatched - t1
+        rec.apply_s = time.perf_counter() - t2
 
     # ------------------------------------------------- async step dispatch --
     def _async_key(self, kind: str, batch: Dict[str, jnp.ndarray]) -> tuple:
@@ -1188,7 +1247,9 @@ class Engine:
     def _dispatch_async(self, sb: StepBatch, lane_tok):
         """Dispatch one pipeline step WITHOUT blocking: prefer the AOT
         executable warmed up for this shape (zero traces in steady state);
-        fall back to the jit path and count the miss."""
+        fall back to the jit path and count the miss. Books the step
+        counters and logs the step (``sb.record``); its time is booked
+        when its tokens are applied."""
         if self.faults is not None:
             self.faults.before_execute(sb)
         if self.ecfg.sampling.temperature > 0:
@@ -1201,9 +1262,11 @@ class Engine:
             toks, self.cache, lane_tok = fn(*args)
         else:
             self.aot_misses += 1
-            toks, self.cache, lane_tok = self._async_fn(*args, kind=sb.kind)
-        self._book_time(sb, 0.0)      # step counters; async wall time is
-        return toks, lane_tok         # booked end-to-end by the caller
+            with TraceAnnotation("serve.compile"):
+                toks, self.cache, lane_tok = self._step_fns[sb.kind](*args)
+        self._log_step(sb, fn is None, time.perf_counter())
+        self._count_step(sb)
+        return toks, lane_tok
 
     # ------------------------------------------------------- AOT warmup ----
     def _dummy_batch(self, kind: str, R: int, S: int,
@@ -1265,20 +1328,13 @@ class Engine:
                                     self._dummy_batch("packed", R, S)))
         return lattice
 
-    def warmup(self) -> int:
-        """AOT-compile (``lower().compile()``) the async step executable
-        for EVERY shape in the bucket lattice, so steady-state serving
-        never traces or compiles. Returns the number of executables built.
-        Compiled executables bypass the jit call cache entirely — dispatch
-        looks them up by shape key (``_dispatch_async``)."""
+    def _lattice_args(self):
+        """Yield ``(kind, AOT key, step arguments)`` for every shape in
+        the bucket lattice (``_warmup_lattice``)."""
         B = self.ecfg.num_lanes
         lane_tok = jnp.zeros((B,), jnp.int32)
         key = jax.random.PRNGKey(0)
-        built = 0
         for kind, batch in self._warmup_lattice():
-            akey = self._async_key(kind, batch)
-            if akey in self._aot:
-                continue
             R = batch["page_table"].shape[0]
             n_slots = batch["last_pos"].size if kind == "packed" else R
             sb = StepBatch(kind=kind, batch=batch,
@@ -1287,9 +1343,20 @@ class Engine:
                            feed=np.full(R, -2, np.int32),
                            row_lane=np.zeros(R, np.int32),
                            scatter_lane=np.full(n_slots, B, np.int32))
-            args = self._async_args(sb, lane_tok, key)
-            self._aot[akey] = self._async_fn.lower(
-                *args, kind=kind).compile()
+            yield (kind, self._async_key(kind, batch),
+                   self._async_args(sb, lane_tok, key))
+
+    def warmup(self) -> int:
+        """AOT-compile (``lower().compile()``) the async step executable
+        for EVERY shape in the bucket lattice, so steady-state serving
+        never traces or compiles. Returns the number of executables built.
+        Compiled executables bypass the jit call cache entirely — dispatch
+        looks them up by shape key (``_dispatch_async``)."""
+        built = 0
+        for kind, akey, args in self._lattice_args():
+            if akey in self._aot:
+                continue
+            self._aot[akey] = self._step_fns[kind].lower(*args).compile()
             built += 1
         return built
 
@@ -1311,12 +1378,14 @@ class Engine:
         return drained
 
     def step(self) -> None:
-        plan = self.scheduler.schedule_step()
+        t0 = time.perf_counter()
+        with TraceAnnotation("serve.schedule"):
+            plan = self.scheduler.schedule_step()
         if plan.empty:
             self._update_pool_stats()       # rejections still count
             return
         try:
-            self._run_mixed(plan)
+            self._run_mixed(plan, time.perf_counter() - t0)
         except Exception as exc:
             # a step fault must not leak pool pages or strand requests:
             # drain everything as ERROR, then surface the fault

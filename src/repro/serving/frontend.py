@@ -27,7 +27,20 @@ exploits JAX's async dispatch to overlap them:
     async step executable for EVERY shape in the bucket lattice — prefill
     buckets x packed row buckets x decode — so steady-state serving never
     traces: ``engine.aot_misses`` stays 0 and ``engine.trace_counts`` is
-    frozen after warmup.
+    frozen after warmup. Each step kind is its own program
+    (``Engine.serve_step_<kind>``), named ``jit_serve_step_<kind>`` in a
+    device trace.
+
+Measurement: the loop's phases run inside ``jax.profiler.TraceAnnotation``
+spans — ``serve.turn`` around a turn, ``serve.schedule``, ``serve.build``,
+``serve.dispatch`` (with ``serve.compile`` on an AOT miss), ``serve.wait``
+(blocked on the oldest step) and ``serve.apply`` — and the emit worker's
+conversion in ``serve.fetch``, so a device trace shows what the host did
+around every idle gap. Each dispatched step also leaves a ``StepRecord`` in
+``serving.steplog.RECENT`` with the same phases' times, what the step
+carried, and whether it compiled. ``EngineStats`` counts a step at dispatch
+and books its time when its tokens are applied: from when the device could
+start it (dispatched, and the step before it fetched) to their arrival.
 
 Greedy outputs are bit-identical to ``Engine.generate``: the device
 consumes its own sampled tokens in dispatch order, and the paged-pool step
@@ -88,6 +101,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.engine import Engine, StepBatch
 from repro.serving.request import FinishReason, Request, RequestState
@@ -186,6 +200,7 @@ class AsyncEngine:
         self._streams: Dict[int, TokenStream] = {}
         self._cancelled: set = set()           # req_ids pending release
         self._inflight_steps = 0
+        self._last_fetched = 0.0       # t_fetched of the last applied step
         self._next_id = 0
         self._id_lock = threading.Lock()
         self._failed: Optional[BaseException] = None
@@ -287,7 +302,12 @@ class AsyncEngine:
                 faults = self.engine.faults
                 if faults is not None:
                     faults.on_emit()
-                self._done_q.put((sb, np.asarray(toks_dev)))
+                with TraceAnnotation("serve.fetch"):
+                    toks = np.asarray(toks_dev)
+                # the record's one field this thread writes; the loop
+                # reads it only after taking the step off _done_q
+                sb.record.t_fetched = time.perf_counter()
+                self._done_q.put((sb, toks))
             except WorkerKilled:
                 return                  # silent death: the watchdog fires
             except BaseException as exc:
@@ -317,7 +337,8 @@ class AsyncEngine:
         wedged (dead emit worker / hung device) and fails loudly."""
         try:
             if block:
-                sb, toks = self._done_q.get(timeout=self.watchdog_s)
+                with TraceAnnotation("serve.wait"):
+                    sb, toks = self._done_q.get(timeout=self.watchdog_s)
             else:
                 sb, toks = self._done_q.get(block=False)
         except queue.Empty:
@@ -330,18 +351,27 @@ class AsyncEngine:
             return True
         eng = self.engine
         now = time.perf_counter()
-        finished: List[Request] = []
-        for req, first, idx in sb.samples:
-            emitted = eng._emit(req, int(toks[idx]), now, first=first)
-            stream = self._streams.get(req.req_id)
-            if emitted and stream is not None:
-                stream.put(int(toks[idx]))
-            finished.append(req)
-        eng._finish_done(finished)
-        for req in finished:
-            if req.state is RequestState.FINISHED:
-                self._close_stream(req)
-        eng._update_pool_stats()
+        with TraceAnnotation("serve.apply"):
+            finished: List[Request] = []
+            for req, first, idx in sb.samples:
+                emitted = eng._emit(req, int(toks[idx]), now, first=first)
+                stream = self._streams.get(req.req_id)
+                if emitted and stream is not None:
+                    stream.put(int(toks[idx]))
+                finished.append(req)
+            eng._finish_done(finished)
+            for req in finished:
+                if req.state is RequestState.FINISHED:
+                    self._close_stream(req)
+            eng._update_pool_stats()
+            # the step's device time as the host saw it: from when the
+            # device could start it (dispatched, and the step before it
+            # fetched) to its tokens' arrival
+            rec = sb.record
+            eng._book_time(sb, rec.t_fetched - max(rec.t_dispatched,
+                                                   self._last_fetched))
+            self._last_fetched = rec.t_fetched
+        rec.apply_s = time.perf_counter() - now
         return True
 
     def _close_stream(self, req: Request) -> None:
@@ -371,20 +401,32 @@ class AsyncEngine:
         self._cancelled.intersection_update(self._streams)
 
     def _dispatch_one(self) -> bool:
-        """Build + dispatch ONE device step without waiting for results."""
+        """Build + dispatch ONE device step without waiting for results;
+        its step-log record gets the phase times."""
         eng = self.engine
-        plan = eng.scheduler.schedule_step()
+        t0 = time.perf_counter()
+        with TraceAnnotation("serve.schedule"):
+            plan = eng.scheduler.schedule_step()
         if plan.empty:
             return False
-        sb = eng._build_step(plan, device_feed=True)
-        toks_dev, self._lane_tok = eng._dispatch_async(sb, self._lane_tok)
-        # host metadata advances at DISPATCH time so the next plan can be
-        # built immediately; emission-side effects wait for the tokens
-        eng._note_executed(sb)
-        for req, _, _ in sb.samples:
-            req.inflight += 1
-        self._inflight_steps += 1
-        self._emit_q.put((sb, toks_dev))
+        t1 = time.perf_counter()
+        with TraceAnnotation("serve.build"):
+            sb = eng._build_step(plan, device_feed=True)
+        t2 = time.perf_counter()
+        with TraceAnnotation("serve.dispatch", seq=eng.steps_dispatched):
+            toks_dev, self._lane_tok = eng._dispatch_async(sb,
+                                                           self._lane_tok)
+            # host metadata advances at DISPATCH time so the next plan can
+            # be built immediately; emission-side effects wait for the
+            # tokens
+            eng._note_executed(sb)
+            for req, _, _ in sb.samples:
+                req.inflight += 1
+            self._inflight_steps += 1
+            rec = sb.record
+            rec.schedule_s, rec.build_s = t1 - t0, t2 - t1
+            self._emit_q.put((sb, toks_dev))
+        rec.dispatch_s = time.perf_counter() - t2
         return True
 
     # ------------------------------------------------------- fault drain --
@@ -428,6 +470,10 @@ class AsyncEngine:
 
     def _loop_once(self) -> bool:
         """One scheduling turn. Returns True if anything happened."""
+        with TraceAnnotation("serve.turn"):
+            return self._turn()
+
+    def _turn(self) -> bool:
         faults = self.engine.faults
         if faults is not None:
             faults.on_turn(self)
