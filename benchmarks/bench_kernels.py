@@ -143,12 +143,14 @@ def _measured(quick: bool):
     vf = jax.random.normal(ks[2], (PT, Hkv, ps, D), jnp.float32)
     kq, ksc = quantize_fp8(kf)
     vq, vsc = quantize_fp8(vf)
-    kv, sc = jnp.stack([kq, vq]), jnp.stack([ksc, vsc])
+    # a pool of one layer, attended at layer 0
+    kv, sc = jnp.stack([kq, vq])[None], jnp.stack([ksc, vsc])[None]
     cl = jnp.full((B,), cache_len, jnp.int32)
 
     def kern(share):
-        return ops.paged_pool_decode(q, kv, sc, cl, phys, log, opt_kv=True,
-                                     opt_gqa=True, share_visits=share)
+        return ops.paged_pool_decode(q, kv, sc, 0, cl, phys, log,
+                                     opt_kv=True, opt_gqa=True,
+                                     share_visits=share)
 
     o_lane = kern(False)
     o_vis = kern(True)
@@ -158,7 +160,8 @@ def _measured(quick: bool):
     # honest compiled-XLA wall-clock: the jnp gather oracle on the SAME
     # shared page table
     jref = jax.jit(lambda q_, cl_: ref.paged_pool_decode_ref(
-        q_, kv[0], kv[1], sc[0], sc[1], cl_, phys, log, opt_kv=True))
+        q_, kv[0, 0], kv[0, 1], sc[0, 0], sc[0, 1], cl_, phys, log,
+        opt_kv=True))
     err = float(np.abs(np.asarray(o_vis, np.float32)
                        - np.asarray(jref(q, cl), np.float32)).max())
     us_jnp = _time(jref, q, cl)

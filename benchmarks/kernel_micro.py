@@ -115,7 +115,8 @@ def latent_rows(quick: bool = False):
          "w_uv": jax.random.normal(ks[1], (R, H * dv)) * 0.05}
     qn = jax.random.normal(ks[2], (B, H, dn)).astype(jnp.bfloat16)
     qr = jax.random.normal(ks[3], (B, H, dr)).astype(jnp.bfloat16)
-    latf = jax.random.normal(ks[4], (B * P, ps, R + dr), jnp.float32)
+    # a latent pool of one layer, attended at layer 0
+    latf = jax.random.normal(ks[4], (1, B * P, ps, R + dr), jnp.float32)
     lat, sc = quantize_latent(latf, R)
     cl = jnp.full((B,), cache_len, jnp.int32)
     pt = identity_page_table(B, B * P)
@@ -141,7 +142,7 @@ def latent_rows(quick: bool = False):
     tr = dict(ps=ps, R=R, dr=dr, opt_kv=True, cache_len=cache_len)
     cell("mla-latent-decode",
          lambda qn_, qr_, lat_, sc_, cl_, pt_, co_: mla_mod.mla_paged_decode(
-             qn_, qr_, lat_, sc_, cl_, p, cfg, co_, page_table=pt_),
+             qn_, qr_, lat_, sc_, 0, cl_, p, cfg, co_, page_table=pt_),
          (qn, qr, lat, sc, cl, pt),
          latent_bytes_per_call(B, P, **tr, fused=True),
          latent_bytes_per_call(B, P, **tr, fused=False))
@@ -152,8 +153,8 @@ def latent_rows(quick: bool = False):
                                  (B, S)).astype(jnp.int32)
     cell("mla-latent-chunk",
          lambda qn_, qr_, lat_, sc_, pos_, pt_, co_:
-             mla_mod.mla_chunk_attention(qn_, qr_, lat_, sc_, pos_, pt_, p,
-                                         cfg, co_),
+             mla_mod.mla_chunk_attention(qn_, qr_, lat_, sc_, 0, pos_, pt_,
+                                         p, cfg, co_),
          (qn4, qr4, lat, sc, positions, pt),
          latent_bytes_per_call(B, P, **tr, fused=True),
          latent_bytes_per_call(B, P, **tr, fused=False))
@@ -177,15 +178,16 @@ def run(quick: bool = False):
 
     kq, ksc = quantize_fp8(kf)
     vq, vsc = quantize_fp8(vf)
-    kv8, sc8 = jnp.stack([kq, vq]), jnp.stack([ksc, vsc])
-    kv16 = jnp.stack([kf, vf]).astype(jnp.bfloat16)
+    # pools of one layer, attended at layer 0
+    kv8, sc8 = jnp.stack([kq, vq])[None], jnp.stack([ksc, vsc])[None]
+    kv16 = jnp.stack([kf, vf])[None].astype(jnp.bfloat16)
 
     rows = []
     for mode, co in MODES.items():
         kv, sc = (kv8, sc8) if co.opt_kv else (kv16, None)
         # jnp reference path (jit, XLA:CPU) — the schedule comparison
         fn = jax.jit(lambda q, kv, sc, cl, co=co: paged_decode_attention(
-            q, kv, sc, cl, coopt=co))
+            q, kv, sc, 0, cl, coopt=co))
         out = fn(q, kv, sc, cl).block_until_ready()
         t0 = time.perf_counter()
         for _ in range(20):
@@ -200,12 +202,12 @@ def run(quick: bool = False):
             kphys = jnp.where(beyond, -1, phys)
         else:
             kphys = phys
-        kout = ops.paged_pool_decode(q, kv, sc, cl, kphys, log,
+        kout = ops.paged_pool_decode(q, kv, sc, 0, cl, kphys, log,
                                      opt_kv=co.opt_kv, opt_gqa=co.opt_gqa)
-        ksl = sc[0] if sc is not None else None
-        vsl = sc[1] if sc is not None else None
-        expected = ref.paged_pool_decode_ref(q, kv[0], kv[1], ksl, vsl, cl,
-                                             phys, log, opt_kv=co.opt_kv)
+        ksl = sc[0, 0] if sc is not None else None
+        vsl = sc[0, 1] if sc is not None else None
+        expected = ref.paged_pool_decode_ref(q, kv[0, 0], kv[0, 1], ksl, vsl,
+                                             cl, phys, log, opt_kv=co.opt_kv)
         err = float(np.abs(np.asarray(kout, np.float32) -
                            np.asarray(expected, np.float32)).max())
 

@@ -14,7 +14,9 @@ Lineage: the paged kernels (PRs 3-5) share three load-bearing conventions:
     the PR 5 slot-wrap incident class, where an unhandled sentinel let a
     write land on a live pool line. (The write kernel instead clamps its
     page indices before the call; its index_maps carry inline allows
-    citing that.)
+    citing that.) A read whose every index is a literal (``lyr[0]``)
+    takes a scalar argument — the layer of the pool — not a table entry
+    keyed by the grid, and holds no sentinel.
   * Every block named by the specs is resident in VMEM (~16 MiB/core),
     double-buffered, alongside the scratch accumulators. The estimator
     below computes worst-case residency from the BlockSpec shapes and
@@ -76,6 +78,8 @@ def _unparse(node: ast.AST) -> str:
 # --------------------------------------------------------- dim evaluation --
 def _eval_dim(node: ast.AST, used: Dict[str, int],
               unknown: List[str]) -> int:
+    if isinstance(node, ast.Constant) and node.value is None:
+        return 1                    # a squeezed block dim holds one element
     if isinstance(node, ast.Constant) and isinstance(node.value, int):
         return node.value
     if isinstance(node, ast.Name):
@@ -188,6 +192,14 @@ def _parent_map(root: ast.AST) -> Dict[int, ast.AST]:
     return out
 
 
+def _scalar_read(sub: ast.Subscript) -> bool:
+    """Every index a literal (``lyr[0]``): a scalar argument, not a table
+    lookup keyed by the grid."""
+    idx = sub.slice
+    elts = idx.elts if isinstance(idx, ast.Tuple) else [idx]
+    return all(isinstance(e, ast.Constant) for e in elts)
+
+
 def _clamped(sub: ast.Subscript, parents: Dict[int, ast.AST]) -> bool:
     node: ast.AST = sub
     while id(node) in parents:
@@ -213,7 +225,7 @@ def _check_index_map(f: FileCtx, qual: str, im, grid_len: int,
         if not isinstance(base, ast.Name):
             continue
         if base.id in prefetch:
-            if not _clamped(node, parents):
+            if not (_scalar_read(node) or _clamped(node, parents)):
                 out.append(Finding(
                     code=CODE, path=f.path, line=node.lineno, symbol=qual,
                     message=(f"index_map dereferences page table "

@@ -2,9 +2,9 @@
 spill tier (host-side, pure Python).
 
 XLA wants static shapes, so the device cache is ONE preallocated paged pool
-shared by every sequence (``repro.core.opt_kv.make_layer_cache`` / model
-``init_cache`` — leaves shaped ``(2, P_total, Hkv, ps, D)`` with no batch
-dimension) and all dynamic paging happens here as *indices*: each sequence
+shared by every sequence (``repro.core.opt_kv.make_pool`` / model
+``init_cache`` — leaves shaped ``(L, 2, P_total, Hkv, ps, D)`` with no
+batch dimension) and all dynamic paging happens here as *indices*: each sequence
 owns a logical-ordered list of physical pages; token slot =
 page_table[pos // ps] * ps + pos % ps, a *global* flat slot.
 

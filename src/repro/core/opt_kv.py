@@ -13,12 +13,15 @@ the attention loop — on a single host and, through the ``kernels.sharded``
 shard_map layer, per shard of a GSPMD mesh; this module is the
 numerically-identical jnp parity reference used by tests.
 
-Cache layout (one layer) — GLOBAL POOL, no batch dimension:
-    kv (2, P_total, Hkv, ps, D) + scale (2, P_total, Hkv, ps).
+Cache layout — ONE GLOBAL POOL for every layer, no batch dimension:
+    kv (L, 2, P_total, Hkv, ps, D) + scale (L, 2, P_total, Hkv, ps).
 Heads come before tokens within a page, so one head's page is a (ps, D)
 tile: the block the Pallas kernels DMA (Mosaic needs the last two block
 dims to be multiples of (8, 128) or whole). Flat slot ``page * ps + off``
-names row ``off`` of page ``page`` in every head.
+names row ``off`` of page ``page`` in every head of a layer; the write path
+addresses LINES of the whole pool, ``(layer * P_total + page) * ps + off``
+(``pool_lines``), so the model's layer scan hands the pool on whole and no
+layer is ever sliced out of it.
 All sequences share the pool; the host-side ``BlockManager`` hands each
 sequence a disjoint set of pages (refcounted, prefix-cache shareable) and the
 per-step batch carries *global* flat slot indices and per-lane page tables.
@@ -108,42 +111,77 @@ def global_to_local_pages(phys_table, first_page, num_local: int):
     return jnp.where(owned, local, -1).astype(jnp.int32)
 
 
-def global_to_local_slots(slot_idx, first_slot, num_local: int):
-    """Flat-slot analogue of ``global_to_local_pages``: GLOBAL flat slots
-    (page * ps + offset) outside the shard's ``[first_slot, first_slot +
-    num_local)`` slot range (or already -1 / SkipSet) become ``num_local`` —
-    one PAST the shard's last line, so a ``mode='drop'`` scatter discards
-    them as out of bounds (Eq. 5 semantics per shard). -1 would WRAP to the
-    shard's last line, which holds live data."""
-    local = slot_idx - first_slot
-    owned = (slot_idx >= 0) & (local >= 0) & (local < num_local)
-    return jnp.where(owned, local, num_local).astype(jnp.int32)
+def global_to_local_lines(line_idx, first_page, num_local: int,
+                          num_pages: int, page_size: int):
+    """Line analogue of ``global_to_local_pages``: lines of the whole pool,
+    ``(layer * num_pages + page) * ps + off``, become lines of one mesh
+    shard's pool, ``(layer * num_local + page - first_page) * ps + off``;
+    lines of pages outside the shard's ``[first_page, first_page +
+    num_local)`` (and -1 / SkipSet) become -1, which the writers drop."""
+    per_layer = num_pages * page_size
+    layer = line_idx // per_layer
+    local = line_idx % per_layer - first_page * page_size
+    owned = (line_idx >= 0) & (local >= 0) & (local < num_local * page_size)
+    return jnp.where(owned, layer * num_local * page_size + local,
+                     -1).astype(jnp.int32)
 
 
-def make_layer_cache(num_pages: int, page_size: int, num_kv_heads: int,
-                     head_dim: int, coopt: CoOptConfig):
-    """Zero-initialised single-layer GLOBAL paged cache (kv, scale|None)."""
-    kv = jnp.zeros((2, num_pages, num_kv_heads, page_size, head_dim),
-                   coopt.kv_dtype)
-    scale = (jnp.zeros((2, num_pages, num_kv_heads, page_size), jnp.float32)
-             if coopt.opt_kv else None)
+def pool_lines(slot_idx, layer, num_pages: int, page_size: int):
+    """One layer's flat slots (``page * ps + off``, -1 = SkipSet) -> lines
+    of the whole pool, ``(layer * num_pages + page) * ps + off``, the
+    addresses the write path takes; -1 stays -1."""
+    return jnp.where(slot_idx >= 0, slot_idx + layer * num_pages * page_size,
+                     -1).astype(jnp.int32)
+
+
+def make_pool(num_layers: int, num_pages: int, page_size: int,
+              num_kv_heads: int, head_dim: int, coopt: CoOptConfig):
+    """Zero-initialised GLOBAL paged cache of ``num_layers`` layers
+    (kv, scale|None)."""
+    shape = (num_layers, 2, num_pages, num_kv_heads, page_size)
+    kv = jnp.zeros(shape + (head_dim,), coopt.kv_dtype)
+    scale = jnp.zeros(shape, jnp.float32) if coopt.opt_kv else None
     return kv, scale
 
 
-def scatter_kv(kv, scale, vals, scl, slots):
-    """Scatter new tokens into one layer's pool: kv (2, P, H, ps, D), scale
-    (2, P, H, ps) | None; vals (2, B, S, H, D) in the pool dtype; scl
-    (2, B, S, H) | None; slots (B, S) flat ``page * ps + off``. Slots that
-    are negative or past the pool are dropped."""
-    _, P, _, ps, _ = kv.shape
-    page = jnp.where(slots < 0, P, slots // ps)
-    off = slots % ps
-    # advanced indices split by a slice put (B, S) first: (B, S, 2, H[, D])
-    kv = kv.at[:, page, :, off].set(jnp.moveaxis(vals, 0, 2), mode="drop")
+def scatter_kv(kv, scale, vals, scl, lines):
+    """Scatter new tokens to lines of the pool: kv (L, 2, P, H, ps, D),
+    scale (L, 2, P, H, ps) | None; vals (2, B, S, H, D) in the pool dtype;
+    scl (2, B, S, H) | None; lines (B, S) ``(layer * P + page) * ps + off``.
+    Lines that are negative or past the pool are dropped."""
+    L, _, P, _, ps, _ = kv.shape
+    layer = jnp.where(lines < 0, L, lines // (P * ps))
+    page = lines // ps % P
+    off = lines % ps
+    # advanced indices split by slices put (B, S) first: (B, S, 2, H[, D])
+    kv = kv.at[layer, :, page, :, off].set(jnp.moveaxis(vals, 0, 2),
+                                           mode="drop")
     if scale is not None:
-        scale = scale.at[:, page, :, off].set(jnp.moveaxis(scl, 0, 2),
-                                              mode="drop")
+        scale = scale.at[layer, :, page, :, off].set(
+            jnp.moveaxis(scl, 0, 2), mode="drop")
     return kv, scale
+
+
+def scatter_latent(lat_cache, scale_cache, latent, lines, *, opt_kv: bool,
+                   lora_rank: int):
+    """The MLA latent write: dual-scale quantization (under ``opt_kv``) and a
+    scatter to lines of the latent pool (L, P, ps, R+dr), scales
+    (L, P, ps, 2) | None; latent (B, S, R+dr); lines (B, S). Negative lines
+    are dropped: they are sent past the pool's end, where ``mode="drop"``
+    discards them (a negative index would wrap)."""
+    L, P, ps, W = lat_cache.shape
+    n = L * P * ps
+    lines = jnp.where(lines < 0, n, lines)
+    flat = lat_cache.reshape(n, W)
+    if opt_kv:
+        from repro.cache.quant import quantize_latent
+        qv, s = quantize_latent(latent, lora_rank)
+        flat = flat.at[lines].set(qv.astype(flat.dtype), mode="drop")
+        sf = scale_cache.reshape(n, 2).at[lines].set(s, mode="drop")
+        scale_cache = sf.reshape(L, P, ps, 2)
+    else:
+        flat = flat.at[lines].set(latent.astype(flat.dtype), mode="drop")
+    return flat.reshape(L, P, ps, W), scale_cache
 
 
 # ------------------------------------------------------- identity layout --
@@ -169,25 +207,27 @@ def identity_slots(batch: int, positions, total_pages: int,
     return (positions.astype(jnp.int32) + off)
 
 
-def write_kv(kv_cache, scale_cache, k_new, v_new, slot_idx, coopt: CoOptConfig):
-    """Write new tokens' K/V into the global paged cache.
+def write_kv(kv_cache, scale_cache, k_new, v_new, line_idx,
+             coopt: CoOptConfig):
+    """Write new tokens' K/V into the global paged cache of every layer.
 
-    kv_cache: (2, P, Hkv, ps, D); k_new/v_new: (B, S, Hkv, D);
-    slot_idx: (B, S) int32 — GLOBAL flat slot (= page * page_size + offset)
-    in the shared pool; -1/SkipSet => skip. Returns updated
-    (kv_cache, scale_cache).
+    kv_cache: (L, 2, P, Hkv, ps, D), scale_cache (L, 2, P, Hkv, ps) | None;
+    k_new/v_new: (B, S, Hkv, D); line_idx: (B, S) int32 — lines of the
+    whole pool, ``(layer * P + page) * page_size + offset`` (``pool_lines``
+    of one layer's flat slots); -1/SkipSet => skip. Returns updated
+    (kv_cache, scale_cache); the kernel path writes them in place.
     """
     if coopt.use_kernel:
         from repro.kernels import ops
         return ops.kv_cache_write(kv_cache, scale_cache, k_new, v_new,
-                                  slot_idx, opt_kv=coopt.opt_kv)
+                                  line_idx, opt_kv=coopt.opt_kv)
     new = jnp.stack([k_new, v_new])                      # (2,B,S,H,D)
     if coopt.opt_kv:
         q, s = quantize_fp8(new, axis=-1)                # (2,B,S,H,D),(2,B,S,H)
         return scatter_kv(kv_cache, scale_cache, q.astype(kv_cache.dtype), s,
-                          slot_idx)
+                          line_idx)
     return scatter_kv(kv_cache, scale_cache, new.astype(kv_cache.dtype),
-                      None, slot_idx)
+                      None, line_idx)
 
 
 def dequant_pages(kv_pages, scale_pages, coopt: CoOptConfig, dtype=jnp.bfloat16):
@@ -201,7 +241,8 @@ def gather_cached_kv(kv_cache, scale_cache, page_table, coopt: CoOptConfig,
                      dtype=jnp.bfloat16):
     """Reference of the paper's dedicated ``gather_cached_kv`` kernel.
 
-    kv_cache: (2, P, Hkv, ps, D) global pool; page_table: (B, Psel) int32
+    kv_cache: (2, P, Hkv, ps, D) one layer of the global pool
+    (``pool[layer]``), scale_cache likewise; page_table: (B, Psel) int32
     physical page ids in logical order (negative => zero page). Returns
     (2, B, Psel*ps, Hkv, D) dequantized — token j of the output is the lane's
     logical position j, so downstream masks index by position directly.

@@ -1,9 +1,12 @@
 """Opt-Pa — paged attention for long sequences (paper §3.3, Alg. 3).
 
 Decode-phase attention of ONE query token per lane against the GLOBAL paged
-KV pool: ``kv_pages (2, P_total, Hkv, ps, D)`` shared by every lane, with a
+KV pool: ``kv_pages (L, 2, P_total, Hkv, ps, D)`` of every layer, shared by
+every lane, and a ``layer`` index naming the layer to attend, with a
 per-lane ``page_table (B, P_lane)`` naming the lane's physical pages in
-logical order (-1 = unallocated). Lanes never alias pages they can write
+logical order (-1 = unallocated). The kernel path hands the pool on whole
+(the kernels pick the layer in place); the jnp reference reads
+``kv_pages[layer]``. Lanes never alias pages they can write
 (refcounted pool, CoW prefix sharing), so the gather is race-free.
 
 Two-stage strategy, mapped to TPU (DESIGN.md §3):
@@ -77,18 +80,20 @@ def _weighted_v(p, v, opt_gqa: bool, Hq: int):
     return jnp.einsum("bht,bthd->bhd", p, v.astype(jnp.float32))
 
 
-def paged_decode_attention(q, kv_pages, scale_pages, cache_len, *,
+def paged_decode_attention(q, kv_pages, scale_pages, layer, cache_len, *,
                            coopt: CoOptConfig, window: int = 0,
                            sink_pages: int = 1,
                            page_table: Optional[jax.Array] = None) -> jax.Array:
-    """q: (B, Hq, D); kv_pages: (2, P_total, Hkv, ps, D) global pool;
-    cache_len: (B,) tokens valid per lane (the current token must already be
-    written); page_table: (B, P_lane) physical pages in logical order
-    (default: static lane-identity partition of the pool).
+    """q: (B, Hq, D); kv_pages: (L, 2, P_total, Hkv, ps, D) global pool of
+    every layer, scale_pages (L, 2, P_total, Hkv, ps) | None; layer: int32
+    scalar, the layer to attend; cache_len: (B,) tokens valid per lane (the
+    current token must already be written); page_table: (B, P_lane)
+    physical pages in logical order (default: static lane-identity
+    partition of the pool).
     Returns (B, Hq, D) in q.dtype.
     """
     B, Hq, D = q.shape
-    _, P_total, Hkv, ps, _ = kv_pages.shape
+    P_total, Hkv, ps = kv_pages.shape[2:5]
     if page_table is None:
         page_table = identity_page_table(B, P_total)
 
@@ -102,12 +107,14 @@ def paged_decode_attention(q, kv_pages, scale_pages, cache_len, *,
                                            sink_pages=sink_pages,
                                            opt_pa=coopt.opt_pa)
         return ops.paged_pool_decode(
-            q, kv_pages, scale_pages, cache_len, phys, logical,
+            q, kv_pages, scale_pages, layer, cache_len, phys, logical,
             opt_kv=coopt.opt_kv,
             opt_gqa=True if window else coopt.opt_gqa,
             window=window, sink_pages=sink_pages if window else 0,
             share_visits=coopt.share_visits)
 
+    kv_pages = kv_pages[layer]
+    scale_pages = None if scale_pages is None else scale_pages[layer]
     if window:
         # Block-sparse policy: Opt-KV SkipSet = outside {sinks + window},
         # decided in the logical page domain then mapped to physical pages
@@ -130,14 +137,16 @@ def paged_decode_attention(q, kv_pages, scale_pages, cache_len, *,
 
 
 # ------------------------------------------------ continuation prefill ----
-def paged_chunk_attention(q, kv_pages, scale_pages, positions, page_table,
-                          coopt: CoOptConfig, *, window: int = 0,
+def paged_chunk_attention(q, kv_pages, scale_pages, layer, positions,
+                          page_table, coopt: CoOptConfig, *, window: int = 0,
                           sink_pages: int = 1, seg_q=None, page_seg=None,
                           page_base=None) -> jax.Array:
     """Chunked-continuation prefill attention (the ONE ragged step path):
     a chunk of queries per lane — q (B,S,Hq,D) with absolute ``positions``
     (B,S) — attends over the lane's WHOLE cached history (prefix-cache hits,
-    earlier chunks, and this chunk, already written) through its page table.
+    earlier chunks, and this chunk, already written) in layer ``layer`` of
+    the pool of every layer (``kv_pages`` (L,2,P_total,Hkv,ps,D),
+    ``scale_pages`` | None) through its page table.
     Key j of the gathered view is the lane's logical position j, so causality
     is a plain position compare; a decode lane is a chunk of length 1.
 
@@ -152,21 +161,23 @@ def paged_chunk_attention(q, kv_pages, scale_pages, positions, page_table,
     (byte-identical to the pre-packing math).
     Returns (B, S, Hq, D) in q.dtype."""
     B, S, Hq, D = q.shape
-    _, P_total, Hkv, ps, _ = kv_pages.shape
+    P_total, Hkv, ps = kv_pages.shape[2:5]
     if page_table is None:
         page_table = identity_page_table(B, P_total)
 
     if coopt.use_kernel:
         from repro.kernels import ops
         return ops.paged_chunk_prefill(
-            q, positions, kv_pages, scale_pages, page_table,
+            q, positions, kv_pages, scale_pages, layer, page_table,
             opt_kv=coopt.opt_kv, opt_gqa=coopt.opt_gqa, window=window,
             sink_pages=sink_pages, seg_q=seg_q, page_seg=page_seg,
             page_base=page_base)
 
     # jnp reference: gather the lane's pages in logical order, then a
     # position-masked softmax over the gathered view.
-    flat = gather_cached_kv(kv_pages, scale_pages, page_table, coopt)
+    flat = gather_cached_kv(kv_pages[layer],
+                            None if scale_pages is None
+                            else scale_pages[layer], page_table, coopt)
     k, v = flat                                        # (B,T,Hkv,D) each
     T = k.shape[1]
     if not coopt.opt_gqa and Hkv != Hq:

@@ -9,7 +9,10 @@ hits, and the chunk itself (written before attention). The lane's physical
 page table is scalar-prefetched and dereferenced inside the BlockSpec
 index_map, so a chunk's queries attend over prior cached pages without the
 host gathering the whole history into a contiguous buffer (Opt-Pa "lazy
-memory mapping", paper §3.3, applied to the prefill continuation).
+memory mapping", paper §3.3, applied to the prefill continuation). The
+kernel takes the whole pool of every layer and a ``layer`` scalar; the
+index_maps pick the layer and one block carries a head page's K and V, so
+neither a layer nor a half is sliced out of the pool.
 
 Grid: (batch, kv_head, q_group, logical_page). Queries arrive grouped
 (Opt-GQA): rows are (seq, group) pairs, so each KV page is streamed into VMEM
@@ -78,11 +81,13 @@ def resident_rows(R: int, G: int, cap: int = 0) -> int:
     return rq or R
 
 
-def _chunk_kernel(phys_ref,                          # scalar prefetch
-                  q_ref, pos_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  o_ref, *refs,
+def _chunk_kernel(phys_ref, lyr_ref,                 # scalar prefetch
+                  q_ref, pos_ref, kv_ref, *refs,
                   ps: int, rep: int, opt_kv: bool, window: int, sink: int,
                   num_pages: int, return_state: bool):
+    # kv_ref (2, 1, 1, ps, D): one head page's K and V; sc_ref (2, 1, Hkv,
+    # ps): its page's K and V scales, which come only under Opt-KV
+    sc_ref, o_ref, *refs = refs if opt_kv else (None, *refs)
     if return_state:
         mo_ref, lo_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -111,13 +116,13 @@ def _chunk_kernel(phys_ref,                          # scalar prefetch
     @pl.when(live)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)          # (rq, D)
-        k = k_ref[0, 0].astype(jnp.float32)          # (ps, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = kv_ref[0, 0, 0].astype(jnp.float32)      # (ps, D)
+        v = kv_ref[1, 0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * (1.0 / math.sqrt(D))                 # (rq, ps)
         if opt_kv:                                   # Eq. 6 fused dequant
-            s = s * ks_ref[0, pl.ds(kvh, 1), :]      # per-key scale row
+            s = s * sc_ref[0, 0, pl.ds(kvh, 1), :]   # per-key scale row
         kpos = base * ps + jax.lax.broadcasted_iota(jnp.int32, (rq, ps), 1)
         qp = jnp.broadcast_to(qpos[:, None], (rq, ps))
         mask = (kpos <= qp) & (qseg[:, None] == pseg)
@@ -132,7 +137,7 @@ def _chunk_kernel(phys_ref,                          # scalar prefetch
         # exp(s - m_new) alone would yield 1.0 while m_new is still _NEG
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         l_new = l_ref[:, 0:1] * corr + jnp.sum(p, -1, keepdims=True)
-        pv = p * vs_ref[0, pl.ds(kvh, 1), :] if opt_kv else p
+        pv = p * sc_ref[1, 0, pl.ds(kvh, 1), :] if opt_kv else p
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -149,19 +154,21 @@ def _chunk_kernel(phys_ref,                          # scalar prefetch
             lo_ref[0, 0] = l_ref[...]
 
 
-def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
+def flash_chunk_prefill(q, positions, kv_pages, scale_pages, layer,
                         phys_table, *, opt_kv: bool, opt_gqa: bool = True,
                         window: int = 0, sink_pages: int = 0,
                         block_q: int = 0, return_state: bool = False,
                         interpret: bool = False, seg_q=None, page_seg=None,
                         page_base=None):
     """q: (B, S, Hq, D) chunk queries; positions: (B, S) absolute per-row
-    positions; k/v_pages: (P_total, Hkv, ps, D) GLOBAL pool [fp8 if opt_kv];
-    k/v_scale: (P_total, Hkv, ps) f32 or None; phys_table: (B, NP) int32
-    physical pages in logical order (-1 = skip, never DMA'd). The chunk's
-    own K/V must already be written to the pool. Returns (B, S, Hq, D); with
-    ``return_state`` also the final online-softmax (m, l) as (B, S, Hq) f32
-    for the cross-shard log-sum-exp merge (``kernels.sharded``).
+    positions; kv_pages: (L, 2, P_total, Hkv, ps, D) the GLOBAL pool of every
+    layer [fp8 if opt_kv]; scale_pages: (L, 2, P_total, Hkv, ps) f32, read
+    only under opt_kv (None otherwise); layer: int32 scalar, the layer to
+    attend; phys_table: (B, NP) int32 physical pages in logical order (-1 =
+    skip, never DMA'd). The chunk's own K/V must already be written to the
+    pool. Returns (B, S, Hq, D); with ``return_state`` also the final
+    online-softmax (m, l) as (B, S, Hq) f32 for the cross-shard log-sum-exp
+    merge (``kernels.sharded``).
 
     Concat-prefill packing (all three or none): ``seg_q`` (B, S) int32 is
     each query row's segment id (-1 = pad row, matches nothing);
@@ -170,7 +177,7 @@ def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
     (key positions are ``page_base * ps + iota``). Defaults reproduce the
     unpacked layout exactly: one segment 0 per row, base == slot index."""
     B, S, Hq, D = q.shape
-    P, Hkv, ps, _ = k_pages.shape
+    _, _, P, Hkv, ps, _ = kv_pages.shape
     NP = phys_table.shape[1]
     if seg_q is None:
         seg_q = jnp.zeros((B, S), jnp.int32)
@@ -204,20 +211,27 @@ def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
                         page_base.astype(jnp.int32),
                         page_seg.astype(jnp.int32)])              # (3, B, NP)
 
-    if k_scale is None:
-        k_scale = jnp.zeros((P, Hkv, ps), jnp.float32)
-        v_scale = k_scale
+    # the layer is picked here, in place in the pool; one block holds the
+    # head page's K and V
+    def kv_idx(b, h, i, j, phys, lyr):
+        return (lyr[0], 0, jnp.maximum(phys[0, b, j], 0), h // rep, 0, 0)
 
-    def kv_idx(b, h, i, j, phys):
-        return (jnp.maximum(phys[0, b, j], 0), h // rep, 0, 0)
+    def sc_idx(b, h, i, j, phys, lyr):
+        return (lyr[0], 0, jnp.maximum(phys[0, b, j], 0), 0, 0)
 
-    def sc_idx(b, h, i, j, phys):
-        return (jnp.maximum(phys[0, b, j], 0), 0, 0)
+    in_specs = [
+        pl.BlockSpec((1, 1, rq, D), lambda b, h, i, j, phys, lyr: (b, h, i, 0)),
+        pl.BlockSpec((1, 2, rq), lambda b, h, i, j, phys, lyr: (b, 0, i)),
+        pl.BlockSpec((None, 2, 1, 1, ps, D), kv_idx)]
+    operands = [qf, pos_rep, kv_pages]
+    if opt_kv:
+        in_specs += [pl.BlockSpec((None, 2, 1, Hkv, ps), sc_idx)]
+        operands += [scale_pages]
 
     out_blk = pl.BlockSpec((1, 1, rq, D),
-                           lambda b, h, i, j, phys: (b, h, i, 0))
+                           lambda b, h, i, j, phys, lyr: (b, h, i, 0))
     st_blk = pl.BlockSpec((1, 1, rq, 128),
-                          lambda b, h, i, j, phys: (b, h, i, 0))
+                          lambda b, h, i, j, phys, lyr: (b, h, i, 0))
     out_specs = [out_blk]
     out_shape = [jax.ShapeDtypeStruct((B, heads, R, D), q.dtype)]
     if return_state:
@@ -231,18 +245,9 @@ def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
     res = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(B, heads, NQ, NP),
-            in_specs=[
-                pl.BlockSpec((1, 1, rq, D),
-                             lambda b, h, i, j, phys: (b, h, i, 0)),
-                pl.BlockSpec((1, 2, rq),
-                             lambda b, h, i, j, phys: (b, 0, i)),
-                pl.BlockSpec((1, 1, ps, D), kv_idx),
-                pl.BlockSpec((1, 1, ps, D), kv_idx),
-                pl.BlockSpec((1, Hkv, ps), sc_idx),
-                pl.BlockSpec((1, Hkv, ps), sc_idx),
-            ],
+            in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((rq, 128), jnp.float32),
@@ -255,7 +260,7 @@ def flash_chunk_prefill(q, positions, k_pages, v_pages, k_scale, v_scale,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(table3, qf, pos_rep, k_pages, v_pages, k_scale, v_scale)
+    )(table3, jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     out = res[0].reshape(B, heads, S, G, D).transpose(0, 2, 1, 3, 4) \
                 .reshape(B, S, Hq, D)
     if not return_state:

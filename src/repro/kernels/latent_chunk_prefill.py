@@ -13,8 +13,9 @@ never gathered host-side (the ``jnp.take`` full-pool materialisation this
 kernel replaces).
 
 Latent pool addressing — identical to ``paged_latent_decode`` (see its
-module docstring for the full scheme): ``lat_pages (P_total, ps, R+dr)``
-packs ``[c_kv | k_rope]`` per token; ``scale_pages (P_total, ps, 2)`` holds
+module docstring for the full scheme): the pool of every layer and a
+``layer`` scalar read by the index_maps; ``lat_pages (L, P_total, ps, R+dr)``
+packs ``[c_kv | k_rope]`` per token; ``scale_pages (L, P_total, ps, 2)`` holds
 the DUAL FP8 scales (col 0 = c_kv, col 1 = k_rope — separate dynamic
 ranges, Eq. 6); the lane's physical page table is scalar-prefetched and
 dereferenced in the BlockSpec index_map (-1 = unallocated/SkipSet, never
@@ -68,12 +69,13 @@ def resident_rows(RW: int, H: int, cap: int = 0) -> int:
     return rl
 
 
-def _latent_chunk_kernel(phys_ref,                   # scalar prefetch
-                         ql_ref, qr_ref, pos_ref, lat_ref, sc_ref,
-                         o_ref, *refs,
+def _latent_chunk_kernel(phys_ref, lyr_ref,          # scalar prefetch
+                         ql_ref, qr_ref, pos_ref, lat_ref, *refs,
                          ps: int, R: int, sm_scale: float, opt_kv: bool,
                          window: int, sink: int, num_pages: int,
                          return_state: bool):
+    # the scale block comes only under Opt-KV
+    sc_ref, o_ref, *refs = refs if opt_kv else (None, *refs)
     if return_state:
         mo_ref, lo_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -147,15 +149,17 @@ def _latent_chunk_kernel(phys_ref,                   # scalar prefetch
 
 
 def latent_chunk_prefill(q_lat, q_rope, positions, lat_pages, scale_pages,
-                         phys_table, *, sm_scale: float, opt_kv: bool,
+                         layer, phys_table, *, sm_scale: float, opt_kv: bool,
                          window: int = 0, sink_pages: int = 0,
                          block_q: int = 0, return_state: bool = False,
                          interpret: bool = False, seg_q=None, page_seg=None,
                          page_base=None):
     """q_lat: (B, S, H, R) W_uk-absorbed chunk queries; q_rope: (B, S, H, dr);
-    positions: (B, S) absolute per-row positions; lat_pages: (P_total, ps,
-    R+dr) GLOBAL latent pool [fp8 if opt_kv]; scale_pages: (P_total, ps, 2)
-    f32 dual scales or None; phys_table: (B, NP) int32 physical pages in
+    positions: (B, S) absolute per-row positions; lat_pages: (L, P_total,
+    ps, R+dr) GLOBAL latent pool of every layer [fp8 if opt_kv]; scale_pages:
+    (L, P_total, ps, 2) f32 dual scales, read only under opt_kv (None
+    otherwise); layer: int32 scalar, the layer to attend; phys_table: (B, NP)
+    int32 physical pages in
     logical order (-1 = skip, never DMA'd). The chunk's own latents must
     already be written to the pool. Returns o_lat (B, S, H, R) f32; the
     caller applies the ``w_uv`` expansion. With ``return_state`` also the
@@ -169,7 +173,7 @@ def latent_chunk_prefill(q_lat, q_rope, positions, lat_pages, scale_pages,
     segment restarts its position domain. Defaults (no packing) reduce to
     the exact previous math: base == slot index, one segment everywhere."""
     B, S, H, R = q_lat.shape
-    P, ps, W = lat_pages.shape
+    _, P, ps, W = lat_pages.shape
     dr = q_rope.shape[-1]
     NP = phys_table.shape[1]
     RW = S * H                                       # row r = s*H + h
@@ -196,14 +200,21 @@ def latent_chunk_prefill(q_lat, q_rope, positions, lat_pages, scale_pages,
                         page_base.astype(jnp.int32),
                         page_seg.astype(jnp.int32)])              # (3, B, NP)
 
-    if scale_pages is None:
-        scale_pages = jnp.zeros((P, ps, 2), jnp.float32)
+    def lat_idx(b, i, j, phys, lyr):
+        return (lyr[0], jnp.maximum(phys[0, b, j], 0), 0, 0)
 
-    def lat_idx(b, i, j, phys):
-        return (jnp.maximum(phys[0, b, j], 0), 0, 0)
+    in_specs = [
+        pl.BlockSpec((1, rl, R), lambda b, i, j, phys, lyr: (b, i, 0)),
+        pl.BlockSpec((1, rl, dr), lambda b, i, j, phys, lyr: (b, i, 0)),
+        pl.BlockSpec((1, 2, rl), lambda b, i, j, phys, lyr: (b, 0, i)),
+        pl.BlockSpec((None, 1, ps, W), lat_idx)]
+    operands = [qlf, qrf, pos_rep, lat_pages]
+    if opt_kv:
+        in_specs += [pl.BlockSpec((None, 1, ps, 2), lat_idx)]
+        operands += [scale_pages]
 
-    out_blk = pl.BlockSpec((1, rl, R), lambda b, i, j, phys: (b, i, 0))
-    st_blk = pl.BlockSpec((1, rl, 128), lambda b, i, j, phys: (b, i, 0))
+    out_blk = pl.BlockSpec((1, rl, R), lambda b, i, j, phys, lyr: (b, i, 0))
+    st_blk = pl.BlockSpec((1, rl, 128), lambda b, i, j, phys, lyr: (b, i, 0))
     out_specs = [out_blk]
     out_shape = [jax.ShapeDtypeStruct((B, RW, R), jnp.float32)]
     if return_state:
@@ -217,15 +228,9 @@ def latent_chunk_prefill(q_lat, q_rope, positions, lat_pages, scale_pages,
     res = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(B, NQ, NP),
-            in_specs=[
-                pl.BlockSpec((1, rl, R), lambda b, i, j, phys: (b, i, 0)),
-                pl.BlockSpec((1, rl, dr), lambda b, i, j, phys: (b, i, 0)),
-                pl.BlockSpec((1, 2, rl), lambda b, i, j, phys: (b, 0, i)),
-                pl.BlockSpec((1, ps, W), lat_idx),
-                pl.BlockSpec((1, ps, 2), lat_idx),
-            ],
+            in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((rl, 128), jnp.float32),
@@ -237,7 +242,7 @@ def latent_chunk_prefill(q_lat, q_rope, positions, lat_pages, scale_pages,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(table3, qlf, qrf, pos_rep, lat_pages, scale_pages)
+    )(table3, jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     out = res[0].reshape(B, S, H, R)
     if not return_state:
         return out

@@ -15,21 +15,27 @@ One kernel fuses all three techniques (DESIGN.md §2):
             ``block_sum`` shared-memory reduction becomes a VMEM-resident
             running (max, sum, acc) carried across the page grid dim.
 
-Pool addressing: the cache has NO batch dimension — ``k/v_pages`` are
-``(P_total, Hkv, ps, D)`` shared by every lane. Each lane's *physical* page
-table is scalar-prefetched and dereferenced inside the BlockSpec index_map,
-so the block DMA'd at grid step (b, h, i) IS lane b's i-th logical page —
-the paper's "lazy memory mapping" realised as data-dependent prefetch. A
-parallel *logical* table supplies token positions (logical page id) for the
-causal / sliding-window masks; for dense decode it is simply ``arange``.
+Pool addressing: the cache has NO batch dimension — the kernel takes the
+WHOLE pool of every layer, ``kv_pages (L, 2, P_total, Hkv, ps, D)``, shared by
+every lane, and a ``layer`` scalar. The layer is chosen in the BlockSpec
+index_maps and one block carries a head page's K and V (a (2, ps, D) pair of
+tiles), so no layer or half is ever sliced out of the pool into a buffer of
+its own.
+Each lane's *physical* page table is scalar-prefetched and dereferenced
+inside the index_map too, so the block DMA'd at grid step (b, h, i) IS lane
+b's i-th logical page — the paper's "lazy memory mapping" realised as
+data-dependent prefetch. A parallel *logical* table supplies token positions
+(logical page id) for the causal / sliding-window masks; for dense decode it
+is simply ``arange``.
 
 TPU layout: grid = (batch, kv_head, page). Heads come before tokens within
-a page, so one grid step DMAs the (page_size, head_dim) tile of one KV head
-— lane dim = head_dim, sublane = tokens, which satisfies Mosaic's (8, 128)
-block rule (a token-major ``(ps, Hkv, D)`` page would need a block of 1 in
-the sublane place). The per-token fp8 scales ``(P_total, Hkv, ps)`` are
-DMA'd a page at a time for all heads (a (Hkv, ps) block spans the array's
-last two dims) and the kernel reads its head's row; they are applied to the
+a page, so one grid step DMAs the K and V (page_size, head_dim) tiles of one
+KV head — lane dim = head_dim, sublane = tokens, which satisfies Mosaic's
+(8, 128) block rule (a token-major ``(ps, Hkv, D)`` page would need a block
+of 1 in the sublane place). The per-token fp8 scales ``(L, 2, P_total, Hkv,
+ps)`` are DMA'd a page at a time for all heads, K's and V's in one block
+(each (Hkv, ps) spans the array's last two dims), and the kernel reads its
+head's rows; they are applied to the
 (G, ps) score and probability tiles as a row vector, which equals
 dequantizing the K/V rows. Scratch lives in VMEM; (m, l) are kept
 lane-replicated (G, 128) as on-chip reduction tiles.
@@ -52,11 +58,13 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG = -1e30
 
 
-def _pool_kernel(len_ref, phys_ref, log_ref,     # scalar prefetch
-                 q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                 o_ref, *refs,
+def _pool_kernel(len_ref, phys_ref, log_ref, lyr_ref,     # scalar prefetch
+                 q_ref, kv_ref, *refs,
                  ps: int, rep: int, opt_kv: bool, window: int, sink: int,
                  num_sel: int, return_state: bool):
+    # kv_ref (2, 1, 1, ps, D): one head page's K and V; sc_ref (2, 1, Hkv,
+    # ps): its page's K and V scales, which come only under Opt-KV
+    sc_ref, o_ref, *refs = refs if opt_kv else (None, *refs)
     if return_state:
         mo_ref, lo_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -81,13 +89,13 @@ def _pool_kernel(len_ref, phys_ref, log_ref,     # scalar prefetch
     @pl.when(page >= 0)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                  # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (ps, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = kv_ref[0, 0, 0].astype(jnp.float32)              # (ps, D)
+        v = kv_ref[1, 0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * (1.0 / math.sqrt(D))                         # (G, ps)
         if opt_kv:  # Opt-KV Eq. 6: fused dequant at the VMEM boundary
-            s = s * ks_ref[0, pl.ds(kvh, 1), :]              # (1, ps) row
+            s = s * sc_ref[0, 0, pl.ds(kvh, 1), :]           # (1, ps) row
         pos = lpage * ps + jax.lax.broadcasted_iota(jnp.int32, (G, ps), 1)
         mask = pos < length
         if window:
@@ -102,7 +110,7 @@ def _pool_kernel(len_ref, phys_ref, log_ref,     # scalar prefetch
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)                               # (G, ps)
         l_new = l_ref[:, 0:1] * corr + jnp.sum(p, -1, keepdims=True)
-        pv = p * vs_ref[0, pl.ds(kvh, 1), :] if opt_kv else p
+        pv = p * sc_ref[1, 0, pl.ds(kvh, 1), :] if opt_kv else p
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -121,20 +129,21 @@ def _pool_kernel(len_ref, phys_ref, log_ref,     # scalar prefetch
             lo_ref[0, 0] = l_ref[...]
 
 
-def paged_pool_decode(q, k_pages, v_pages, k_scale, v_scale, cache_len,
+def paged_pool_decode(q, kv_pages, scale_pages, layer, cache_len,
                       phys_table, log_table, *, opt_kv: bool, opt_gqa: bool,
                       window: int = 0, sink_pages: int = 0,
                       return_state: bool = False, interpret: bool = False):
-    """q: (B, Hq, D); k/v_pages: (P_total, Hkv, ps, D) GLOBAL pool [fp8 if
-    opt_kv]; k/v_scale: (P_total, Hkv, ps) f32 or None; cache_len: (B,) int32;
-    phys_table/log_table: (B, NSel) int32 — physical page to DMA / logical
-    page id for positions; -1 = skip (never DMA'd). Returns (B, Hq, D);
-    with ``return_state`` also the final online-softmax (m, l) as (B, Hq)
-    f32 — a shard holding NONE of a lane's pages reports (m=-1e30, l=0), so
-    its contribution vanishes in the cross-shard log-sum-exp merge
-    (``kernels.sharded``)."""
+    """q: (B, Hq, D); kv_pages: (L, 2, P_total, Hkv, ps, D) the GLOBAL pool of
+    every layer [fp8 if opt_kv]; scale_pages: (L, 2, P_total, Hkv, ps) f32,
+    read only under opt_kv (None otherwise); layer: int32 scalar, the layer
+    to attend; cache_len: (B,) int32; phys_table/log_table: (B, NSel) int32
+    — physical page to DMA / logical page id for positions; -1 = skip (never
+    DMA'd). Returns (B, Hq, D); with ``return_state`` also the final
+    online-softmax (m, l) as (B, Hq) f32 — a shard holding NONE of a lane's
+    pages reports (m=-1e30, l=0), so its contribution vanishes in the
+    cross-shard log-sum-exp merge (``kernels.sharded``)."""
     B, Hq, D = q.shape
-    P, Hkv, ps, _ = k_pages.shape
+    _, _, P, Hkv, ps, _ = kv_pages.shape
     NSel = phys_table.shape[1]
 
     if opt_gqa:
@@ -144,20 +153,26 @@ def paged_pool_decode(q, k_pages, v_pages, k_scale, v_scale, cache_len,
         G, heads, rep = 1, Hq, max(Hq // Hkv, 1)
     qf = q.reshape(B, heads, G, D)
 
-    if k_scale is None:
-        k_scale = jnp.zeros((P, Hkv, ps), jnp.float32)
-        v_scale = k_scale
+    # the layer is picked here, in place in the pool; one block holds the
+    # head page's K and V
+    def kv_idx(b, h, s, L, phys, log, lyr):
+        return (lyr[0], 0, jnp.maximum(phys[b, s], 0), h // rep, 0, 0)
 
-    def kv_idx(b, h, s, L, phys, log):
-        return (jnp.maximum(phys[b, s], 0), h // rep, 0, 0)
+    def sc_idx(b, h, s, L, phys, log, lyr):
+        return (lyr[0], 0, jnp.maximum(phys[b, s], 0), 0, 0)
 
-    def sc_idx(b, h, s, L, phys, log):
-        return (jnp.maximum(phys[b, s], 0), 0, 0)
+    in_specs = [pl.BlockSpec((1, 1, G, D),
+                             lambda b, h, s, L, phys, log, lyr: (b, h, 0, 0)),
+                pl.BlockSpec((None, 2, 1, 1, ps, D), kv_idx)]
+    operands = [qf, kv_pages]
+    if opt_kv:
+        in_specs += [pl.BlockSpec((None, 2, 1, Hkv, ps), sc_idx)]
+        operands += [scale_pages]
 
     out_blk = pl.BlockSpec((1, 1, G, D),
-                           lambda b, h, s, L, phys, log: (b, h, 0, 0))
+                           lambda b, h, s, L, phys, log, lyr: (b, h, 0, 0))
     st_blk = pl.BlockSpec((1, 1, G, 128),
-                          lambda b, h, s, L, phys, log: (b, h, 0, 0))
+                          lambda b, h, s, L, phys, log, lyr: (b, h, 0, 0))
     out_specs = [out_blk]
     out_shape = [jax.ShapeDtypeStruct((B, heads, G, D), q.dtype)]
     if return_state:
@@ -170,16 +185,9 @@ def paged_pool_decode(q, k_pages, v_pages, k_scale, v_scale, cache_len,
     res = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B, heads, NSel),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, D),
-                             lambda b, h, s, L, phys, log: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, ps, D), kv_idx),
-                pl.BlockSpec((1, 1, ps, D), kv_idx),
-                pl.BlockSpec((1, Hkv, ps), sc_idx),
-                pl.BlockSpec((1, Hkv, ps), sc_idx),
-            ],
+            in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((G, 128), jnp.float32),
@@ -191,8 +199,8 @@ def paged_pool_decode(q, k_pages, v_pages, k_scale, v_scale, cache_len,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(cache_len, phys_table, log_table, qf, k_pages, v_pages,
-      k_scale, v_scale)
+    )(cache_len, phys_table, log_table,
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     out = res[0].reshape(B, Hq, D)
     if not return_state:
         return out
@@ -201,9 +209,8 @@ def paged_pool_decode(q, k_pages, v_pages, k_scale, v_scale, cache_len,
     return out, m, l
 
 
-def _visit_kernel(vp_ref, vm_ref, vl_ref,            # scalar prefetch
-                  q_ref, len_ref, k_ref, v_ref, ks_ref, vs_ref,
-                  o_ref, *refs,
+def _visit_kernel(vp_ref, vm_ref, vl_ref, lyr_ref,    # scalar prefetch
+                  q_ref, len_ref, kv_ref, *refs,
                   ps: int, G: int, rep: int, opt_kv: bool, window: int,
                   sink: int, num_visits: int, return_state: bool):
     """Cross-lane visit grid: one step per deduplicated (page, lane-set).
@@ -218,6 +225,7 @@ def _visit_kernel(vp_ref, vm_ref, vl_ref,            # scalar prefetch
     evolves update-for-update like ``_pool_kernel`` — the no-sharing plan
     is bit-identical, a shared plan saves (members - 1) page streams.
     """
+    sc_ref, o_ref, *refs = refs if opt_kv else (None, *refs)
     if return_state:
         mo_ref, lo_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -238,13 +246,13 @@ def _visit_kernel(vp_ref, vm_ref, vl_ref,            # scalar prefetch
     @pl.when(page >= 0)
     def _compute():
         q = q_ref[0].astype(jnp.float32)                     # (BG, D)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (ps, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = kv_ref[0, 0, 0].astype(jnp.float32)              # (ps, D)
+        v = kv_ref[1, 0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * (1.0 / math.sqrt(q_ref.shape[2]))            # (BG, ps)
         if opt_kv:  # Opt-KV Eq. 6: fused dequant — ONCE per visit, not per lane
-            s = s * ks_ref[0, pl.ds(kvh, 1), :]
+            s = s * sc_ref[0, 0, pl.ds(kvh, 1), :]
         # row r belongs to lane r // G; membership = lane's bit in the mask
         lane_r = jax.lax.broadcasted_iota(jnp.int32, (BG, 1), 0) // G
         member = jnp.equal(
@@ -266,7 +274,7 @@ def _visit_kernel(vp_ref, vm_ref, vl_ref,            # scalar prefetch
         # non-member rows hard-zero so their (m, l, acc) are untouched
         p = jnp.where(member, jnp.exp(s - m_new), 0.0)       # (BG, ps)
         l_new = l_ref[:, 0:1] * corr + jnp.sum(p, -1, keepdims=True)
-        pv = p * vs_ref[0, pl.ds(kvh, 1), :] if opt_kv else p
+        pv = p * sc_ref[1, 0, pl.ds(kvh, 1), :] if opt_kv else p
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             pv, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -283,20 +291,20 @@ def _visit_kernel(vp_ref, vm_ref, vl_ref,            # scalar prefetch
             lo_ref[0] = l_ref[...]
 
 
-def paged_pool_decode_visits(q, k_pages, v_pages, k_scale, v_scale,
-                             cache_len, visit_page, visit_lanes, visit_log,
+def paged_pool_decode_visits(q, kv_pages, scale_pages, layer, cache_len,
+                             visit_page, visit_lanes, visit_log,
                              *, opt_kv: bool, opt_gqa: bool, window: int = 0,
                              sink_pages: int = 0, return_state: bool = False,
                              interpret: bool = False):
-    """Batched-visit twin of ``paged_pool_decode``: same pool/query/window
-    semantics, but the page grid dim iterates a deduplicated cross-lane
-    visit list (``kernels.visits.plan_visits``) instead of (lane x page) —
-    each page shared by N lanes is streamed into VMEM once, not N times.
-    visit_page/visit_lanes/visit_log: (NV,) int32 plan vectors. Requires
-    B <= visits.MAX_VISIT_LANES (int32 lane bitmask); ``ops`` dispatches
-    back to the per-lane grid beyond that."""
+    """Batched-visit twin of ``paged_pool_decode``: same pool/layer/query/
+    window semantics, but the page grid dim iterates a deduplicated
+    cross-lane visit list (``kernels.visits.plan_visits``) instead of
+    (lane x page) — each page shared by N lanes is streamed into VMEM once,
+    not N times. visit_page/visit_lanes/visit_log: (NV,) int32 plan vectors.
+    Requires B <= visits.MAX_VISIT_LANES (int32 lane bitmask); ``ops``
+    dispatches back to the per-lane grid beyond that."""
     B, Hq, D = q.shape
-    P, Hkv, ps, _ = k_pages.shape
+    _, _, P, Hkv, ps, _ = kv_pages.shape
     NV = visit_page.shape[0]
 
     if opt_gqa:
@@ -310,18 +318,25 @@ def paged_pool_decode_visits(q, k_pages, v_pages, k_scale, v_scale,
         cache_len.astype(jnp.int32)[:, None, None], (B, G, 128)
     ).reshape(BG, 128)
 
-    if k_scale is None:
-        k_scale = jnp.zeros((P, Hkv, ps), jnp.float32)
-        v_scale = k_scale
+    def kv_idx(h, v, vp, vl, vm, lyr):
+        return (lyr[0], 0, jnp.maximum(vp[v], 0), h // rep, 0, 0)
 
-    def kv_idx(h, v, vp, vl, vm):
-        return (jnp.maximum(vp[v], 0), h // rep, 0, 0)
+    def sc_idx(h, v, vp, vl, vm, lyr):
+        return (lyr[0], 0, jnp.maximum(vp[v], 0), 0, 0)
 
-    def sc_idx(h, v, vp, vl, vm):
-        return (jnp.maximum(vp[v], 0), 0, 0)
+    in_specs = [
+        pl.BlockSpec((1, BG, D), lambda h, v, vp, vl, vm, lyr: (h, 0, 0)),
+        pl.BlockSpec((BG, 128), lambda h, v, vp, vl, vm, lyr: (0, 0)),
+        pl.BlockSpec((None, 2, 1, 1, ps, D), kv_idx)]
+    operands = [qf, len_rows, kv_pages]
+    if opt_kv:
+        in_specs += [pl.BlockSpec((None, 2, 1, Hkv, ps), sc_idx)]
+        operands += [scale_pages]
 
-    out_blk = pl.BlockSpec((1, BG, D), lambda h, v, vp, vl, vm: (h, 0, 0))
-    st_blk = pl.BlockSpec((1, BG, 128), lambda h, v, vp, vl, vm: (h, 0, 0))
+    out_blk = pl.BlockSpec((1, BG, D),
+                           lambda h, v, vp, vl, vm, lyr: (h, 0, 0))
+    st_blk = pl.BlockSpec((1, BG, 128),
+                          lambda h, v, vp, vl, vm, lyr: (h, 0, 0))
     out_specs = [out_blk]
     out_shape = [jax.ShapeDtypeStruct((heads, BG, D), q.dtype)]
     if return_state:
@@ -334,16 +349,9 @@ def paged_pool_decode_visits(q, k_pages, v_pages, k_scale, v_scale,
     res = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(heads, NV),
-            in_specs=[
-                pl.BlockSpec((1, BG, D), lambda h, v, vp, vl, vm: (h, 0, 0)),
-                pl.BlockSpec((BG, 128), lambda h, v, vp, vl, vm: (0, 0)),
-                pl.BlockSpec((1, 1, ps, D), kv_idx),
-                pl.BlockSpec((1, 1, ps, D), kv_idx),
-                pl.BlockSpec((1, Hkv, ps), sc_idx),
-                pl.BlockSpec((1, Hkv, ps), sc_idx),
-            ],
+            in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((BG, 128), jnp.float32),
@@ -355,8 +363,8 @@ def paged_pool_decode_visits(q, k_pages, v_pages, k_scale, v_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(visit_page, visit_lanes, visit_log, qf, len_rows,
-      k_pages, v_pages, k_scale, v_scale)
+    )(visit_page, visit_lanes, visit_log,
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
 
     def unrows(x, last):
         return x.reshape(heads, B, G, last).transpose(1, 0, 2, 3) \

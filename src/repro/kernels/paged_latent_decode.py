@@ -8,11 +8,12 @@ streamed into VMEM exactly ONCE per decode step and shared by every absorbed
 query head; there is no per-head KV expansion anywhere on the path (Eq. 7/8's
 sharing argument with a group of size H).
 
-Latent pool addressing (one layer):
-  * ``lat_pages (P_total, ps, R+dr)`` — NO batch dimension; every lane shares
-    the pool. A token's cache line packs ``[c_kv | k_rope]`` back to back, so
-    one DMA fetches both score streams.
-  * ``scale_pages (P_total, ps, 2)`` — DUAL per-token FP8 scales (Eq. 6):
+Latent pool addressing (the pool of every layer, and a ``layer`` scalar
+that the BlockSpec index_maps read, so no layer is sliced out of the pool):
+  * ``lat_pages (L, P_total, ps, R+dr)`` — NO batch dimension; every lane
+    shares the pool. A token's cache line packs ``[c_kv | k_rope]`` back to
+    back, so one DMA fetches both score streams.
+  * ``scale_pages (L, P_total, ps, 2)`` — DUAL per-token FP8 scales (Eq. 6):
     column 0 dequantizes the c_kv segment, column 1 the k_rope segment. The
     two segments come from different projections with different dynamic
     ranges; a shared scale would crush the smaller segment's mantissa.
@@ -53,12 +54,13 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG = -1e30
 
 
-def _latent_kernel(len_ref, phys_ref, log_ref,       # scalar prefetch
-                   ql_ref, qr_ref, lat_ref, sc_ref,
-                   o_ref, *refs,
+def _latent_kernel(len_ref, phys_ref, log_ref, lyr_ref,   # scalar prefetch
+                   ql_ref, qr_ref, lat_ref, *refs,
                    ps: int, R: int, sm_scale: float, opt_kv: bool,
                    window: int, sink: int, num_sel: int,
                    return_state: bool):
+    # the scale block comes only under Opt-KV
+    sc_ref, o_ref, *refs = refs if opt_kv else (None, *refs)
     if return_state:
         mo_ref, lo_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -128,13 +130,15 @@ def _latent_kernel(len_ref, phys_ref, log_ref,       # scalar prefetch
             lo_ref[0] = l_ref[...]
 
 
-def paged_latent_decode(q_lat, q_rope, lat_pages, scale_pages, cache_len,
-                        phys_table, log_table, *, sm_scale: float,
+def paged_latent_decode(q_lat, q_rope, lat_pages, scale_pages, layer,
+                        cache_len, phys_table, log_table, *, sm_scale: float,
                         opt_kv: bool, window: int = 0, sink_pages: int = 0,
                         return_state: bool = False, interpret: bool = False):
     """q_lat: (B, H, R) W_uk-absorbed queries; q_rope: (B, H, dr); lat_pages:
-    (P_total, ps, R+dr) GLOBAL latent pool [fp8 if opt_kv]; scale_pages:
-    (P_total, ps, 2) f32 dual c/k_rope scales or None; cache_len: (B,) int32;
+    (L, P_total, ps, R+dr) GLOBAL latent pool of every layer [fp8 if opt_kv];
+    scale_pages: (L, P_total, ps, 2) f32 dual c/k_rope scales, read only
+    under opt_kv (None otherwise); layer: int32 scalar, the layer to attend;
+    cache_len: (B,) int32;
     phys_table/log_table: (B, NSel) int32 — physical page to DMA / logical
     page id for positions; -1 = skip (never DMA'd). ``sm_scale`` is the
     softmax scale 1/sqrt(dn+dr) — NOT derivable from R (absorption changes
@@ -143,17 +147,26 @@ def paged_latent_decode(q_lat, q_rope, lat_pages, scale_pages, cache_len,
     the final online-softmax (m, l) as (B, H) f32 for the cross-shard
     log-sum-exp merge (``kernels.sharded``)."""
     B, H, R = q_lat.shape
-    P, ps, W = lat_pages.shape
+    _, P, ps, W = lat_pages.shape
     NSel = phys_table.shape[1]
 
-    if scale_pages is None:
-        scale_pages = jnp.zeros((P, ps, 2), jnp.float32)
+    def lat_idx(b, s, L, phys, log, lyr):
+        return (lyr[0], jnp.maximum(phys[b, s], 0), 0, 0)
 
-    def lat_idx(b, s, L, phys, log):
-        return (jnp.maximum(phys[b, s], 0), 0, 0)
+    in_specs = [
+        pl.BlockSpec((1, H, R), lambda b, s, L, phys, log, lyr: (b, 0, 0)),
+        pl.BlockSpec((1, H, q_rope.shape[-1]),
+                     lambda b, s, L, phys, log, lyr: (b, 0, 0)),
+        pl.BlockSpec((None, 1, ps, W), lat_idx)]
+    operands = [q_lat, q_rope, lat_pages]
+    if opt_kv:
+        in_specs += [pl.BlockSpec((None, 1, ps, 2), lat_idx)]
+        operands += [scale_pages]
 
-    out_blk = pl.BlockSpec((1, H, R), lambda b, s, L, phys, log: (b, 0, 0))
-    st_blk = pl.BlockSpec((1, H, 128), lambda b, s, L, phys, log: (b, 0, 0))
+    out_blk = pl.BlockSpec((1, H, R),
+                           lambda b, s, L, phys, log, lyr: (b, 0, 0))
+    st_blk = pl.BlockSpec((1, H, 128),
+                          lambda b, s, L, phys, log, lyr: (b, 0, 0))
     out_specs = [out_blk]
     out_shape = [jax.ShapeDtypeStruct((B, H, R), jnp.float32)]
     if return_state:
@@ -166,15 +179,9 @@ def paged_latent_decode(q_lat, q_rope, lat_pages, scale_pages, cache_len,
     res = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B, NSel),
-            in_specs=[
-                pl.BlockSpec((1, H, R), lambda b, s, L, phys, log: (b, 0, 0)),
-                pl.BlockSpec((1, H, q_rope.shape[-1]),
-                             lambda b, s, L, phys, log: (b, 0, 0)),
-                pl.BlockSpec((1, ps, W), lat_idx),
-                pl.BlockSpec((1, ps, 2), lat_idx),
-            ],
+            in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((H, 128), jnp.float32),
@@ -186,16 +193,15 @@ def paged_latent_decode(q_lat, q_rope, lat_pages, scale_pages, cache_len,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(cache_len, phys_table, log_table, q_lat, q_rope, lat_pages,
-      scale_pages)
+    )(cache_len, phys_table, log_table,
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     if not return_state:
         return res[0]
     return res[0], res[1][..., 0], res[2][..., 0]
 
 
-def _latent_visit_kernel(vp_ref, vm_ref, vl_ref,     # scalar prefetch
-                         ql_ref, qr_ref, len_ref, lat_ref, sc_ref,
-                         o_ref, *refs,
+def _latent_visit_kernel(vp_ref, vm_ref, vl_ref, lyr_ref,   # scalar prefetch
+                         ql_ref, qr_ref, len_ref, lat_ref, *refs,
                          ps: int, R: int, H: int, sm_scale: float,
                          opt_kv: bool, window: int, sink: int,
                          num_visits: int, return_state: bool):
@@ -206,6 +212,7 @@ def _latent_visit_kernel(vp_ref, vm_ref, vl_ref,     # scalar prefetch
     dual-dequantizes its latent page ONCE and updates every member lane's
     running (m, l, acc) state; non-member rows take exact identity updates
     so the no-sharing plan is bit-identical to ``_latent_kernel``."""
+    sc_ref, o_ref, *refs = refs if opt_kv else (None, *refs)
     if return_state:
         mo_ref, lo_ref, m_ref, l_ref, acc_ref = refs
     else:
@@ -273,7 +280,7 @@ def _latent_visit_kernel(vp_ref, vm_ref, vl_ref,     # scalar prefetch
             lo_ref[...] = l_ref[...]
 
 
-def paged_latent_decode_visits(q_lat, q_rope, lat_pages, scale_pages,
+def paged_latent_decode_visits(q_lat, q_rope, lat_pages, scale_pages, layer,
                                cache_len, visit_page, visit_lanes, visit_log,
                                *, sm_scale: float, opt_kv: bool,
                                window: int = 0, sink_pages: int = 0,
@@ -282,10 +289,11 @@ def paged_latent_decode_visits(q_lat, q_rope, lat_pages, scale_pages,
     """Batched-visit twin of ``paged_latent_decode``: the page grid dim
     iterates a deduplicated cross-lane visit list (``kernels.visits``) so a
     latent page shared by N lanes is streamed/dequantized once per step.
+    Pool, scales and ``layer`` as in ``paged_latent_decode``;
     visit_page/visit_lanes/visit_log: (NV,) int32 plan vectors; requires
     B <= visits.MAX_VISIT_LANES."""
     B, H, R = q_lat.shape
-    P, ps, W = lat_pages.shape
+    _, P, ps, W = lat_pages.shape
     dr = q_rope.shape[-1]
     NV = visit_page.shape[0]
     BH = B * H
@@ -296,14 +304,21 @@ def paged_latent_decode_visits(q_lat, q_rope, lat_pages, scale_pages,
         cache_len.astype(jnp.int32)[:, None, None], (B, H, 128)
     ).reshape(BH, 128)
 
-    if scale_pages is None:
-        scale_pages = jnp.zeros((P, ps, 2), jnp.float32)
+    def lat_idx(v, vp, vl, vm, lyr):
+        return (lyr[0], jnp.maximum(vp[v], 0), 0, 0)
 
-    def lat_idx(v, vp, vl, vm):
-        return (jnp.maximum(vp[v], 0), 0, 0)
+    in_specs = [
+        pl.BlockSpec((BH, R), lambda v, vp, vl, vm, lyr: (0, 0)),
+        pl.BlockSpec((BH, dr), lambda v, vp, vl, vm, lyr: (0, 0)),
+        pl.BlockSpec((BH, 128), lambda v, vp, vl, vm, lyr: (0, 0)),
+        pl.BlockSpec((None, 1, ps, W), lat_idx)]
+    operands = [qlf, qrf, len_rows, lat_pages]
+    if opt_kv:
+        in_specs += [pl.BlockSpec((None, 1, ps, 2), lat_idx)]
+        operands += [scale_pages]
 
-    out_blk = pl.BlockSpec((BH, R), lambda v, vp, vl, vm: (0, 0))
-    st_blk = pl.BlockSpec((BH, 128), lambda v, vp, vl, vm: (0, 0))
+    out_blk = pl.BlockSpec((BH, R), lambda v, vp, vl, vm, lyr: (0, 0))
+    st_blk = pl.BlockSpec((BH, 128), lambda v, vp, vl, vm, lyr: (0, 0))
     out_specs = [out_blk]
     out_shape = [jax.ShapeDtypeStruct((BH, R), jnp.float32)]
     if return_state:
@@ -317,15 +332,9 @@ def paged_latent_decode_visits(q_lat, q_rope, lat_pages, scale_pages,
     res = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(NV,),
-            in_specs=[
-                pl.BlockSpec((BH, R), lambda v, vp, vl, vm: (0, 0)),
-                pl.BlockSpec((BH, dr), lambda v, vp, vl, vm: (0, 0)),
-                pl.BlockSpec((BH, 128), lambda v, vp, vl, vm: (0, 0)),
-                pl.BlockSpec((1, ps, W), lat_idx),
-                pl.BlockSpec((1, ps, 2), lat_idx),
-            ],
+            in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((BH, 128), jnp.float32),
@@ -337,8 +346,8 @@ def paged_latent_decode_visits(q_lat, q_rope, lat_pages, scale_pages,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(visit_page, visit_lanes, visit_log, qlf, qrf, len_rows,
-      lat_pages, scale_pages)
+    )(visit_page, visit_lanes, visit_log,
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     out = res[0].reshape(B, H, R)
     if not return_state:
         return out

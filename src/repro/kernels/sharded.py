@@ -17,10 +17,14 @@ single-host kernel against only its owned contiguous page range:
     pages axes:  m* = pmax(m);  w_s = exp(m_s - m*) * l_s;
     out = psum(w_s * o_s) / psum(w_s).  A shard holding none of a lane's
     pages reports (m = -1e30, l = 0) and so contributes nothing;
-  * the write path stays shard-local too: global flat slots are translated
-    to the shard's slot range and the same write kernel runs per shard
-    (other shards' slots become SkipSet -1s), so the pool is written in
-    place with NO cross-shard traffic.
+  * the write path stays shard-local too: lines of the whole pool are
+    translated to lines of the shard's own pool and the same write kernel
+    runs per shard (other shards' lines become SkipSet -1s), so the pool is
+    written in place with NO cross-shard traffic.
+
+Every layer's pool travels whole, ``(L, 2, P_total, Hkv, ps, D)`` (latent:
+``(L, P_total, ps, R+dr)``), pages-sharded on its pages axis, with the
+``layer`` scalar replicated: the per-shard kernels pick the layer in place.
 
 The engine-facing contract is unchanged: callers pass GLOBAL pools, GLOBAL
 tables/slots, and get replicated outputs — ``kernels.ops`` dispatches here
@@ -39,12 +43,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.cache.quant import quantize_latent
 # PAGES_AXES — the mesh axes the pages axis is sharded over — lives with
 # the shard-ownership math in core.opt_kv (re-exported here for kernel-side
 # callers); host tooling reads it without importing the Pallas stack.
 from repro.core.opt_kv import (PAGES_AXES,                  # noqa: F401
-                               global_to_local_pages, global_to_local_slots)
+                               global_to_local_lines, global_to_local_pages,
+                               scatter_latent)
 from repro.kernels import flash_chunk_prefill as _fc
 from repro.kernels import kv_cache_write as _kw
 from repro.kernels import latent_chunk_prefill as _lc
@@ -109,35 +113,33 @@ def _pages_spec(ndim: int, pages_dim: int, ctx: ShardCtx) -> P:
 # ------------------------------------------------------------- read path --
 @partial(jax.jit, static_argnames=("ctx", "opt_kv", "opt_gqa", "window",
                                    "sink_pages", "share_visits", "interpret"))
-def paged_pool_decode(ctx: ShardCtx, q, kv_pages, scale_pages, cache_len,
-                      phys_table, log_table, *, opt_kv: bool, opt_gqa: bool,
-                      window: int = 0, sink_pages: int = 0,
+def paged_pool_decode(ctx: ShardCtx, q, kv_pages, scale_pages, layer,
+                      cache_len, phys_table, log_table, *, opt_kv: bool,
+                      opt_gqa: bool, window: int = 0, sink_pages: int = 0,
                       share_visits: bool = False, interpret: bool = False):
-    """Distributed ``paged_gqa_decode``: kv_pages (2, P_total, Hkv, ps, D)
-    pages-sharded over ``ctx.axes``; q/tables/cache_len replicated; returns
-    the replicated (B, Hq, D) attention output. With ``share_visits`` each
-    shard plans its visit list AFTER the global->local page translation, so
-    visits are deduplicated within (and never cross) the shard's own page
-    range."""
-    P_total = kv_pages.shape[1]
+    """Distributed ``paged_gqa_decode``: kv_pages (L, 2, P_total, Hkv, ps, D)
+    pages-sharded over ``ctx.axes``; q/layer/tables/cache_len replicated;
+    returns the replicated (B, Hq, D) attention output. With
+    ``share_visits`` each shard plans its visit list AFTER the global->local
+    page translation, so visits are deduplicated within (and never cross)
+    the shard's own page range."""
+    P_total = kv_pages.shape[2]
     P_local = P_total // ctx.num_shards
-    if scale_pages is None:
-        scale_pages = jnp.zeros(kv_pages.shape[:-1], jnp.float32)
     use_visits = share_visits and 1 < q.shape[0] <= _vs.MAX_VISIT_LANES
 
-    def body(q, kv, sc, cl, phys, log):
+    def body(q, kv, sc, lyr, cl, phys, log):
         first = _shard_index(ctx) * P_local
         lphys = global_to_local_pages(phys, first, P_local)
         if use_visits:
             vp, vm, vl = _vs.plan_visits(lphys, log)
             o, m, l = _pd.paged_pool_decode_visits(
-                q, kv[0], kv[1], sc[0], sc[1], cl, vp, vm, vl,
+                q, kv, sc, lyr, cl, vp, vm, vl,
                 opt_kv=opt_kv, opt_gqa=opt_gqa, window=window,
                 sink_pages=sink_pages, return_state=True,
                 interpret=interpret)
         else:
             o, m, l = _pd.paged_pool_decode(
-                q, kv[0], kv[1], sc[0], sc[1], cl, lphys, log,
+                q, kv, sc, lyr, cl, lphys, log,
                 opt_kv=opt_kv, opt_gqa=opt_gqa, window=window,
                 sink_pages=sink_pages, return_state=True,
                 interpret=interpret)
@@ -145,31 +147,30 @@ def paged_pool_decode(ctx: ShardCtx, q, kv_pages, scale_pages, cache_len,
 
     return jax.shard_map(
         body, mesh=ctx.mesh,
-        in_specs=(P(), _pages_spec(5, 1, ctx), _pages_spec(4, 1, ctx),
-                  P(), P(), P()),
+        in_specs=(P(), _pages_spec(6, 2, ctx), _pages_spec(5, 2, ctx),
+                  P(), P(), P(), P()),
         out_specs=P(), check_vma=False,
-    )(q, kv_pages, scale_pages, cache_len.astype(jnp.int32),
-      phys_table.astype(jnp.int32), log_table.astype(jnp.int32))
+    )(q, kv_pages, scale_pages, jnp.asarray(layer, jnp.int32),
+      cache_len.astype(jnp.int32), phys_table.astype(jnp.int32),
+      log_table.astype(jnp.int32))
 
 
 @partial(jax.jit, static_argnames=("ctx", "opt_kv", "opt_gqa", "window",
                                    "sink_pages", "interpret"))
 def paged_chunk_prefill(ctx: ShardCtx, q, positions, kv_pages, scale_pages,
-                        phys_table, *, opt_kv: bool, opt_gqa: bool,
+                        layer, phys_table, *, opt_kv: bool, opt_gqa: bool,
                         window: int = 0, sink_pages: int = 0,
                         interpret: bool = False, seg_q=None, page_seg=None,
                         page_base=None):
     """Distributed ``flash_chunk_prefill``: chunk queries (B, S, Hq, D)
-    replicated, pool pages-sharded; per-shard partials lse-merged. The
-    packing tables (seg/base) live in the LOGICAL page domain, so they ride
-    along replicated and untranslated — only the physical table is mapped
-    into each shard's local range."""
+    replicated, pool (L, 2, P_total, Hkv, ps, D) pages-sharded; per-shard
+    partials lse-merged. The packing tables (seg/base) live in the LOGICAL
+    page domain, so they ride along replicated and untranslated — only the
+    physical table is mapped into each shard's local range."""
     B, S = positions.shape
-    P_total = kv_pages.shape[1]
+    P_total = kv_pages.shape[2]
     NP = phys_table.shape[1]
     P_local = P_total // ctx.num_shards
-    if scale_pages is None:
-        scale_pages = jnp.zeros(kv_pages.shape[:-1], jnp.float32)
     if seg_q is None:
         seg_q = jnp.zeros((B, S), jnp.int32)
     if page_seg is None:
@@ -177,11 +178,11 @@ def paged_chunk_prefill(ctx: ShardCtx, q, positions, kv_pages, scale_pages,
     if page_base is None:
         page_base = jnp.broadcast_to(jnp.arange(NP, dtype=jnp.int32), (B, NP))
 
-    def body(q, pos, kv, sc, phys, sq, pseg, pbase):
+    def body(q, pos, kv, sc, lyr, phys, sq, pseg, pbase):
         first = _shard_index(ctx) * P_local
         lphys = global_to_local_pages(phys, first, P_local)
         o, m, l = _fc.flash_chunk_prefill(
-            q, pos, kv[0], kv[1], sc[0], sc[1], lphys,
+            q, pos, kv, sc, lyr, lphys,
             opt_kv=opt_kv, opt_gqa=opt_gqa, window=window,
             sink_pages=sink_pages, return_state=True, interpret=interpret,
             seg_q=sq, page_seg=pseg, page_base=pbase)
@@ -189,73 +190,73 @@ def paged_chunk_prefill(ctx: ShardCtx, q, positions, kv_pages, scale_pages,
 
     return jax.shard_map(
         body, mesh=ctx.mesh,
-        in_specs=(P(), P(), _pages_spec(5, 1, ctx), _pages_spec(4, 1, ctx),
-                  P(), P(), P(), P()),
+        in_specs=(P(), P(), _pages_spec(6, 2, ctx), _pages_spec(5, 2, ctx),
+                  P(), P(), P(), P(), P()),
         out_specs=P(), check_vma=False,
     )(q, positions.astype(jnp.int32), kv_pages, scale_pages,
-      phys_table.astype(jnp.int32), seg_q.astype(jnp.int32),
-      page_seg.astype(jnp.int32), page_base.astype(jnp.int32))
+      jnp.asarray(layer, jnp.int32), phys_table.astype(jnp.int32),
+      seg_q.astype(jnp.int32), page_seg.astype(jnp.int32),
+      page_base.astype(jnp.int32))
 
 
 @partial(jax.jit, static_argnames=("ctx", "sm_scale", "opt_kv", "window",
                                    "sink_pages", "share_visits", "interpret"))
 def paged_latent_decode(ctx: ShardCtx, q_lat, q_rope, lat_pages, scale_pages,
-                        cache_len, phys_table, log_table, *, sm_scale: float,
-                        opt_kv: bool, window: int = 0, sink_pages: int = 0,
-                        share_visits: bool = False, interpret: bool = False):
-    """Distributed ``paged_latent_decode``: latent pool (P_total, ps, R+dr)
-    pages-sharded; absorbed queries replicated; returns o_lat (B, H, R) f32.
-    With ``share_visits`` each shard plans its visit list AFTER the
-    global->local translation (shard-local visit lists, see
+                        layer, cache_len, phys_table, log_table, *,
+                        sm_scale: float, opt_kv: bool, window: int = 0,
+                        sink_pages: int = 0, share_visits: bool = False,
+                        interpret: bool = False):
+    """Distributed ``paged_latent_decode``: latent pool (L, P_total, ps,
+    R+dr) pages-sharded; absorbed queries and layer replicated; returns
+    o_lat (B, H, R) f32. With ``share_visits`` each shard plans its visit
+    list AFTER the global->local translation (shard-local visit lists, see
     ``paged_pool_decode``)."""
-    P_total, ps, _ = lat_pages.shape
+    P_total = lat_pages.shape[1]
     P_local = P_total // ctx.num_shards
-    if scale_pages is None:
-        scale_pages = jnp.zeros((P_total, ps, 2), jnp.float32)
     use_visits = share_visits and 1 < q_lat.shape[0] <= _vs.MAX_VISIT_LANES
 
-    def body(ql, qr, lat, sc, cl, phys, log):
+    def body(ql, qr, lat, sc, lyr, cl, phys, log):
         first = _shard_index(ctx) * P_local
         lphys = global_to_local_pages(phys, first, P_local)
         if use_visits:
             vp, vm, vl = _vs.plan_visits(lphys, log)
             o, m, l = _ld.paged_latent_decode_visits(
-                ql, qr, lat, sc, cl, vp, vm, vl, sm_scale=sm_scale,
+                ql, qr, lat, sc, lyr, cl, vp, vm, vl, sm_scale=sm_scale,
                 opt_kv=opt_kv, window=window, sink_pages=sink_pages,
                 return_state=True, interpret=interpret)
         else:
             o, m, l = _ld.paged_latent_decode(
-                ql, qr, lat, sc, cl, lphys, log, sm_scale=sm_scale,
+                ql, qr, lat, sc, lyr, cl, lphys, log, sm_scale=sm_scale,
                 opt_kv=opt_kv, window=window, sink_pages=sink_pages,
                 return_state=True, interpret=interpret)
         return _lse_merge(ctx, o, m, l, jnp.float32)
 
     return jax.shard_map(
         body, mesh=ctx.mesh,
-        in_specs=(P(), P(), _pages_spec(3, 0, ctx), _pages_spec(3, 0, ctx),
-                  P(), P(), P()),
+        in_specs=(P(), P(), _pages_spec(4, 1, ctx), _pages_spec(4, 1, ctx),
+                  P(), P(), P(), P()),
         out_specs=P(), check_vma=False,
-    )(q_lat, q_rope, lat_pages, scale_pages, cache_len.astype(jnp.int32),
-      phys_table.astype(jnp.int32), log_table.astype(jnp.int32))
+    )(q_lat, q_rope, lat_pages, scale_pages, jnp.asarray(layer, jnp.int32),
+      cache_len.astype(jnp.int32), phys_table.astype(jnp.int32),
+      log_table.astype(jnp.int32))
 
 
 @partial(jax.jit, static_argnames=("ctx", "sm_scale", "opt_kv", "window",
                                    "sink_pages", "interpret"))
 def latent_chunk_prefill(ctx: ShardCtx, q_lat, q_rope, positions, lat_pages,
-                         scale_pages, phys_table, *, sm_scale: float,
+                         scale_pages, layer, phys_table, *, sm_scale: float,
                          opt_kv: bool, window: int = 0, sink_pages: int = 0,
                          interpret: bool = False, seg_q=None, page_seg=None,
                          page_base=None):
     """Distributed ``latent_chunk_prefill``: chunk of absorbed queries
-    (B, S, H, R) replicated, latent pool pages-sharded; returns o_lat
-    (B, S, H, R) f32. Packing tables (seg/base) are logical-domain and ride
-    along replicated — only the physical table is shard-translated."""
+    (B, S, H, R) replicated, latent pool (L, P_total, ps, R+dr)
+    pages-sharded; returns o_lat (B, S, H, R) f32. Packing tables
+    (seg/base) are logical-domain and ride along replicated — only the
+    physical table is shard-translated."""
     B, S = positions.shape
     NP = phys_table.shape[1]
-    P_total, ps, _ = lat_pages.shape
+    P_total = lat_pages.shape[1]
     P_local = P_total // ctx.num_shards
-    if scale_pages is None:
-        scale_pages = jnp.zeros((P_total, ps, 2), jnp.float32)
     if seg_q is None:
         seg_q = jnp.zeros((B, S), jnp.int32)
     if page_seg is None:
@@ -263,87 +264,77 @@ def latent_chunk_prefill(ctx: ShardCtx, q_lat, q_rope, positions, lat_pages,
     if page_base is None:
         page_base = jnp.broadcast_to(jnp.arange(NP, dtype=jnp.int32), (B, NP))
 
-    def body(ql, qr, pos, lat, sc, phys, sq, pseg, pbase):
+    def body(ql, qr, pos, lat, sc, lyr, phys, sq, pseg, pbase):
         first = _shard_index(ctx) * P_local
         lphys = global_to_local_pages(phys, first, P_local)
         o, m, l = _lc.latent_chunk_prefill(
-            ql, qr, pos, lat, sc, lphys, sm_scale=sm_scale, opt_kv=opt_kv,
-            window=window, sink_pages=sink_pages, return_state=True,
-            interpret=interpret, seg_q=sq, page_seg=pseg, page_base=pbase)
+            ql, qr, pos, lat, sc, lyr, lphys, sm_scale=sm_scale,
+            opt_kv=opt_kv, window=window, sink_pages=sink_pages,
+            return_state=True, interpret=interpret, seg_q=sq,
+            page_seg=pseg, page_base=pbase)
         return _lse_merge(ctx, o, m, l, jnp.float32)
 
     return jax.shard_map(
         body, mesh=ctx.mesh,
-        in_specs=(P(), P(), P(), _pages_spec(3, 0, ctx),
-                  _pages_spec(3, 0, ctx), P(), P(), P(), P()),
+        in_specs=(P(), P(), P(), _pages_spec(4, 1, ctx),
+                  _pages_spec(4, 1, ctx), P(), P(), P(), P(), P()),
         out_specs=P(), check_vma=False,
     )(q_lat, q_rope, positions.astype(jnp.int32), lat_pages, scale_pages,
-      phys_table.astype(jnp.int32), seg_q.astype(jnp.int32),
-      page_seg.astype(jnp.int32), page_base.astype(jnp.int32))
+      jnp.asarray(layer, jnp.int32), phys_table.astype(jnp.int32),
+      seg_q.astype(jnp.int32), page_seg.astype(jnp.int32),
+      page_base.astype(jnp.int32))
 
 
 # ------------------------------------------------------------ write path --
 @partial(jax.jit, static_argnames=("ctx", "opt_kv", "interpret"))
 def kv_pool_write(ctx: ShardCtx, kv_cache, scale_cache, k_new, v_new,
-                  slot_idx, *, opt_kv: bool, interpret: bool = False):
-    """Shard-local write into the pages-sharded KV pool: every shard runs
-    the single-device write kernel (``kernels.kv_cache_write``) on its own
-    page range, with the slots of other shards turned into SkipSet -1s. No
-    cross-shard traffic, and each line is quantized by the same kernel as
-    on one device. Returns updated (kv_cache, scale_cache)."""
-    _, Pt, _, ps, _ = kv_cache.shape
-    n_local = Pt // ctx.num_shards * ps
-    has_scale = scale_cache is not None
-    if not has_scale:
-        scale_cache = jnp.zeros(kv_cache.shape[:-1], jnp.float32)
+                  line_idx, *, opt_kv: bool, interpret: bool = False):
+    """Shard-local write into the pages-sharded KV pool of every layer: each
+    shard runs the single-device write kernel (``kernels.kv_cache_write``)
+    on its own page range, with lines of other shards' pages turned into
+    SkipSet -1s. No cross-shard traffic, and each line is quantized by the
+    same kernel as on one device. Returns updated (kv_cache, scale_cache)."""
+    P_total, ps = kv_cache.shape[2], kv_cache.shape[4]
+    P_local = P_total // ctx.num_shards
+    sc_in = scale_cache if opt_kv else None
 
-    def body(kv, sc, k, v, slots):
-        # flat slots map like page ids: owned -> local, anything else -> -1
-        local = global_to_local_pages(slots, _shard_index(ctx) * n_local,
-                                      n_local)
+    def body(kv, sc, k, v, lines):
+        local = global_to_local_lines(lines, _shard_index(ctx) * P_local,
+                                      P_local, P_total, ps)
         return _kw.kv_cache_write(k, v, local, kv, sc, opt_kv=opt_kv,
                                   interpret=interpret)
 
     kv, sc = jax.shard_map(
         body, mesh=ctx.mesh,
-        in_specs=(_pages_spec(5, 1, ctx), _pages_spec(4, 1, ctx),
+        in_specs=(_pages_spec(6, 2, ctx), _pages_spec(5, 2, ctx),
                   P(), P(), P()),
-        out_specs=(_pages_spec(5, 1, ctx), _pages_spec(4, 1, ctx)),
+        out_specs=(_pages_spec(6, 2, ctx), _pages_spec(5, 2, ctx)),
         check_vma=False,
-    )(kv_cache, scale_cache, k_new, v_new, slot_idx.astype(jnp.int32))
-    return kv, (sc if has_scale else None)
+    )(kv_cache, sc_in, k_new, v_new, line_idx.astype(jnp.int32))
+    return kv, (sc if opt_kv else scale_cache)
 
 
 @partial(jax.jit, static_argnames=("ctx", "opt_kv", "lora_rank"))
 def latent_pool_write(ctx: ShardCtx, lat_cache, scale_cache, latent,
-                      slot_idx, *, opt_kv: bool, lora_rank: int):
-    """Shard-local write into the pages-sharded MLA latent pool (dual-scale
-    quantization replicated, scatter shard-local). lat_cache (P, ps, R+dr);
-    latent (B, S, R+dr). Returns updated (lat_cache, scale_cache)."""
-    Pt, ps, W = lat_cache.shape
+                      line_idx, *, opt_kv: bool, lora_rank: int):
+    """Shard-local write into the pages-sharded MLA latent pool of every
+    layer (lat_cache (L, P, ps, R+dr); latent (B, S, R+dr); line_idx (B, S)
+    lines of the whole pool): each shard quantizes and scatters the lines it
+    owns. Returns updated (lat_cache, scale_cache)."""
+    _, Pt, ps, _ = lat_cache.shape
     P_local = Pt // ctx.num_shards
-    if opt_kv:
-        vals, scl = quantize_latent(latent, lora_rank)
-    else:
-        vals, scl = latent, jnp.zeros(latent.shape[:-1] + (2,), jnp.float32)
-    has_scale = scale_cache is not None
-    if not has_scale:
-        scale_cache = jnp.zeros((Pt, ps, 2), jnp.float32)
+    sc_in = scale_cache if opt_kv else None
 
-    def body(lat, sc, vals, scl, slots):
-        first = _shard_index(ctx) * (P_local * ps)
-        ls = global_to_local_slots(slots, first, P_local * ps)
-        flat = lat.reshape(P_local * ps, W)
-        flat = flat.at[ls].set(vals.astype(flat.dtype), mode="drop")
-        sflat = sc.reshape(P_local * ps, 2)
-        sflat = sflat.at[ls].set(scl, mode="drop")
-        return flat.reshape(P_local, ps, W), sflat.reshape(P_local, ps, 2)
+    def body(lat, sc, latent, lines):
+        local = global_to_local_lines(lines, _shard_index(ctx) * P_local,
+                                      P_local, Pt, ps)
+        return scatter_latent(lat, sc, latent, local, opt_kv=opt_kv,
+                              lora_rank=lora_rank)
 
     lat, sc = jax.shard_map(
         body, mesh=ctx.mesh,
-        in_specs=(_pages_spec(3, 0, ctx), _pages_spec(3, 0, ctx), P(), P(),
-                  P()),
-        out_specs=(_pages_spec(3, 0, ctx), _pages_spec(3, 0, ctx)),
+        in_specs=(_pages_spec(4, 1, ctx), _pages_spec(4, 1, ctx), P(), P()),
+        out_specs=(_pages_spec(4, 1, ctx), _pages_spec(4, 1, ctx)),
         check_vma=False,
-    )(lat_cache, scale_cache, vals, scl, slot_idx.astype(jnp.int32))
-    return lat, (sc if has_scale else None)
+    )(lat_cache, sc_in, latent, line_idx.astype(jnp.int32))
+    return lat, (sc if opt_kv else scale_cache)

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.coopt import CoOptConfig, COOPT
 from repro.core.opt_kv import (identity_page_table, identity_slots,
-                               kv_pool_shapes, write_kv)
+                               kv_pool_shapes, pool_lines, write_kv)
 from repro.core.opt_pa import paged_chunk_attention, paged_decode_attention
 from repro.models.layers import (Spec, apply_rope, causal_attention, init_tree,
                                  linear, repeat_kv, rmsnorm, shard_act)
@@ -187,7 +187,9 @@ class GriffinModel:
                      valid=None, last_pos=None):
         """Scan over (rec, rec, attn) periods + trailing rec layers.
 
-        attn_fn(pl, x, kv_c, sc_c) -> (attn_out, kv_c, sc_c)."""
+        The attention layers' K/V pool (one layer per period) rides whole
+        in the carry. attn_fn(pl, x, kv, sc, layer) -> (attn_out, kv, sc),
+        with ``layer`` the period's traced index into the pool."""
         cfg = self.cfg
         NP, NT = self.n_periods, self.n_trail
         rec_p = params["rec"]
@@ -209,12 +211,8 @@ class GriffinModel:
             return shard_act(hh, ("batch", "seq", None)), c1, h1
 
         def period(carry, xs):
-            hh = carry
-            if coopt.opt_kv:
-                rp, c0, h0, ap, kv_c, sc_c = xs
-            else:
-                rp, c0, h0, ap, kv_c = xs
-                sc_c = None
+            hh, kv, sc = carry
+            rp, c0, h0, ap, layer = xs
             c_out, h_out = [], []
             for j in range(2):
                 rj = jax.tree.map(lambda a: a[j], rp)
@@ -222,22 +220,18 @@ class GriffinModel:
                 c_out.append(c1)
                 h_out.append(h1)
             x = rmsnorm(hh, ap["ln"], cfg.norm_eps)
-            a, kv_c, sc_c = attn_fn(ap, x, kv_c, sc_c)
+            a, kv, sc = attn_fn(ap, x, kv, sc, layer)
             hh = hh + a
             hh = hh + self._mlp(ap, rmsnorm(hh, ap["ln_f"], cfg.norm_eps))
             hh = shard_act(hh, ("batch", "seq", None))
-            ys = (jnp.stack(c_out), jnp.stack(h_out), kv_c) + \
-                ((sc_c,) if coopt.opt_kv else ())
-            return hh, ys
+            return (hh, kv, sc), (jnp.stack(c_out), jnp.stack(h_out))
 
-        xs = (rec_main, cs_main, hs_main, params["attn"], kv) + \
-            ((sc,) if coopt.opt_kv else ())
+        xs = (rec_main, cs_main, hs_main, params["attn"],
+              jnp.arange(NP, dtype=jnp.int32))
         period_fn = jax.checkpoint(period) if h.shape[1] > 1 else period
-        h, ys = jax.lax.scan(period_fn, h, xs)
+        (h, new_kv, new_sc), ys = jax.lax.scan(period_fn, (h, kv, sc), xs)
         new_conv = ys[0].reshape(NP * 2, *cs.shape[1:])
         new_lru = ys[1].reshape(NP * 2, *hs.shape[1:])
-        new_kv = ys[2]
-        new_sc = ys[3] if coopt.opt_kv else None
 
         # trailing rec layers (static count <= 2)
         trail_c, trail_h = [], []
@@ -266,7 +260,7 @@ class GriffinModel:
         cache = self.init_cache(B, S, coopt)
         slots = positions.astype(jnp.int32)
 
-        def attn_fn(ap, x, kv_c, sc_c):
+        def attn_fn(ap, x, kv_c, sc_c, layer):
             # training: in-flight attention only, no cache writes
             a, _, _ = self._attn_full(ap, x, positions, coopt)
             return a, kv_c, sc_c
@@ -306,21 +300,23 @@ class GriffinModel:
         valid = batch.get("pad_mask")
         last_pos = batch.get("last_pos")
         H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        ps = cache["kv"].shape[4]
 
-        def attn_fn(ap, x, kv_c, sc_c):
+        def attn_fn(ap, x, kv_c, sc_c, layer):
+            lines = pool_lines(slots, layer, P_total, ps)
             if chunked:
                 q = linear(x, ap["wq"]).reshape(B, S, H, D)
                 k = linear(x, ap["wk"]).reshape(B, S, Hkv, D)
                 v = linear(x, ap["wv"]).reshape(B, S, Hkv, D)
                 q = apply_rope(q, positions, cfg.rope_theta)
                 k = apply_rope(k, positions, cfg.rope_theta)
-                kv_c, sc_c = write_kv(kv_c, sc_c, k, v, slots, coopt)
+                kv_c, sc_c = write_kv(kv_c, sc_c, k, v, lines, coopt)
                 o = paged_chunk_attention(
-                    q, kv_c, sc_c, positions, page_table, coopt,
+                    q, kv_c, sc_c, layer, positions, page_table, coopt,
                     window=cfg.local_window, sink_pages=cfg.sink_blocks)
                 return linear(o.reshape(B, S, H * D), ap["wo"]), kv_c, sc_c
             a, k, v = self._attn_full(ap, x, positions, coopt)
-            kv_c, sc_c = write_kv(kv_c, sc_c, k, v, slots, coopt)
+            kv_c, sc_c = write_kv(kv_c, sc_c, k, v, lines, coopt)
             return a, kv_c, sc_c
 
         h, cache = self._period_scan(params, cache, h, positions, slots,
@@ -361,16 +357,18 @@ class GriffinModel:
             new_len = cache["length"] + 1
         new_len = new_len.astype(jnp.int32)
         H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        ps = cache["kv"].shape[4]
 
-        def attn_fn(ap, x, kv_c, sc_c):
+        def attn_fn(ap, x, kv_c, sc_c, layer):
             q = linear(x, ap["wq"]).reshape(B, 1, H, D)
             k = linear(x, ap["wk"]).reshape(B, 1, Hkv, D)
             v = linear(x, ap["wv"]).reshape(B, 1, Hkv, D)
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
-            kv_c, sc_c = write_kv(kv_c, sc_c, k, v, slots, coopt)
+            kv_c, sc_c = write_kv(kv_c, sc_c, k, v,
+                                  pool_lines(slots, layer, P_total, ps), coopt)
             o = paged_decode_attention(
-                q[:, 0], kv_c, sc_c, new_len, coopt=coopt,
+                q[:, 0], kv_c, sc_c, layer, new_len, coopt=coopt,
                 window=cfg.local_window, sink_pages=cfg.sink_blocks,
                 page_table=page_table)
             return linear(o.reshape(B, 1, H * D), ap["wo"]), kv_c, sc_c

@@ -88,15 +88,17 @@ def _expand_o(o_lat, p, cfg, dtype):
                       ).astype(dtype)
 
 
-def mla_chunk_attention(q_nope, q_rope, lat_pages, scale_pages, positions,
-                        page_table, p, cfg, coopt: CoOptConfig, *,
+def mla_chunk_attention(q_nope, q_rope, lat_pages, scale_pages, layer,
+                        positions, page_table, p, cfg, coopt: CoOptConfig, *,
                         window: int = 0, sink_pages: int = 1, seg_q=None,
                         page_seg=None, page_base=None):
     """Matrix-absorption CHUNK attention against the global latent pool —
     the MLA leg of the unified chunked-continuation prefill path.
 
     q_nope (B,S,H,dn), q_rope (B,S,H,dr) are this chunk's queries with
-    absolute ``positions`` (B,S); the chunk's latents are already written to
+    absolute ``positions`` (B,S); ``lat_pages`` (L,P_total,ps,R+dr) and
+    ``scale_pages`` are the latent pool of every layer and ``layer`` the one
+    attended. The chunk's latents are already written to
     the paged cache, so queries attend the lane's WHOLE latent history
     (prefix-cache hits + earlier chunks + this one) in absorbed form
     — K/V are never materialised per head, exactly like decode (a decode
@@ -111,7 +113,7 @@ def mla_chunk_attention(q_nope, q_rope, lat_pages, scale_pages, positions,
                         cfg.qk_rope_head_dim, cfg.kv_lora_rank,
                         cfg.v_head_dim)
     B, S = q_nope.shape[:2]
-    P_total, ps, _ = lat_pages.shape
+    P_total, ps = lat_pages.shape[1:3]
     if page_table is None:
         from repro.core.opt_kv import identity_page_table
         page_table = identity_page_table(B, P_total)
@@ -122,7 +124,7 @@ def mla_chunk_attention(q_nope, q_rope, lat_pages, scale_pages, positions,
         from repro.kernels import ops
         o_lat = ops.latent_chunk_prefill(
             q_lat, q_rope.astype(jnp.float32), positions, lat_pages,
-            scale_pages if coopt.opt_kv else None, page_table,
+            scale_pages if coopt.opt_kv else None, layer, page_table,
             sm_scale=scale, opt_kv=coopt.opt_kv, window=window,
             sink_pages=sink_pages, seg_q=seg_q, page_seg=page_seg,
             page_base=page_base)
@@ -133,9 +135,9 @@ def mla_chunk_attention(q_nope, q_rope, lat_pages, scale_pages, positions,
                        ("batch", None, None, "latent"))
 
     pt = jnp.maximum(page_table, 0)
-    lat = jnp.take(lat_pages, pt, axis=0)              # (B,NP,ps,R+dr)
+    lat = jnp.take(lat_pages[layer], pt, axis=0)       # (B,NP,ps,R+dr)
     if coopt.opt_kv:
-        sc = jnp.take(scale_pages, pt, axis=0)
+        sc = jnp.take(scale_pages[layer], pt, axis=0)
         lat = dequantize_latent(lat, sc, R, dtype=jnp.float32)
     else:
         lat = lat.astype(jnp.float32)
@@ -168,13 +170,14 @@ def mla_chunk_attention(q_nope, q_rope, lat_pages, scale_pages, positions,
     return _expand_o(o_lat, p, cfg, q_nope.dtype)
 
 
-def mla_paged_decode(q_nope, q_rope, lat_pages, scale_pages, cache_len, p, cfg,
-                     coopt: CoOptConfig, *, window: int = 0, sink_pages: int = 1,
-                     page_table=None):
+def mla_paged_decode(q_nope, q_rope, lat_pages, scale_pages, layer, cache_len,
+                     p, cfg, coopt: CoOptConfig, *, window: int = 0,
+                     sink_pages: int = 1, page_table=None):
     """Absorbed decode against the GLOBAL latent pool. q_nope/q_rope
-    (B,H,dn|dr); lat_pages (P_total,ps,R+dr) shared by all lanes;
-    page_table (B,P_lane) physical pages in logical order (default:
-    lane-identity partition). Under ``coopt.use_kernel`` this dispatches to
+    (B,H,dn|dr); lat_pages (L,P_total,ps,R+dr) the pool of every layer,
+    shared by all lanes, and ``layer`` the one attended; page_table
+    (B,P_lane) physical pages in logical order (default: lane-identity
+    partition). Under ``coopt.use_kernel`` this dispatches to
     the fused ``paged_latent_decode`` Pallas kernel — each latent page
     streamed into VMEM once and shared by all H absorbed heads, dual-scale
     FP8 dequant fused at the HBM->VMEM boundary; the jnp body below is the
@@ -182,7 +185,7 @@ def mla_paged_decode(q_nope, q_rope, lat_pages, scale_pages, cache_len, p, cfg,
     H, dn, dr, R, dv = (cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                         cfg.kv_lora_rank, cfg.v_head_dim)
     B = q_nope.shape[0]
-    P_total, ps, _ = lat_pages.shape
+    P_total, ps = lat_pages.shape[1:3]
     if page_table is None:
         from repro.core.opt_kv import identity_page_table
         page_table = identity_page_table(B, P_total)
@@ -203,7 +206,8 @@ def mla_paged_decode(q_nope, q_rope, lat_pages, scale_pages, cache_len, p, cfg,
                                            opt_pa=coopt.opt_pa)
         o_lat = ops.paged_latent_decode(
             q_lat, q_rope.astype(jnp.float32), lat_pages,
-            scale_pages if coopt.opt_kv else None, cache_len, phys, logical,
+            scale_pages if coopt.opt_kv else None, layer, cache_len, phys,
+            logical,
             sm_scale=scale, opt_kv=coopt.opt_kv, window=window,
             sink_pages=sink_pages, share_visits=coopt.share_visits)
         return _expand_o(o_lat, p, cfg, q_nope.dtype)
@@ -212,6 +216,8 @@ def mla_paged_decode(q_nope, q_rope, lat_pages, scale_pages, cache_len, p, cfg,
     # cache — its r dim inherits w_uk's d_in->data otherwise, §Perf P2)
     q_lat = shard_act(q_lat, ("batch", None, "latent"))
     q_rope = shard_act(q_rope, ("batch", None, "latent"))
+    lat_pages = lat_pages[layer]
+    scale_pages = scale_pages[layer] if coopt.opt_kv else None
 
     def dequant(pages, scales):
         """pages (..., R+dr); scales (..., 2) — separate c / rope scales."""

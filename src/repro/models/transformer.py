@@ -18,7 +18,8 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.coopt import CoOptConfig, COOPT
 from repro.core.opt_kv import (identity_page_table, identity_slots,
-                               kv_pool_shapes, pool_layout, write_kv)
+                               kv_pool_shapes, pool_layout, pool_lines,
+                               write_kv)
 from repro.core.opt_pa import paged_chunk_attention, paged_decode_attention
 from repro.models import mla as mla_mod
 from repro.models.layers import (Spec, apply_rope, causal_attention, init_tree,
@@ -165,12 +166,12 @@ class TransformerModel:
                                  repeat_kv(v, H // Hkv), window=cfg.attn_window)
         return linear(o.reshape(B, S, H * D), p["wo"]), k, v
 
-    def _attention_decode(self, p, x, kv_slice, positions, new_len,
+    def _attention_decode(self, p, x, kv_c, sc_c, layer, positions, new_len,
                           page_table, coopt, long_window: int):
-        """One-token attention against this layer's slice of the GLOBAL
-        paged pool. kv_slice: ("kv", "scale") for this layer (already
-        containing the new token); page_table: (B, P_lane) physical pages
-        in logical order. Returns projected output (B,1,d)."""
+        """One-token attention against layer ``layer`` of the GLOBAL paged
+        pool of every layer (kv_c, sc_c; already containing the new token);
+        page_table: (B, P_lane) physical pages in logical order. Returns
+        projected output (B,1,d)."""
         cfg = self.cfg
         B = x.shape[0]
         window = cfg.attn_window or long_window
@@ -182,7 +183,7 @@ class TransformerModel:
             qn, qr = q[..., :dn], q[..., dn:]
             qr = apply_rope(qr, positions, cfg.rope_theta)
             o = mla_mod.mla_paged_decode(
-                qn[:, 0], qr[:, 0], kv_slice["kv"], kv_slice.get("scale"),
+                qn[:, 0], qr[:, 0], kv_c, sc_c, layer,
                 new_len, p, cfg, coopt, window=window,
                 sink_pages=cfg.sink_blocks, page_table=page_table)
             return linear(o.reshape(B, 1, -1), p["wo"])
@@ -192,7 +193,7 @@ class TransformerModel:
             q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         q = apply_rope(q, positions, cfg.rope_theta)
         o = paged_decode_attention(
-            q[:, 0], kv_slice["kv"], kv_slice.get("scale"), new_len,
+            q[:, 0], kv_c, sc_c, layer, new_len,
             coopt=coopt, window=window, sink_pages=cfg.sink_blocks,
             page_table=page_table)
         return linear(o.reshape(B, 1, H * D), p["wo"])
@@ -316,62 +317,59 @@ class TransformerModel:
                                  num_shards=num_shards,
                                  cache_cfg=cache_cfg).items()}
 
-    def _write_layer(self, kv_c, sc_c, new_a, new_b, slots, coopt):
-        """Write cache entries for one layer (GLOBAL flat slots; -1 =
-        SkipSet drop). MLA: new_a=(B,S,R+dr), kv_c=(P,ps,R+dr)."""
+    def _write_layer(self, kv_c, sc_c, layer, new_a, new_b, slots, coopt):
+        """Write layer ``layer``'s cache entries (GLOBAL flat slots; -1 =
+        SkipSet drop) into the pool of every layer, addressed as lines of
+        the whole pool (``opt_kv.pool_lines``). MLA: new_a=(B,S,R+dr),
+        kv_c=(L,P,ps,R+dr)."""
         if self.cfg.family == "mla":
             # ops dispatch: shard-local scatter under a mesh ctx, the
             # identical jnp scatter otherwise (ONE write implementation)
             from repro.kernels import ops
+            _, P, ps, _ = kv_c.shape
             return ops.latent_pool_write(
-                kv_c, sc_c, new_a, slots, opt_kv=coopt.opt_kv,
-                lora_rank=self.cfg.kv_lora_rank)
-        return write_kv(kv_c, sc_c, new_a, new_b, slots, coopt)
+                kv_c, sc_c, new_a, pool_lines(slots, layer, P, ps),
+                opt_kv=coopt.opt_kv, lora_rank=self.cfg.kv_lora_rank)
+        P, ps = kv_c.shape[2], kv_c.shape[4]
+        return write_kv(kv_c, sc_c, new_a, new_b,
+                        pool_lines(slots, layer, P, ps), coopt)
 
     def _scan_with_cache(self, params, cache, h, new_len, coopt, step_fn):
-        """Scan layers threading per-layer cache slices as xs/ys.
-        ``new_len`` (B,) is the per-lane token count after this step —
-        supplied by the engine (global slots carry no length info)."""
-        cfg = self.cfg
+        """Scan the layers with the WHOLE pool in the carry: ``cache["kv"]``
+        (L,2,P,Hkv,ps,D) and ``cache["scale"]`` (L,2,P,Hkv,ps) (MLA:
+        (L,P,ps,R+dr) / (L,P,ps,2)) are never sliced per layer nor restacked.
+        ``step_fn(h, layer_params, kv, sc, layer, kind)`` gets the full pool
+        and the traced global layer index (``start + i`` across segments),
+        and returns ``(h, kv, sc)``: the kernels address the layer in place
+        and the write updates the carried pool in place. ``new_len`` (B,) is
+        the per-lane token count after this step — supplied by the engine
+        (global slots carry no length info)."""
         start = 0
-        kv_out, sc_out = [], []
+        kv, sc = cache["kv"], (cache["scale"] if coopt.opt_kv else None)
         for seg_params, (count, kind) in zip(params["segments"],
                                              self._segments()):
-            kv_seg = cache["kv"][start:start + count]
-            sc_seg = (cache["scale"][start:start + count]
-                      if coopt.opt_kv else None)
-            xs = (seg_params, kv_seg, sc_seg) if coopt.opt_kv else \
-                 (seg_params, kv_seg)
-
             def body(carry, xs, kind=kind):
-                hh = carry
-                if coopt.opt_kv:
-                    pl, kv_c, sc_c = xs
-                else:
-                    pl, kv_c = xs
-                    sc_c = None
-                hh, kv_c, sc_c = step_fn(hh, pl, kv_c, sc_c, kind)
-                ys = (kv_c, sc_c) if coopt.opt_kv else (kv_c,)
-                return hh, ys
+                hh, kv, sc = carry
+                pl, layer = xs
+                return step_fn(hh, pl, kv, sc, layer, kind), None
 
-            h, ys = jax.lax.scan(body, h, xs)
-            kv_out.append(ys[0])
-            if coopt.opt_kv:
-                sc_out.append(ys[1])
+            layers = start + jnp.arange(count, dtype=jnp.int32)
+            (h, kv, sc), _ = jax.lax.scan(body, (h, kv, sc),
+                                          (seg_params, layers))
             start += count
         cache = dict(cache)
-        cache["kv"] = jnp.concatenate(kv_out, 0) if len(kv_out) > 1 else kv_out[0]
+        cache["kv"] = kv
         if coopt.opt_kv:
-            cache["scale"] = (jnp.concatenate(sc_out, 0)
-                              if len(sc_out) > 1 else sc_out[0])
+            cache["scale"] = sc
         cache["length"] = new_len
         return h, cache
 
-    def _attention_chunk(self, p, x, positions, kv_c, sc_c, page_table,
-                         coopt, long_window: int = 0, seg_q=None,
+    def _attention_chunk(self, p, x, positions, kv_c, sc_c, layer,
+                         page_table, coopt, long_window: int = 0, seg_q=None,
                          page_seg=None, page_base=None):
         """Prefill-continuation attention (chunked prefill / mixed step):
-        the chunk's K/V are already written to the GLOBAL paged cache;
+        the chunk's K/V are already written to layer ``layer`` of the
+        GLOBAL paged cache of every layer (kv_c, sc_c);
         queries attend over the lane's WHOLE cache (prefix-cache hits +
         previous chunks + this one) through its page table with true
         positions — see ``core.opt_pa.paged_chunk_attention``. Supports
@@ -390,17 +388,17 @@ class TransformerModel:
             qn, qr = q[..., :dn], q[..., dn:]
             qr = apply_rope(qr, positions, cfg.rope_theta)
             o = mla_mod.mla_chunk_attention(
-                qn, qr, kv_c, sc_c, positions, page_table, p, cfg, coopt,
-                window=window, sink_pages=cfg.sink_blocks, seg_q=seg_q,
-                page_seg=page_seg, page_base=page_base)
+                qn, qr, kv_c, sc_c, layer, positions, page_table, p, cfg,
+                coopt, window=window, sink_pages=cfg.sink_blocks,
+                seg_q=seg_q, page_seg=page_seg, page_base=page_base)
             return linear(o.reshape(B, S, -1), p["wo"])
         H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         q = linear(x, p["wq"], p.get("bq")).reshape(B, S, H, D)
         if cfg.qk_norm:
             q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
         q = apply_rope(q, positions, cfg.rope_theta)
-        o = paged_chunk_attention(q, kv_c, sc_c, positions, page_table,
-                                  coopt, window=window,
+        o = paged_chunk_attention(q, kv_c, sc_c, layer, positions,
+                                  page_table, coopt, window=window,
                                   sink_pages=cfg.sink_blocks, seg_q=seg_q,
                                   page_seg=page_seg, page_base=page_base)
         return linear(o.reshape(B, S, H * D).astype(x.dtype), p["wo"])
@@ -462,21 +460,22 @@ class TransformerModel:
         page_seg = batch.get("page_seg")
         page_base = batch.get("page_base")
 
-        def step(hh, pl, kv_c, sc_c, kind):
+        def step(hh, pl, kv_c, sc_c, layer, kind):
             x = rmsnorm(hh, pl["ln1"], cfg.norm_eps)
             if chunked:
                 new_a, new_b = self._new_kv(pl, x, positions)
-                kv_c, sc_c = self._write_layer(kv_c, sc_c, new_a, new_b,
-                                               slots, coopt)
+                kv_c, sc_c = self._write_layer(kv_c, sc_c, layer, new_a,
+                                               new_b, slots, coopt)
                 a = self._attention_chunk(pl, x, positions, kv_c, sc_c,
-                                          page_table, coopt, long_window,
-                                          seg_q=seg_q, page_seg=page_seg,
+                                          layer, page_table, coopt,
+                                          long_window, seg_q=seg_q,
+                                          page_seg=page_seg,
                                           page_base=page_base)
             else:
                 a, new_a, new_b = self._attention_full(pl, x, positions,
                                                        coopt)
-                kv_c, sc_c = self._write_layer(kv_c, sc_c, new_a, new_b,
-                                               slots, coopt)
+                kv_c, sc_c = self._write_layer(kv_c, sc_c, layer, new_a,
+                                               new_b, slots, coopt)
             hh = hh + a
             f, _ = self._ffn(pl, rmsnorm(hh, pl["ln2"], cfg.norm_eps), kind,
                              coopt)
@@ -517,14 +516,14 @@ class TransformerModel:
             new_len = cache["length"] + 1
         new_len = new_len.astype(jnp.int32)
 
-        def step(hh, pl, kv_c, sc_c, kind):
+        def step(hh, pl, kv_c, sc_c, layer, kind):
             x = rmsnorm(hh, pl["ln1"], cfg.norm_eps)
             new_a, new_b = self._new_kv(pl, x, positions)
-            kv_c, sc_c = self._write_layer(kv_c, sc_c, new_a, new_b, slots,
-                                           coopt)
-            a = self._attention_decode(pl, x, {"kv": kv_c, "scale": sc_c},
-                                       positions, new_len, page_table,
-                                       coopt, long_window)
+            kv_c, sc_c = self._write_layer(kv_c, sc_c, layer, new_a, new_b,
+                                           slots, coopt)
+            a = self._attention_decode(pl, x, kv_c, sc_c, layer, positions,
+                                       new_len, page_table, coopt,
+                                       long_window)
             hh = hh + a
             f, _ = self._ffn(pl, rmsnorm(hh, pl["ln2"], cfg.norm_eps), kind,
                              coopt)

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core.coopt import CoOptConfig, COOPT
 from repro.core.opt_kv import (identity_page_table, identity_slots,
-                               kv_pool_shapes, write_kv)
+                               kv_pool_shapes, pool_lines, write_kv)
 from repro.core.opt_pa import paged_chunk_attention, paged_decode_attention
 from repro.cache.quant import quantize_fp8, dequantize_fp8
 from repro.models.layers import (Spec, causal_attention, gelu_mlp, init_tree,
@@ -174,34 +174,36 @@ class WhisperModel:
         new_len = (cache["length"] + S if cache_len is None
                    else cache_len).astype(jnp.int32)
 
-        xs = (params["dec"], cache["kv"], cache["xk"], cache["xv"])
-        if coopt.opt_kv:
-            xs = xs + (cache["scale"], cache["xscale"])
+        # the self-attention pool rides whole in the carry; each layer
+        # writes and reads its own layer of it in place
+        kv, sc = cache["kv"], (cache["scale"] if coopt.opt_kv else None)
+        P, ps = kv.shape[2], kv.shape[4]
+        xs = (params["dec"], cache["xk"], cache["xv"],
+              cache["xscale"] if coopt.opt_kv else None,
+              jnp.arange(kv.shape[0], dtype=jnp.int32))
 
-        def body(hh, xs):
-            if coopt.opt_kv:
-                pl, kv_c, xk, xv, sc_c, xsc = xs
-            else:
-                pl, kv_c, xk, xv = xs
-                sc_c, xsc = None, None
+        def body(carry, xs):
+            hh, kv, sc = carry
+            pl, xk, xv, xsc, layer = xs
             x = layernorm(hh, pl["ln1"], pl["ln1_b"], cfg.norm_eps)
             q = linear(x, pl["wq"], pl["bq"]).reshape(B, S, H, D)
             k = linear(x, pl["wk"]).reshape(B, S, H, D)
             v = linear(x, pl["wv"], pl["bv"]).reshape(B, S, H, D)
-            kv_c, sc_c = write_kv(kv_c, sc_c, k, v, slots, coopt)
+            kv, sc = write_kv(kv, sc, k, v, pool_lines(slots, layer, P, ps),
+                              coopt)
             if chunk_attn:
                 # continuation chunk: attend the lane's whole cached history
                 # (prefix hits + earlier chunks + this one) with true
                 # positions — the unified ragged step path; the long_window
                 # policy mirrors the decode branch so a token's logits are
                 # step-composition independent
-                o = paged_chunk_attention(q, kv_c, sc_c, positions,
+                o = paged_chunk_attention(q, kv, sc, layer, positions,
                                           page_table, coopt,
                                           window=long_window,
                                           sink_pages=cfg.sink_blocks)
             elif S == 1:
                 o = paged_decode_attention(
-                    q[:, 0], kv_c, sc_c, new_len, coopt=coopt,
+                    q[:, 0], kv, sc, layer, new_len, coopt=coopt,
                     window=long_window, sink_pages=cfg.sink_blocks,
                     page_table=page_table)[:, None]
             else:
@@ -211,15 +213,14 @@ class WhisperModel:
             hh = hh + self._cross_attn(pl, x, xk, xv, xsc, coopt)
             x = layernorm(hh, pl["ln2"], pl["ln2_b"], cfg.norm_eps)
             hh = hh + gelu_mlp(x, pl["w1"], pl["b1"], pl["w2"], pl["b2"])
-            ys = (kv_c, sc_c) if coopt.opt_kv else (kv_c,)
-            return shard_act(hh, ("batch", "seq", None)), ys
+            return (shard_act(hh, ("batch", "seq", None)), kv, sc), None
 
         body_fn = jax.checkpoint(body) if S > 1 else body
-        h, ys = jax.lax.scan(body_fn, h, xs)
+        (h, kv, sc), _ = jax.lax.scan(body_fn, (h, kv, sc), xs)
         cache = dict(cache)
-        cache["kv"] = ys[0]
+        cache["kv"] = kv
         if coopt.opt_kv:
-            cache["scale"] = ys[1]
+            cache["scale"] = sc
         cache["length"] = new_len
         h = layernorm(h, params["final_norm"], params["final_norm_b"],
                       cfg.norm_eps)

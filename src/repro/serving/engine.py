@@ -8,8 +8,8 @@ loaded, per-head KV expansion) and each Opt-* flag turns on one technique,
 so Figs. 6-7's five modes are one constructor argument apart.
 
 Design (hardware adaptation, DESIGN.md §3): the device cache is a GLOBAL
-paged pool — per-layer leaves ``(2, P_total, Hkv, ps, D)`` with no batch
-dimension, ``P_total = num_lanes * pages(max_len)`` padded to tile evenly
+paged pool — leaves ``(L, 2, P_total, Hkv, ps, D)`` of every layer with no
+batch dimension, ``P_total = num_lanes * pages(max_len)`` padded to tile evenly
 over ``num_shards`` KV shards (the final page reserved). The pool's page
 range is partitioned along the mesh ``(pod, data)`` axes — the axes
 CACHE_RULES shard the pages axis over — and every request is pinned to

@@ -292,6 +292,57 @@ def test_pallas_vmem_budget(tmp_path):
     assert report[0]["est_vmem_bytes"] > (1 << 20)
 
 
+_LAYER_KERNEL = """
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(q, pool, phys, layer, *, interpret):
+        def page_idx(b, s, phys, lyr):
+            return (lyr[0], {DEREF}, 0, 0)
+        return pl.pallas_call(
+            _kern,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(4, 8),
+                in_specs=[
+                    pl.BlockSpec((1, 1, 64, 128),
+                                 lambda b, s, phys, lyr: (b, s, 0, 0)),
+                    pl.BlockSpec((None, 1, {BQ}, 128), page_idx),
+                ],
+                out_specs=[pl.BlockSpec((1, 1, 64, 128),
+                                        lambda b, s, phys, lyr: (b, s, 0, 0))],
+            ),
+            interpret=interpret,
+        )(phys, layer, q, pool)
+"""
+
+
+def _layer_kernel_src(deref="jnp.maximum(phys[b, s], 0)", bq=64):
+    return _LAYER_KERNEL.replace("{DEREF}", deref).replace("{BQ}", str(bq))
+
+
+def test_pallas_layer_scalar_and_squeezed_dims_ok(tmp_path):
+    """A second scalar-prefetch ref read at a literal index (the pool's
+    layer) is a scalar argument, not a page table; a ``None`` (squeezed)
+    block dim holds one element, not an unresolved 128."""
+    live, _s, _b, report = _lint(tmp_path, "kernels/k.py",
+                                 _layer_kernel_src())
+    assert live == []
+    (entry,) = report
+    assert entry["unresolved_dims"] == []
+    assert entry["block_bytes"] == 3 * 64 * 128 * 4
+
+
+def test_pallas_layer_kernel_table_still_checked(tmp_path):
+    # the page table beside the layer scalar keeps its sentinel rule
+    live, *_ = _lint(tmp_path, "kernels/k.py",
+                     _layer_kernel_src(deref="phys[b, s]"))
+    assert _codes(live) == ["COOPT005"]
+    assert "'phys'" in live[0].message
+
+
 def test_vmem_report_covers_repo_kernels():
     """The four pooled serving kernels must appear in the repo's VMEM
     report and sit under the default budget."""

@@ -1,4 +1,5 @@
-"""Opt-GQA (Eq. 7/8) and Opt-Pa (Eq. 9/10) numerics over the GLOBAL pool."""
+"""Opt-GQA (Eq. 7/8) and Opt-Pa (Eq. 9/10) numerics over the GLOBAL pool
+(here a pool of one layer, attended at layer 0)."""
 import math
 
 import jax
@@ -51,7 +52,8 @@ def test_grouped_equals_expanded_attention():
 
 # ------------------------------------------------------------- Opt-Pa ------
 def _paged(B=2, P=8, ps=16, Hq=8, Hkv=2, D=32, opt_kv=False, seed=0):
-    """Global pool holding B lanes x P pages each (lane-identity layout)."""
+    """Global pool of one layer holding B lanes x P pages each
+    (lane-identity layout)."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     PT = B * P
     q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
@@ -60,8 +62,8 @@ def _paged(B=2, P=8, ps=16, Hq=8, Hkv=2, D=32, opt_kv=False, seed=0):
     if opt_kv:
         kq, ksc = quantize_fp8(k)
         vq, vsc = quantize_fp8(v)
-        return q, jnp.stack([kq, vq]), jnp.stack([ksc, vsc])
-    return q, jnp.stack([k, v]).astype(jnp.bfloat16), None
+        return q, jnp.stack([kq, vq])[None], jnp.stack([ksc, vsc])[None]
+    return q, jnp.stack([k, v])[None].astype(jnp.bfloat16), None
 
 
 @settings(max_examples=10, deadline=None)
@@ -70,9 +72,9 @@ def test_blockwise_softmax_equals_flat(cache_len, seed):
     """Eq. 10 online block-wise softmax == flat softmax, any context len."""
     q, kv, sc = _paged(seed=seed)
     cl = jnp.array([cache_len, max(cache_len // 2, 1)], jnp.int32)
-    flat = paged_decode_attention(q, kv, sc, cl,
+    flat = paged_decode_attention(q, kv, sc, 0, cl,
                                   coopt=CoOptConfig(opt_pa=False))
-    blk = paged_decode_attention(q, kv, sc, cl,
+    blk = paged_decode_attention(q, kv, sc, 0, cl,
                                  coopt=CoOptConfig(opt_pa=True, page_group=2))
     np.testing.assert_allclose(np.asarray(flat, np.float32),
                                np.asarray(blk, np.float32), atol=2e-2)
@@ -86,7 +88,7 @@ def test_all_modes_agree_bf16():
     outs = {}
     for name in ("original", "opt-gqa", "opt-pa"):
         outs[name] = np.asarray(paged_decode_attention(
-            q, kv, sc, cl, coopt=MODES[name]), np.float32)
+            q, kv, sc, 0, cl, coopt=MODES[name]), np.float32)
     np.testing.assert_allclose(outs["original"], outs["opt-gqa"], atol=2e-2)
     np.testing.assert_allclose(outs["original"], outs["opt-pa"], atol=2e-2)
 
@@ -108,10 +110,10 @@ def test_blockwise_nondividing_page_group_matches_flat():
     must equal the flat softmax (the pad pages are fully masked)."""
     q, kv, sc = _paged()
     cl = jnp.array([100, 37], jnp.int32)
-    flat = paged_decode_attention(q, kv, sc, cl,
+    flat = paged_decode_attention(q, kv, sc, 0, cl,
                                   coopt=CoOptConfig(opt_pa=False))
     blk = paged_decode_attention(
-        q, kv, sc, cl, coopt=CoOptConfig(opt_pa=True, page_group=3))
+        q, kv, sc, 0, cl, coopt=CoOptConfig(opt_pa=True, page_group=3))
     np.testing.assert_allclose(np.asarray(flat, np.float32),
                                np.asarray(blk, np.float32), atol=2e-2)
 
@@ -120,9 +122,9 @@ def test_explicit_page_table_matches_identity_default():
     """Passing the lane-identity table explicitly == the default."""
     q, kv, sc = _paged()
     cl = jnp.array([100, 37], jnp.int32)
-    pt = identity_page_table(2, kv.shape[1])
-    a = paged_decode_attention(q, kv, sc, cl, coopt=MODES["opt-pa"])
-    b = paged_decode_attention(q, kv, sc, cl, coopt=MODES["opt-pa"],
+    pt = identity_page_table(2, kv.shape[2])
+    a = paged_decode_attention(q, kv, sc, 0, cl, coopt=MODES["opt-pa"])
+    b = paged_decode_attention(q, kv, sc, 0, cl, coopt=MODES["opt-pa"],
                                page_table=pt)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -141,11 +143,13 @@ def test_permuted_page_table_matches_contiguous():
     scat_v = jnp.zeros((8, Hkv, ps, D)).at[jnp.array(perm)].set(pages_v)
     cl = jnp.array([P * ps], jnp.int32)
     a = paged_decode_attention(
-        q, jnp.stack([pages_k, pages_v]).astype(jnp.bfloat16), None, cl,
+        q, jnp.stack([pages_k, pages_v])[None].astype(jnp.bfloat16), None, 0,
+        cl,
         coopt=MODES["opt-pa"],
         page_table=jnp.arange(P, dtype=jnp.int32)[None])
     b = paged_decode_attention(
-        q, jnp.stack([scat_k, scat_v]).astype(jnp.bfloat16), None, cl,
+        q, jnp.stack([scat_k, scat_v])[None].astype(jnp.bfloat16), None, 0,
+        cl,
         coopt=MODES["opt-pa"],
         page_table=jnp.array(perm, jnp.int32)[None])
     np.testing.assert_allclose(np.asarray(a, np.float32),
@@ -156,8 +160,8 @@ def test_fp8_mode_close_to_bf16():
     q, kvq, scq = _paged(opt_kv=True)
     _, kvb, _ = _paged(opt_kv=False)
     cl = jnp.array([128, 64], jnp.int32)
-    a = paged_decode_attention(q, kvb, None, cl, coopt=MODES["original"])
-    b = paged_decode_attention(q, kvq, scq, cl, coopt=MODES["coopt"])
+    a = paged_decode_attention(q, kvb, None, 0, cl, coopt=MODES["original"])
+    b = paged_decode_attention(q, kvq, scq, 0, cl, coopt=MODES["coopt"])
     # fp8 K/V perturbs attention outputs by O(2^-3) of value scale
     err = np.abs(np.asarray(a, np.float32) - np.asarray(b, np.float32))
     assert err.max() < 0.25, err.max()
@@ -167,8 +171,8 @@ def test_window_policy_matches_dense_when_window_covers_all():
     """Window >= context => block-sparse result == dense result."""
     q, kv, sc = _paged(P=4)
     cl = jnp.array([64, 40], jnp.int32)
-    dense = paged_decode_attention(q, kv, sc, cl, coopt=MODES["original"])
-    win = paged_decode_attention(q, kv, sc, cl, coopt=MODES["original"],
+    dense = paged_decode_attention(q, kv, sc, 0, cl, coopt=MODES["original"])
+    win = paged_decode_attention(q, kv, sc, 0, cl, coopt=MODES["original"],
                                  window=4 * 16, sink_pages=1)
     np.testing.assert_allclose(np.asarray(dense, np.float32),
                                np.asarray(win, np.float32), atol=2e-2)
@@ -182,9 +186,9 @@ def test_window_policy_drops_middle_tokens():
     # middle token with huge key would dominate IF not skipped
     k = k.at[3, :, 0].set(100.0)
     v = jnp.ones_like(k)
-    kv = jnp.stack([k, v]).astype(jnp.bfloat16)
+    kv = jnp.stack([k, v])[None].astype(jnp.bfloat16)
     cl = jnp.array([128], jnp.int32)
-    out = paged_decode_attention(q, kv, None, cl, coopt=MODES["original"],
+    out = paged_decode_attention(q, kv, None, 0, cl, coopt=MODES["original"],
                                  window=32, sink_pages=1)
     # all values are 1 where attended; the spike token is outside the window
     np.testing.assert_allclose(np.asarray(out, np.float32), 1.0, atol=1e-2)

@@ -1,5 +1,6 @@
 """Opt-KV write/read path semantics over the GLOBAL pool (paper §3.1,
-Eq. 5/6)."""
+Eq. 5/6): a pool of one layer, written at lines of layer 0 (its flat
+slots) and gathered from ``pool[0]``."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,11 +9,11 @@ import pytest
 from repro.core.coopt import CoOptConfig, COOPT, ORIGINAL, OPT_KV
 from repro.core.opt_kv import (gather_cached_kv, identity_page_table,
                                identity_slots, logical_to_physical,
-                               make_layer_cache, window_page_table, write_kv)
+                               make_pool, window_page_table, write_kv)
 
 
 def _mk(P=8, ps=8, H=2, D=16, B=2, S=5, coopt=OPT_KV):
-    kv, sc = make_layer_cache(P, ps, H, D, coopt)
+    kv, sc = make_pool(1, P, ps, H, D, coopt)
     k = jax.random.normal(jax.random.PRNGKey(0), (B, S, H, D), jnp.float32)
     v = jax.random.normal(jax.random.PRNGKey(1), (B, S, H, D), jnp.float32)
     return kv, sc, k, v
@@ -24,8 +25,8 @@ def test_skipset_negative_slots_never_written():
     # lanes write DISJOINT global slots (refcounted pool invariant)
     slots = jnp.array([[0, -1, 2, -1, 4], [-1, 33, -1, 35, -1]], jnp.int32)
     kv2, sc2 = write_kv(kv, sc, k, v, slots, OPT_KV)
-    # (2, P, H, ps, D) -> flat token lines (2, P*ps, H, D)
-    flat = np.asarray(jnp.swapaxes(kv2, 2, 3).reshape(2, -1, 2, 16)
+    # (1, 2, P, H, ps, D) -> flat token lines (2, P*ps, H, D)
+    flat = np.asarray(jnp.swapaxes(kv2[0], 2, 3).reshape(2, -1, 2, 16)
                       .astype(jnp.float32))
     # skipped slots stay zero
     assert np.all(flat[:, 1] == 0) and np.all(flat[:, 3] == 0)
@@ -43,7 +44,7 @@ def test_write_then_gather_roundtrip_fp8():
     slots = identity_slots(2, jnp.broadcast_to(jnp.arange(5), (2, 5)), 8, 8)
     kv2, sc2 = write_kv(kv, sc, k, v, slots, OPT_KV)
     table = identity_page_table(2, 8)[:, :1]      # each lane's first page
-    out = gather_cached_kv(kv2, sc2, table, OPT_KV, dtype=jnp.float32)
+    out = gather_cached_kv(kv2[0], sc2[0], table, OPT_KV, dtype=jnp.float32)
     amax = float(np.abs(np.asarray(k)).max())
     np.testing.assert_allclose(np.asarray(out[0, :, :5]), np.asarray(k),
                                atol=amax * 2 ** -3)
@@ -55,7 +56,7 @@ def test_bf16_mode_is_exactish():
     slots = identity_slots(2, jnp.broadcast_to(jnp.arange(5), (2, 5)), 8, 8)
     kv2, _ = write_kv(kv, None, k, v, slots, co)
     table = identity_page_table(2, 8)[:, :1]
-    out = gather_cached_kv(kv2, None, table, co, dtype=jnp.float32)
+    out = gather_cached_kv(kv2[0], None, table, co, dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(out[0, :, :5]), np.asarray(k),
                                atol=0.01, rtol=0.01)
 
@@ -65,7 +66,7 @@ def test_gather_negative_pages_are_zero():
     slots = identity_slots(2, jnp.broadcast_to(jnp.arange(5), (2, 5)), 8, 8)
     kv2, sc2 = write_kv(kv, sc, k, v, slots, OPT_KV)
     table = jnp.array([[0, -1], [-1, 4]], jnp.int32)
-    out = np.asarray(gather_cached_kv(kv2, sc2, table, OPT_KV,
+    out = np.asarray(gather_cached_kv(kv2[0], sc2[0], table, OPT_KV,
                                       dtype=jnp.float32))
     ps = 8
     assert np.all(out[:, 0, ps:] == 0)            # lane 0, table slot 1 = -1
@@ -79,7 +80,7 @@ def test_shared_page_read_by_two_lanes():
     slots = jnp.broadcast_to(jnp.arange(5), (1, 5)).astype(jnp.int32)
     kv2, sc2 = write_kv(kv, sc, k[:1], v[:1], slots, OPT_KV)
     table = jnp.array([[0], [0]], jnp.int32)      # both lanes -> page 0
-    out = np.asarray(gather_cached_kv(kv2, sc2, table, OPT_KV,
+    out = np.asarray(gather_cached_kv(kv2[0], sc2[0], table, OPT_KV,
                                       dtype=jnp.float32))
     np.testing.assert_array_equal(out[:, 0], out[:, 1])
 

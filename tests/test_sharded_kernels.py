@@ -1,7 +1,9 @@
 """shard_map'd pooled Pallas kernels — ONE kernel hot path for single-host
 AND distributed serving (PR 5 acceptance).
 
-Covers: kernel-level parity of every sharded wrapper vs the jnp reference,
+Covers: kernel-level parity of every sharded wrapper vs the jnp reference
+(at one layer alone and at a nonzero layer of a pool of several, where the
+result must equal the same wrapper's on that layer alone bit for bit),
 engine-level greedy identity (dense AND mla) with ``use_kernel`` under a
 simulated multi-device mesh, the no-pool-all-gather HLO guarantee of the
 sharded step, the EngineConfig.num_shards <-> mesh consistency bugfix, and
@@ -47,6 +49,11 @@ def mesh():
 def _clear_ctx():
     yield
     ops.set_mesh_ctx(None)
+
+
+# (layers in the pool, layer attended): one layer alone, and a nonzero
+# layer of three
+LAYERS = [(1, 0), (3, 2)]
 
 
 def _sharded_pool(mesh, arr, pages_dim):
@@ -98,7 +105,7 @@ def test_configure_for_backend_composes_with_mesh_dispatch(monkeypatch):
     assert ops.interpret_mode() is False
     ctx = _sh.ShardCtx(mesh=None, axes=("data",), num_shards=2)  # dummy
     ops.set_mesh_ctx(ctx)
-    args = (jnp.zeros((1, 2, 4)), jnp.zeros((2, 4, 2, 2, 4)), None,
+    args = (jnp.zeros((1, 2, 4)), jnp.zeros((1, 2, 4, 2, 2, 4)), None, 0,
             jnp.zeros(1, jnp.int32), jnp.zeros((1, 2), jnp.int32),
             jnp.zeros((1, 2), jnp.int32))
     assert ops.paged_pool_decode(*args, opt_kv=False, opt_gqa=True) \
@@ -147,15 +154,17 @@ def test_engine_derives_num_shards_from_sharded_mesh(mesh):
 
 # ------------------------------------------------------ kernel-level parity --
 @needs_sharded_mesh
+@pytest.mark.parametrize("L,layer", LAYERS)
 @pytest.mark.parametrize("opt_kv_on", [False, True])
-def test_sharded_decode_kernel_matches_jnp_reference(mesh, opt_kv_on):
+def test_sharded_decode_kernel_matches_jnp_reference(mesh, opt_kv_on, L,
+                                                     layer):
     """The shard_map'd decode kernel (global table -> local holes, partial
     (m, l) lse-merged across the pages axis) matches the jnp gather
     reference on a pool whose pages are scattered across shards."""
     B, Hq, Hkv, D, ps, P_total = 2, 8, 4, 128, 8, 16
     coopt = COOPT.replace(opt_kv=opt_kv_on, use_kernel=False)
     kv = (jax.random.normal(jax.random.PRNGKey(1),
-                            (2, P_total, Hkv, ps, D), jnp.float32) * 0.3)
+                            (L, 2, P_total, Hkv, ps, D), jnp.float32) * 0.3)
     scale = None
     if opt_kv_on:
         from repro.cache.quant import quantize_fp8
@@ -163,18 +172,27 @@ def test_sharded_decode_kernel_matches_jnp_reference(mesh, opt_kv_on):
     q = jax.random.normal(jax.random.PRNGKey(0), (B, Hq, D), jnp.float32)
     cache_len = jnp.array([37, 90], jnp.int32)
     pt = opt_kv.identity_page_table(B, P_total)
-    ref = opt_pa.paged_decode_attention(q, kv, scale, cache_len,
+    ref = opt_pa.paged_decode_attention(q, kv, scale, layer, cache_len,
                                         coopt=coopt, page_table=pt)
 
     phys, log = opt_kv.decode_page_select(cache_len, pt, ps, opt_pa=True)
-    kv_sh = _sharded_pool(mesh, kv, 1)
-    sc_sh = _sharded_pool(mesh, scale, 1) if scale is not None else None
     ops.set_mesh_ctx(ops.make_mesh_ctx(mesh))
-    out = ops.paged_pool_decode(q, kv_sh, sc_sh, cache_len, phys, log,
-                                opt_kv=opt_kv_on, opt_gqa=True)
+
+    def decode(kv, scale, layer):
+        return ops.paged_pool_decode(
+            q, _sharded_pool(mesh, kv, 2),
+            _sharded_pool(mesh, scale, 2) if scale is not None else None,
+            layer, cache_len, phys, log, opt_kv=opt_kv_on, opt_gqa=True)
+
+    out = decode(kv, scale, layer)
     tol = 0.05 if opt_kv_on else 5e-3
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol)
+    if layer:
+        alone = decode(kv[layer:layer + 1],
+                       None if scale is None else scale[layer:layer + 1], 0)
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(alone, np.float32))
 
 
 @needs_sharded_mesh
@@ -190,23 +208,23 @@ def test_sharded_visit_grid_shard_local_and_matches_reference(mesh):
     from repro.cache.quant import quantize_fp8
     coopt = COOPT.replace(opt_kv=True, use_kernel=False)
     kv = (jax.random.normal(jax.random.PRNGKey(1),
-                            (2, P_total, Hkv, ps, D), jnp.float32) * 0.3)
+                            (1, 2, P_total, Hkv, ps, D), jnp.float32) * 0.3)
     kv, scale = quantize_fp8(kv, axis=-1)
     q = jax.random.normal(jax.random.PRNGKey(0), (B, Hq, D), jnp.float32)
     # prefix pages 0 and 9 shared by ALL lanes (they land in different
     # shards under the page-range partition); two private tail pages each
     pt = jnp.asarray([[0, 9, 2 + b, 12 + b] for b in range(B)], jnp.int32)
     cache_len = jnp.asarray([NP * ps - 3 * b for b in range(B)], jnp.int32)
-    ref = opt_pa.paged_decode_attention(q, kv, scale, cache_len,
+    ref = opt_pa.paged_decode_attention(q, kv, scale, 0, cache_len,
                                         coopt=coopt, page_table=pt)
 
     phys, log = opt_kv.decode_page_select(cache_len, pt, ps, opt_pa=True)
-    kv_sh = _sharded_pool(mesh, kv, 1)
-    sc_sh = _sharded_pool(mesh, scale, 1)
+    kv_sh = _sharded_pool(mesh, kv, 2)
+    sc_sh = _sharded_pool(mesh, scale, 2)
     ops.set_mesh_ctx(ops.make_mesh_ctx(mesh))
-    on = ops.paged_pool_decode(q, kv_sh, sc_sh, cache_len, phys, log,
+    on = ops.paged_pool_decode(q, kv_sh, sc_sh, 0, cache_len, phys, log,
                                opt_kv=True, opt_gqa=True, share_visits=True)
-    off = ops.paged_pool_decode(q, kv_sh, sc_sh, cache_len, phys, log,
+    off = ops.paged_pool_decode(q, kv_sh, sc_sh, 0, cache_len, phys, log,
                                 opt_kv=True, opt_gqa=True,
                                 share_visits=False)
     # near-exact vs the per-lane grid: the visit grid batches all lanes'
@@ -220,51 +238,65 @@ def test_sharded_visit_grid_shard_local_and_matches_reference(mesh):
 
 
 @needs_sharded_mesh
-def test_sharded_chunk_kernel_matches_jnp_reference(mesh):
+@pytest.mark.parametrize("L,layer", LAYERS)
+def test_sharded_chunk_kernel_matches_jnp_reference(mesh, L, layer):
     B, S, Hq, Hkv, D, ps, P_total = 2, 4, 8, 4, 128, 8, 16
     coopt = COOPT.replace(opt_kv=False, use_kernel=False)
     kv = (jax.random.normal(jax.random.PRNGKey(1),
-                            (2, P_total, Hkv, ps, D), jnp.float32) * 0.3)
+                            (L, 2, P_total, Hkv, ps, D), jnp.float32) * 0.3)
     q = jax.random.normal(jax.random.PRNGKey(3), (B, S, Hq, D), jnp.float32)
     positions = jnp.stack([jnp.arange(33, 37),
                            jnp.arange(86, 90)]).astype(jnp.int32)
     pt = opt_kv.identity_page_table(B, P_total)
-    ref = opt_pa.paged_chunk_attention(q, kv, None, positions, pt, coopt)
+    ref = opt_pa.paged_chunk_attention(q, kv, None, layer, positions, pt,
+                                       coopt)
 
     ops.set_mesh_ctx(ops.make_mesh_ctx(mesh))
-    out = ops.paged_chunk_prefill(q, positions, _sharded_pool(mesh, kv, 1),
-                                  None, pt, opt_kv=False, opt_gqa=True)
+    out = ops.paged_chunk_prefill(q, positions, _sharded_pool(mesh, kv, 2),
+                                  None, layer, pt, opt_kv=False,
+                                  opt_gqa=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=5e-3)
+    if layer:
+        alone = ops.paged_chunk_prefill(
+            q, positions, _sharded_pool(mesh, kv[layer:layer + 1], 2), None,
+            0, pt, opt_kv=False, opt_gqa=True)
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(alone, np.float32))
 
 
 @needs_sharded_mesh
-def test_sharded_write_stays_shard_local_and_drops_foreign_slots(mesh):
+@pytest.mark.parametrize("L,layer", LAYERS)
+def test_sharded_write_stays_shard_local_and_drops_foreign_slots(mesh, L,
+                                                                 layer):
     """The shard-local write scatters exactly the intended lines: no
     sentinel-line aliasing on mid-pool shards (a foreign/-1 slot is OOB-
-    dropped, never wrapped), matching the global jnp write bit-for-bit."""
+    dropped, never wrapped), matching the global jnp write bit-for-bit, and
+    every other layer keeps every byte."""
     B, Hkv, D, ps, P_total = 2, 4, 16, 8, 16
     kv = (jax.random.normal(jax.random.PRNGKey(1),
-                            (2, P_total, Hkv, ps, D), jnp.float32))
+                            (L, 2, P_total, Hkv, ps, D), jnp.float32))
     k_new = jnp.full((B, 1, Hkv, D), 7.0)
     v_new = jnp.full((B, 1, Hkv, D), 9.0)
     # one mid-pool slot + one SkipSet (-1) token
     slots = jnp.array([[37], [-1]], jnp.int32)
-    ref, _ = opt_kv.write_kv(kv, None, k_new, v_new, slots,
+    lines = opt_kv.pool_lines(slots, layer, P_total, ps)
+    ref, _ = opt_kv.write_kv(kv, None, k_new, v_new, lines,
                              COOPT.replace(opt_kv=False, use_kernel=False))
     ops.set_mesh_ctx(ops.make_mesh_ctx(mesh))
-    out, _ = ops.kv_cache_write(_sharded_pool(mesh, kv, 1), None,
-                                k_new, v_new, slots, opt_kv=False)
+    out, _ = ops.kv_cache_write(_sharded_pool(mesh, kv, 2), None,
+                                k_new, v_new, lines, opt_kv=False)
     # both writes DROP the -1 token: the pools match bit-for-bit, and no
     # line — the last one included — absorbed the skip
-    def lines(pool):        # (2, P, Hkv, ps, D) -> (2, P*ps, Hkv, D)
-        return np.asarray(pool).swapaxes(2, 3).reshape(2, P_total * ps,
+    def lines_of(pool):     # (L, 2, P, Hkv, ps, D) -> (L, 2, P*ps, Hkv, D)
+        return np.asarray(pool).swapaxes(3, 4).reshape(L, 2, P_total * ps,
                                                        Hkv, D)
-    o, r = lines(out), lines(ref)
+    o, r = lines_of(out), lines_of(ref)
     np.testing.assert_array_equal(o, r)
-    keep = np.ones(P_total * ps, bool)
-    keep[37] = False
-    np.testing.assert_array_equal(o[:, keep], lines(kv)[:, keep])
+    keep = np.ones((L, P_total * ps), bool)
+    keep[layer, 37] = False
+    np.testing.assert_array_equal(o.swapaxes(1, 2)[keep],
+                                  lines_of(kv).swapaxes(1, 2)[keep])
 
 
 # ---------------------------------------------------- engine greedy parity --

@@ -9,7 +9,9 @@ each lane's pages in the same ascending-slot order, so even the
 floating-point reduction order is unchanged), multi-resident-block chunk
 parity (block_q forcing NQ > 1 must not change results), and engine-level
 greedy identity with ``share_visits`` on vs off plus the sharing
-observability counters."""
+observability counters. At a nonzero layer of a pool of several layers the
+visit kernels also run on the whole pool, their index_maps picking the
+layer, bit-identical to the one-layer result of the ``ops`` wrappers."""
 import copy
 
 import jax
@@ -22,6 +24,8 @@ from repro.configs import get_config
 from repro.core.coopt import MODES
 from repro.core.opt_kv import identity_page_table
 from repro.kernels import ops, ref
+from repro.kernels.paged_gqa_decode import paged_pool_decode_visits
+from repro.kernels.paged_latent_decode import paged_latent_decode_visits
 from repro.kernels.visits import (MAX_VISIT_LANES, plan_visits,
                                   sharing_stats)
 from repro.serving import Engine, EngineConfig
@@ -40,17 +44,24 @@ def _shared_tables(B, P, shared):
     return jnp.asarray(phys), jnp.asarray(log), total
 
 
-def _gqa_inputs(B, P, shared, ps, Hkv, G, D, opt_kv, seed=0):
+# (layers in the pool, layer attended): one layer alone, and a nonzero
+# layer of three
+LAYERS = [(1, 0), (3, 2)]
+
+
+def _gqa_inputs(B, P, shared, ps, Hkv, G, D, opt_kv, seed=0, L=1):
+    """A pool of ``L`` layers, each with contents of its own."""
     phys, log, PT = _shared_tables(B, P, shared)
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, Hkv * G, D)).astype(jnp.bfloat16)
-    k = jax.random.normal(ks[1], (PT, Hkv, ps, D), jnp.float32)
-    v = jax.random.normal(ks[2], (PT, Hkv, ps, D), jnp.float32)
+    k = jax.random.normal(ks[1], (L, PT, Hkv, ps, D), jnp.float32)
+    v = jax.random.normal(ks[2], (L, PT, Hkv, ps, D), jnp.float32)
     if opt_kv:
         kq, ksc = quantize_fp8(k)
         vq, vsc = quantize_fp8(v)
-        return q, jnp.stack([kq, vq]), jnp.stack([ksc, vsc]), phys, log
-    return q, jnp.stack([k, v]).astype(jnp.bfloat16), None, phys, log
+        return (q, jnp.stack([kq, vq], 1), jnp.stack([ksc, vsc], 1), phys,
+                log)
+    return q, jnp.stack([k, v], 1).astype(jnp.bfloat16), None, phys, log
 
 
 # ------------------------------------------------------------ plan_visits --
@@ -107,23 +118,32 @@ def test_sharing_stats_counts_dup_streams():
 
 
 # ------------------------------------------------- GQA decode visit grid --
+@pytest.mark.parametrize("L,layer", LAYERS)
 @pytest.mark.parametrize("opt_kv", [False, True])
 @pytest.mark.parametrize("window", [0, 48])
 @pytest.mark.parametrize("lanes", [2, 8])
-def test_gqa_visit_parity_vs_oracle(opt_kv, window, lanes):
+def test_gqa_visit_parity_vs_oracle(opt_kv, window, lanes, L, layer):
     B, P, shared, ps, Hkv, G, D = lanes, 6, 4, 16, 2, 4, 64
-    q, kv, sc, phys, log = _gqa_inputs(B, P, shared, ps, Hkv, G, D, opt_kv)
+    q, kv, sc, phys, log = _gqa_inputs(B, P, shared, ps, Hkv, G, D, opt_kv,
+                                       L=L)
     # varied lengths across the sharing lanes: the positional mask is
     # per-member inside one shared visit
     cl = jnp.asarray(P * ps - 5 * np.arange(B), jnp.int32)
-    out = ops.paged_pool_decode(q, kv, sc, cl, phys, log, opt_kv=opt_kv,
-                                opt_gqa=True, window=window,
+    out = ops.paged_pool_decode(q, kv, sc, layer, cl, phys, log,
+                                opt_kv=opt_kv, opt_gqa=True, window=window,
                                 share_visits=True)
-    ks, vs = (sc[0], sc[1]) if sc is not None else (None, None)
-    exp = ref.paged_pool_decode_ref(q, kv[0], kv[1], ks, vs, cl, phys, log,
-                                    opt_kv=opt_kv, window=window)
+    ks, vs = (sc[layer, 0], sc[layer, 1]) if sc is not None else (None, None)
+    exp = ref.paged_pool_decode_ref(q, kv[layer, 0], kv[layer, 1], ks, vs,
+                                    cl, phys, log, opt_kv=opt_kv,
+                                    window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), atol=3e-2)
+    if layer:       # the index_maps pick the layer of the whole pool
+        whole = paged_pool_decode_visits(q, kv, sc, layer, cl,
+                                         *plan_visits(phys, log),
+                                         opt_kv=opt_kv, opt_gqa=True,
+                                         window=window, interpret=True)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(whole))
 
 
 @pytest.mark.parametrize("shared", [0, 4])
@@ -135,9 +155,9 @@ def test_gqa_visit_grid_bit_identical_to_per_lane(shared):
     q, kv, sc, phys, log = _gqa_inputs(B, P, shared, ps, Hkv, G, D,
                                        opt_kv=True)
     cl = jnp.asarray(P * ps - 7 * np.arange(B), jnp.int32)
-    off = ops.paged_pool_decode(q, kv, sc, cl, phys, log, opt_kv=True,
+    off = ops.paged_pool_decode(q, kv, sc, 0, cl, phys, log, opt_kv=True,
                                 opt_gqa=True, share_visits=False)
-    on = ops.paged_pool_decode(q, kv, sc, cl, phys, log, opt_kv=True,
+    on = ops.paged_pool_decode(q, kv, sc, 0, cl, phys, log, opt_kv=True,
                                opt_gqa=True, share_visits=True)
     np.testing.assert_array_equal(np.asarray(off), np.asarray(on))
 
@@ -150,37 +170,45 @@ def test_visit_dispatch_gate():
         q, kv, sc, phys, log = _gqa_inputs(B, P, 0, ps, Hkv, G, D,
                                            opt_kv=True, seed=2)
         cl = jnp.full((B,), P * ps, jnp.int32)
-        off = ops.paged_pool_decode(q, kv, sc, cl, phys, log, opt_kv=True,
-                                    opt_gqa=True, share_visits=False)
-        on = ops.paged_pool_decode(q, kv, sc, cl, phys, log, opt_kv=True,
+        off = ops.paged_pool_decode(q, kv, sc, 0, cl, phys, log,
+                                    opt_kv=True, opt_gqa=True,
+                                    share_visits=False)
+        on = ops.paged_pool_decode(q, kv, sc, 0, cl, phys, log, opt_kv=True,
                                    opt_gqa=True, share_visits=True)
         np.testing.assert_array_equal(np.asarray(off), np.asarray(on))
 
 
 # -------------------------------------------------- latent (MLA) visits --
+@pytest.mark.parametrize("L,layer", LAYERS)
 @pytest.mark.parametrize("opt_kv", [False, True])
 @pytest.mark.parametrize("window", [0, 48])
-def test_latent_visit_parity_vs_oracle(opt_kv, window):
+def test_latent_visit_parity_vs_oracle(opt_kv, window, L, layer):
     B, P, shared, ps, H, R, dr = 8, 6, 4, 16, 8, 64, 32
     phys, log, PT = _shared_tables(B, P, shared)
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     ql = jax.random.normal(ks[0], (B, H, R)).astype(jnp.bfloat16)
     qr = jax.random.normal(ks[1], (B, H, dr)).astype(jnp.bfloat16)
-    latf = jax.random.normal(ks[2], (PT, ps, R + dr), jnp.float32)
+    latf = jax.random.normal(ks[2], (L, PT, ps, R + dr), jnp.float32)
     if opt_kv:
         lat, sc = quantize_latent(latf, R)
     else:
         lat, sc = latf.astype(jnp.bfloat16), None
     cl = jnp.asarray(P * ps - 5 * np.arange(B), jnp.int32)
     sm = (R + dr) ** -0.5
-    out = ops.paged_latent_decode(ql, qr, lat, sc, cl, phys, log,
+    out = ops.paged_latent_decode(ql, qr, lat, sc, layer, cl, phys, log,
                                   sm_scale=sm, opt_kv=opt_kv, window=window,
                                   share_visits=True)
-    exp = ref.paged_latent_decode_ref(ql, qr, lat, sc, cl, phys, log,
-                                      sm_scale=sm, opt_kv=opt_kv,
+    exp = ref.paged_latent_decode_ref(ql, qr, lat[layer],
+                                      None if sc is None else sc[layer], cl,
+                                      phys, log, sm_scale=sm, opt_kv=opt_kv,
                                       window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), atol=3e-2)
+    if layer:       # the index_maps pick the layer of the whole pool
+        whole = paged_latent_decode_visits(
+            ql, qr, lat, sc, layer, cl, *plan_visits(phys, log), sm_scale=sm,
+            opt_kv=opt_kv, window=window, interpret=True)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(whole))
 
 
 @pytest.mark.parametrize("shared", [0, 4])
@@ -191,13 +219,13 @@ def test_latent_visit_grid_bit_identical_to_per_lane(shared):
     ql = jax.random.normal(ks[0], (B, H, R)).astype(jnp.bfloat16)
     qr = jax.random.normal(ks[1], (B, H, dr)).astype(jnp.bfloat16)
     lat, sc = quantize_latent(
-        jax.random.normal(ks[2], (PT, ps, R + dr), jnp.float32), R)
+        jax.random.normal(ks[2], (1, PT, ps, R + dr), jnp.float32), R)
     cl = jnp.asarray(P * ps - 7 * np.arange(B), jnp.int32)
     sm = (R + dr) ** -0.5
-    off = ops.paged_latent_decode(ql, qr, lat, sc, cl, phys, log,
+    off = ops.paged_latent_decode(ql, qr, lat, sc, 0, cl, phys, log,
                                   sm_scale=sm, opt_kv=True,
                                   share_visits=False)
-    on = ops.paged_latent_decode(ql, qr, lat, sc, cl, phys, log,
+    on = ops.paged_latent_decode(ql, qr, lat, sc, 0, cl, phys, log,
                                  sm_scale=sm, opt_kv=True,
                                  share_visits=True)
     np.testing.assert_array_equal(np.asarray(off), np.asarray(on))
@@ -223,18 +251,19 @@ def test_chunk_prefill_multi_resident_block_parity():
                           jnp.float32)
     kq, ksc = quantize_fp8(k)
     vq, vsc = quantize_fp8(v)
+    kv, sc = jnp.stack([kq, vq])[None], jnp.stack([ksc, vsc])[None]
     positions = jnp.stack([jnp.arange(0, 64),
                            jnp.arange(0, 64) // 2 + 32]).astype(jnp.int32)
     R = S * G
     # row groups are 128-aligned (Mosaic lane tiling of the positions block)
     assert resident_rows(R, G, 128) == 128 and R // 128 > 1  # forces NQ > 1
-    tiled = flash_chunk_prefill(q, positions, kq, vq, ksc, vsc, phys,
+    tiled = flash_chunk_prefill(q, positions, kv, sc, 0, phys,
                                 opt_kv=True, block_q=128, interpret=True)
-    whole = flash_chunk_prefill(q, positions, kq, vq, ksc, vsc, phys,
+    whole = flash_chunk_prefill(q, positions, kv, sc, 0, phys,
                                 opt_kv=True, interpret=True)
     np.testing.assert_array_equal(np.asarray(tiled), np.asarray(whole))
     exp = paged_chunk_attention(
-        q, jnp.stack([kq, vq]), jnp.stack([ksc, vsc]), positions, phys,
+        q, kv, sc, 0, positions, phys,
         CoOptConfig(opt_kv=True, opt_gqa=True, opt_pa=True))
     np.testing.assert_allclose(np.asarray(whole, np.float32),
                                np.asarray(exp, np.float32), atol=3e-2)
@@ -250,20 +279,20 @@ def test_latent_chunk_multi_resident_block_parity():
     ql = jax.random.normal(ks[0], (B, S, H, R)).astype(jnp.bfloat16)
     qr = jax.random.normal(ks[1], (B, S, H, dr)).astype(jnp.bfloat16)
     lat, sc = quantize_latent(
-        jax.random.normal(ks[2], (B * P, ps, R + dr), jnp.float32), R)
+        jax.random.normal(ks[2], (1, B * P, ps, R + dr), jnp.float32), R)
     positions = jnp.stack([jnp.arange(24, 28),
                            jnp.arange(60, 64)]).astype(jnp.int32)
     sm = (R + dr) ** -0.5
     RW = S * H
     assert resident_rows(RW, H, H) == H and RW // H > 1   # forces NQ > 1
-    tiled = latent_chunk_prefill(ql, qr, positions, lat, sc, phys,
+    tiled = latent_chunk_prefill(ql, qr, positions, lat, sc, 0, phys,
                                  sm_scale=sm, opt_kv=True, block_q=H,
                                  interpret=True)
-    whole = latent_chunk_prefill(ql, qr, positions, lat, sc, phys,
+    whole = latent_chunk_prefill(ql, qr, positions, lat, sc, 0, phys,
                                  sm_scale=sm, opt_kv=True, interpret=True)
     np.testing.assert_array_equal(np.asarray(tiled), np.asarray(whole))
-    exp = ref.latent_chunk_prefill_ref(ql, qr, positions, lat, sc, phys,
-                                       sm_scale=sm, opt_kv=True)
+    exp = ref.latent_chunk_prefill_ref(ql, qr, positions, lat[0], sc[0],
+                                       phys, sm_scale=sm, opt_kv=True)
     np.testing.assert_allclose(np.asarray(whole, np.float32),
                                np.asarray(exp, np.float32), atol=3e-2)
 
