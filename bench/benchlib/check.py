@@ -35,15 +35,16 @@ def sample(finished: List[Tuple[np.ndarray, List[int]]], count: int,
     return [longest] + [rest[j] for j in order[:max(count - 1, 0)]]
 
 
-def readings(W, arch, pairs: List[Tuple[np.ndarray, List[int]]],
+def readings(gaps, W, pairs: List[Tuple[np.ndarray, List[int]]],
              length: int, control: bool = False) -> Dict[str, float]:
-    """Run the reference over each (prompt, served tokens) pair padded to
-    ``length``. Returns the mean and the widest gap of a served token
-    (``mean_gap``, ``max_gap``), the share of served tokens that are not
-    the reference's top one (``flipped``), how many were compared, and with
-    ``control`` the same of the control's top tokens (``control_*``)."""
+    """Run the reference, ``gaps(W, tokens, served, lo, hi, control=...)``
+    (the cell's block's, with its ``arch`` bound), over each (prompt,
+    served tokens) pair padded to ``length``. Returns the mean and the
+    widest gap of a served token (``mean_gap``, ``max_gap``), the share of
+    served tokens that are not the reference's top one (``flipped``), how
+    many were compared, and with ``control`` the same of the control's top
+    tokens (``control_*``)."""
     import jax.numpy as jnp
-    from benchlib import reference
 
     worst, worst_c, n = 0.0, 0.0, 0
     tot, tot_c, flip, flip_c = 0.0, 0.0, 0, 0
@@ -57,8 +58,8 @@ def readings(W, arch, pairs: List[Tuple[np.ndarray, List[int]]],
         served = np.zeros(length, np.int32)
         lo, hi = len(prompt) - 1, len(seq) - 1
         served[lo:hi] = seq[len(prompt):]
-        g, gc = reference.gaps(W, jnp.asarray(inp), jnp.asarray(served),
-                               lo, hi, arch=arch, control=control)
+        g, gc = gaps(W, jnp.asarray(inp), jnp.asarray(served), lo, hi,
+                     control=control)
         g, gc = np.asarray(g[lo:hi]), np.asarray(gc[lo:hi])
         worst = max(worst, float(g.max()))
         tot += float(g.sum())

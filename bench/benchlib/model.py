@@ -9,23 +9,8 @@ import dataclasses
 
 import numpy as np
 
-# published config key -> the program's ModelConfig field
-PROGRAM_KEYS = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "head_dim",
-    "vocab_size": "vocab_size",
-    "rms_norm_eps": "norm_eps",
-    "rope_theta": "rope_theta",
-    "attention_bias": "qkv_bias",
-    "qk_norm": "qk_norm",
-}
+from benchlib.spec import SpecError
 
-NORMS = ("ln1", "ln2", "final_norm", "q_norm", "k_norm")
-BIASES = ("bq", "bk", "bv")
 # the program initialises norm scales to one and biases to zero; the
 # benchmark perturbs both so that a path which drops them shows
 NORM_NOISE = 0.1
@@ -33,15 +18,18 @@ BIAS_STD = 0.5
 EMBED_STD = 0.02
 
 
-def program_config(cfg: dict):
-    """The program's ``ModelConfig`` for ``cfg``, every size from the file."""
+def program_config(cfg: dict, block):
+    """The program's ``ModelConfig`` for ``cfg``, every size from the file:
+    each key of the block's ``PROGRAM_KEYS`` that the file has; a file
+    without one of the block's ``REQUIRED`` keys is refused."""
     from repro.configs import get_config
+    missing = [k for k in block.REQUIRED if k not in cfg]
+    if missing:
+        raise SpecError(f"{cfg['name']}: block {cfg['block']} needs "
+                        f"{', '.join(missing)}")
     base = get_config(cfg["program_arch"])
-    pc = base.replace(**{f: cfg[k] for k, f in PROGRAM_KEYS.items()})
-    if pc.family != "dense":
-        raise ValueError(f"{cfg['name']}: the harness serves dense GQA "
-                         f"decoders, not family {pc.family!r}")
-    return pc
+    return base.replace(**{f: cfg[k] for k, f in block.PROGRAM_KEYS.items()
+                           if k in cfg})
 
 
 def coopt_mode():
@@ -68,13 +56,15 @@ def _leaf_name(path) -> str:
     raise ValueError(f"leaf {path} has no name")
 
 
-def seeded_params(model, seed: int, tied: bool):
+def seeded_params(model, seed: int, tied: bool, block):
     """The model's weights, made on the device from ``seed`` in one jitted
     call, in the dtypes the program serves them in. Shapes come from
-    ``jax.eval_shape(model.init)``; each weight keeps the program's init
-    scale (normal, std 1/sqrt(fan-in); embedding std 0.02), norm scales
-    are 1 + N(0, 0.1^2) and biases N(0, 0.5^2). ``tied``: the output head
-    is the embedding's transpose, as a tied model's is."""
+    ``jax.eval_shape(model.init)``; leaf ``i`` draws one normal array from
+    ``fold_in(key, i)``. Each weight keeps the program's init scale
+    (normal, std 1/sqrt(fan-in); embedding std 0.02); the leaves the
+    block names in ``NORMS`` are 1 + N(0, 0.1^2) and in ``BIASES``
+    N(0, 0.5^2). ``tied``: the output head is the embedding's transpose,
+    as a tied model's is."""
     import jax
     import jax.numpy as jnp
 
@@ -84,7 +74,7 @@ def seeded_params(model, seed: int, tied: bool):
     specs = [s for _, s in flat]
 
     def gen(key):
-        out = {}
+        out = [None] * len(names)
         for i, (name, s) in enumerate(zip(names, specs)):
             if name == "lm_head" and tied:
                 continue
@@ -92,33 +82,28 @@ def seeded_params(model, seed: int, tied: bool):
                                   jnp.float32)
             if name == "embed":
                 x = z * EMBED_STD
-            elif name in NORMS:
+            elif name in block.NORMS:
                 x = 1.0 + NORM_NOISE * z
-            elif name in BIASES:
+            elif name in block.BIASES:
                 x = BIAS_STD * z
             elif name.startswith("w") or name == "lm_head":
                 x = z * (1.0 / np.sqrt(s.shape[-2]))
             else:
                 raise ValueError(f"no init rule for weight {name!r}")
-            out[name] = x.astype(s.dtype)
+            out[i] = x.astype(s.dtype)
         if tied:
-            out["lm_head"] = out["embed"].T
-        return jax.tree_util.tree_unflatten(treedef,
-                                            [out[n] for n in names])
+            out[names.index("lm_head")] = out[names.index("embed")].T
+        return jax.tree_util.tree_unflatten(treedef, out)
 
     key = jax.random.key(key_word(seed), impl="rbg")
     return jax.jit(gen)(key)
 
 
-def named_weights(params) -> dict:
-    """``{leaf name: array}`` of a dense model's weights (names are
-    unique there), the plain reference's view of them."""
+def weights_by_path(params) -> dict:
+    """``{path: array}`` of every leaf of the model's weights, the path's
+    keys joined by "/" (``embed``, ``segments/1/wq``): the plain
+    reference's view of them."""
     import jax
     flat, _ = jax.tree_util.tree_flatten_with_path(params)
-    out = {}
-    for p, x in flat:
-        n = _leaf_name(p)
-        if n in out:
-            raise ValueError(f"weight name {n!r} is not unique")
-        out[n] = x
-    return out
+    return {"/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                     for k in p): x for p, x in flat}

@@ -14,13 +14,13 @@ import gc
 import sys
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from typing import List, Optional
 
 from benchlib import check, spec, traffic
 from benchlib.driver import (SLOW_TURN_S, ClosedSource, Driver, OpenSource,
                              Record)
 from benchlib.peaks import chip_peaks
-from benchlib.work import Dims
 
 TRACE_S = 6.0          # traced seconds, the window's last ones
 FAILED = ("rejected", "error", "shed", "timed_out", "preemption_limit")
@@ -46,7 +46,7 @@ def check_devices(chips: int):
 class RunData:
     """What metric readers read (``bench/metrics/<name>.py``)."""
     cell: spec.Cell
-    dims: Dims
+    dims: object                  # the cell's block's Dims
     peaks: dict
     t0: float
     t1: float
@@ -57,6 +57,11 @@ class RunData:
     steps: list = field(default_factory=list)     # traced turns' steps
     trace_rows: Optional[list] = None
     trace: Optional[dict] = None                  # trace.reduce(...)
+
+    @property
+    def block(self):
+        """The cell's block module (``bench/blocks/<block>.py``)."""
+        return self.cell.block
 
     @property
     def window_s(self) -> float:
@@ -123,7 +128,6 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     arrival rate in place of the cell's (to find a cell's capacity)."""
     import jax
     from benchlib import model as bm
-    from benchlib.reference import Arch
     from benchlib.trace import Tracer, reduce, save_rows
     from repro.configs.base import CacheConfig
     from repro.models import get_model
@@ -133,17 +137,17 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     dev = devs[0]
     peaks = chip_peaks(dev.device_kind) if require_tpu else \
         chip_peaks("TPU v5 lite")
-    cfg, p, mix = cell.config, cell.params, cell.traffic
+    cfg, p, mix, block = cell.config, cell.params, cell.traffic, cell.block
     if traffic.max_context(mix) > p["max_len"]:
         raise spec.SpecError(f"{cell.name}: the mix reaches "
                              f"{traffic.max_context(mix)} tokens, more than "
                              f"max_len {p['max_len']}")
     t_ready = time.perf_counter()
 
-    pc = bm.program_config(cfg)
+    pc = bm.program_config(cfg, block)
     mdl = get_model(pc)
     tied = bool(cfg["tie_word_embeddings"])
-    params = jax.block_until_ready(bm.seeded_params(mdl, seed, tied))
+    params = jax.block_until_ready(bm.seeded_params(mdl, seed, tied, block))
     t_weights = time.perf_counter()
 
     ecfg = EngineConfig(num_lanes=p["lanes"], max_len=p["max_len"],
@@ -192,7 +196,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     del fe, engine, params, stats
     gc.collect()
 
-    run = RunData(cell=cell, dims=Dims.from_config(cfg), peaks=peaks,
+    run = RunData(cell=cell, dims=block.Dims.from_config(cfg), peaks=peaks,
                   t0=t0, t1=t1, setup_s=t0 - t_start, records=drv.records,
                   peak_pages=drv.peak_pages, pool_pages=drv.pool_pages,
                   steps=drv.steps)
@@ -231,9 +235,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     pairs += [in_flight[i] for i in
               check.sample(in_flight, limits.get("in_flight", 0), seed)]
     t_check = time.perf_counter()
-    W = bm.named_weights(bm.seeded_params(mdl, seed, tied))
-    got = check.readings(W, Arch.from_config(cfg), pairs, p["max_len"],
-                         control=control)
+    W = bm.weights_by_path(bm.seeded_params(mdl, seed, tied, block))
+    gaps = partial(block.gaps, arch=block.Arch.from_config(cfg))
+    got = check.readings(gaps, W, pairs, p["max_len"], control=control)
     del W
     gc.collect()
     _log(f"check: {len(pairs)} requests ({len(finished)} finished, "

@@ -6,10 +6,10 @@ cell's limit; the benchmark's own runs never run it.
 
 For each seed, in this one process: a run of the cell (its warm-in and a
 short window at its own load), then the reference over the sampled served
-requests. The control, the reference with every matrix product in float8
-e4m3, is read at each position of the same prompts and tokens, and its
-mean gap takes the program's place in the comparison that decides
-``correct``. One JSON line per seed: that ``correct``, the numbers compared
+requests. The control, the cell's block's reference one precision step
+down (the dense block's: every matrix product in float8 e4m3), is read at
+each position of the same prompts and tokens, and its mean gap takes the
+program's place in the comparison that decides ``correct``. One JSON line per seed: that ``correct``, the numbers compared
 beside their limits, and every reading (the program's ``mean_gap`` and
 ``max_gap``, the control's ``control_*``).
 """

@@ -1,7 +1,8 @@
 """Decode attention's share of its roofline: the least time the decoded
 tokens' attention needs at the chip's peaks (live fp8 K/V pages, their
-scales and the query, per layer; ``benchlib.work``) over the summed device
-time of the paged decode kernel in the trace.
+scales and the query, per layer, as the dense GQA block counts them:
+``bench/blocks/dense_gqa.py``) over the summed device time of the paged
+decode kernel in the trace.
 
 The work is that of the decode-only steps dispatched while the trace ran
 (decode lanes of steps that also carry a prompt chunk run through the
@@ -17,13 +18,13 @@ KERNELS = ("_paged_pool_decode_single",)
 def read(run):
     if run.trace_rows is None:
         return None
-    d = run.dims
+    blk, d = run.block, run.dims
     need = 0.0
     for s in run.steps:
         if s.kind != "decode" or not s.decode_ctx:
             continue
-        f = sum(work.attn_flops(d, c) for c in s.decode_ctx)
-        b = sum(work.decode_attn_bytes(d, c) for c in s.decode_ctx)
+        f = sum(blk.attn_flops(d, c) for c in s.decode_ctx)
+        b = sum(blk.decode_attn_bytes(d, c) for c in s.decode_ctx)
         need += d.layers * work.least_time(f, b, run.peaks)
     spent = kernel_seconds(run.trace_rows, KERNELS)
     if need <= 0 or spent <= 0:

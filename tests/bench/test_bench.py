@@ -24,13 +24,14 @@ BENCH = os.path.join(ROOT, "bench")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
-from benchlib import check, model, reference, runner, spec, stats, trace, \
-    traffic, work  # noqa: E402
+from benchlib import check, model, runner, spec, stats, trace, traffic, \
+    work  # noqa: E402
 from benchlib.driver import Record  # noqa: E402
 from benchlib.traffic import Req  # noqa: E402
 from repro.models import get_model  # noqa: E402
 
 SAMPLE_TRACE = os.path.join(BENCH, "testdata", "qwen3-4b.chat.trace.json.gz")
+DENSE = spec.load_block({"block": "dense_gqa"})
 SEED = 2 ** 31 + 17          # larger than 32 signed bits hold
 LIMIT = 0.003                # the small cell's mean_logit_gap (see below)
 MIN_TOKENS = 20
@@ -133,14 +134,15 @@ def test_control_fails_at_test_size():
     """The control (every matrix product in float8) read at each position
     of seeded sequences; its mean gap passes the limit."""
     cell = small_cell()
-    mdl = get_model(model.program_config(cell.config))
-    arch = reference.Arch.from_config(cell.config)
+    mdl = get_model(model.program_config(cell.config, DENSE))
+    arch = DENSE.Arch.from_config(cell.config)
     for seed in (1, 2, 3):
-        W = model.named_weights(model.seeded_params(mdl, seed, True))
+        W = model.weights_by_path(model.seeded_params(mdl, seed, True,
+                                                      DENSE))
         seq = np.random.default_rng(seed).integers(
             0, cell.config["vocab_size"], 256, dtype=np.int32)
-        g, gc = reference.gaps(W, jnp.asarray(seq), jnp.asarray(seq), 64,
-                               255, arch=arch, control=True)
+        g, gc = DENSE.gaps(W, jnp.asarray(seq), jnp.asarray(seq), 64, 255,
+                           arch=arch, control=True)
         assert float(jnp.mean(gc[64:255])) > LIMIT
 
 
@@ -174,9 +176,9 @@ def test_closed_loop_compares_in_flight_requests(monkeypatch):
     seen = []
     readings = check.readings
 
-    def spy(W, arch, pairs, length, control=False):
+    def spy(gaps, W, pairs, length, control=False):
         seen.extend(pairs)
-        return readings(W, arch, pairs, length, control=control)
+        return readings(gaps, W, pairs, length, control=control)
 
     monkeypatch.setattr(check, "readings", spy)
     res = runner.run_cell(cell, SEED, 6.0, False, time.perf_counter(),
@@ -215,7 +217,8 @@ def test_percentile_matches_hand_counts():
 
 def _run_data(records, t0=10.0, t1=20.0):
     cell = small_cell()
-    return runner.RunData(cell=cell, dims=work.Dims.from_config(cell.config),
+    return runner.RunData(cell=cell,
+                          dims=DENSE.Dims.from_config(cell.config),
                           peaks={"bf16_flops": 1e12, "hbm_bw": 1e9},
                           t0=t0, t1=t1, setup_s=12.5, records=records)
 
@@ -254,22 +257,22 @@ def test_client_side_metrics_match_hand_counts():
 
 # ------------------------------------------------------ work and peaks --
 def test_flop_and_byte_models_match_hand_counts():
-    d = work.Dims(layers=2, d_model=8, heads=4, kv_heads=2, head_dim=4,
-                  d_ff=16, vocab=32, page_size=4)
+    d = DENSE.Dims(layers=2, d_model=8, heads=4, kv_heads=2, head_dim=4,
+                   d_ff=16, vocab=32, page_size=4)
     # q,o: 8*16 each; k,v: 8*8 each; ffn 3*8*16 -> 2*(256+128+384)
-    assert work.layer_matmul_flops(d) == 1536
-    assert work.head_flops(d) == 2 * 8 * 32
-    assert work.attn_flops(d, 5) == 4 * 4 * 4 * 5
+    assert DENSE.layer_matmul_flops(d) == 1536
+    assert DENSE.head_flops(d) == 2 * 8 * 32
+    assert DENSE.attn_flops(d, 5) == 4 * 4 * 4 * 5
     # positions 3,4,5 attend 4,5,6 keys: 15 query-key pairs
-    assert work.chunk_attn_flops(d, 3, 3) == 4 * 4 * 4 * 15
-    assert work.prompt_flops(d, 3, 3) == 2 * (3 * 1536 + 960)
-    assert work.decode_flops(d, 5) == 2 * (1536 + 320) + 512
+    assert DENSE.chunk_attn_flops(d, 3, 3) == 4 * 4 * 4 * 15
+    assert DENSE.prompt_flops(d, 3, 3) == 2 * (3 * 1536 + 960)
+    assert DENSE.decode_flops(d, 5) == 2 * (1536 + 320) + 512
     # 5 keys -> 2 pages of 4 tokens; K and V: 2*2*4*2 heads*4 B fp8 + 4 B
     # scales per (token, head); query 4 heads * 4 * 2 B
-    assert work.decode_attn_bytes(d, 5) == 2 * 2 * 4 * 2 * 4 + \
+    assert DENSE.decode_attn_bytes(d, 5) == 2 * 2 * 4 * 2 * 4 + \
         2 * 2 * 4 * 2 * 4 + 4 * 4 * 2
     # chunk at 3..5: 6 keys -> 2 pages; plus queries read, outputs written
-    assert work.chunk_attn_bytes(d, 3, 3) == 2 * 2 * 4 * 2 * (4 + 4) + \
+    assert DENSE.chunk_attn_bytes(d, 3, 3) == 2 * 2 * 4 * 2 * (4 + 4) + \
         2 * 3 * 4 * 4 * 2
     peaks = {"bf16_flops": 100.0, "hbm_bw": 10.0}
     assert work.least_time(1000, 50, peaks) == 10.0

@@ -19,7 +19,7 @@ BENCH = os.path.join(ROOT, "bench")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
-from benchlib import runner, spec, trace, work  # noqa: E402
+from benchlib import runner, spec, trace  # noqa: E402
 from benchlib.driver import Driver  # noqa: E402
 from repro import serving  # noqa: E402
 from repro.serving import steplog  # noqa: E402
@@ -32,7 +32,8 @@ READERS = ("step_slot_use", "host_step_ms", "decode_step_ms")
 
 def _run(t0=10.0, t1=20.0, rows=None):
     cell = spec.load_cell("qwen3-4b.chat")
-    return runner.RunData(cell=cell, dims=work.Dims.from_config(cell.config),
+    return runner.RunData(cell=cell,
+                          dims=cell.block.Dims.from_config(cell.config),
                           peaks={"bf16_flops": 1e12, "hbm_bw": 1e9},
                           t0=t0, t1=t1, setup_s=1.0, records=[],
                           trace_rows=rows)
