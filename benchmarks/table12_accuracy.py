@@ -34,14 +34,14 @@ def _score_choices(model, params, coopt, contexts, choices):
     scores = np.zeros((n_items, n_choice))
     for c in range(n_choice):
         cache = model.init_cache(n_items, ctx_len + cho_len + 4, coopt)
-        logits, cache = model.prefill(params, {"tokens": jnp.asarray(contexts)},
+        logits, cache, _ = model.prefill(params, {"tokens": jnp.asarray(contexts)},
                                       cache, coopt)
         lp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
         tot = np.array(jnp.take_along_axis(
             lp, jnp.asarray(choices[:, c, :1]), axis=-1))[:, 0]
         for t in range(cho_len - 1):
             tok = jnp.asarray(choices[:, c, t:t + 1], jnp.int32)
-            logits, cache = model.decode_step(params, {"token": tok}, cache,
+            logits, cache, _ = model.decode_step(params, {"token": tok}, cache,
                                               coopt)
             lp = jax.nn.log_softmax(logits.astype(jnp.float32), -1)
             tot += np.asarray(jnp.take_along_axis(

@@ -34,7 +34,7 @@ def dense_block_sparse():
     for i in range(0, CTX, 512):
         pos = jnp.broadcast_to(jnp.arange(i, i + 512),
                                (B, 512)).astype(jnp.int32)
-        logits, cache = step(p, {"tokens": toks[:, i:i + 512],
+        logits, cache, _ = step(p, {"tokens": toks[:, i:i + 512],
                                  "positions": pos, "slot_idx": pos}, cache)
     print(f"prefilled {int(cache['length'][0])} tokens")
 
@@ -45,10 +45,10 @@ def dense_block_sparse():
     for name, fn in [("full-attention decode", dec_full),
                      ("block-sparse decode (window 256 + sink)", dec_win)]:
         c = jax.tree.map(lambda x: x, cache)
-        lg, c = fn(p, {"token": tok}, c)    # compile
+        lg, c, _ = fn(p, {"token": tok}, c)    # compile
         t0 = time.perf_counter()
         for _ in range(8):
-            lg, c = fn(p, {"token": jnp.argmax(lg, -1)[:, None]
+            lg, c, _ = fn(p, {"token": jnp.argmax(lg, -1)[:, None]
                            .astype(jnp.int32)}, c)
         lg.block_until_ready()
         dt = (time.perf_counter() - t0) / 8 * 1e3
@@ -63,10 +63,10 @@ def rwkv_constant_state():
     cache = m.init_cache(B, 0, COOPT)      # O(1) state: no pages at all
     dec = jax.jit(lambda p, b, c: m.decode_step(p, b, c, COOPT))
     tok = jnp.zeros((B, 1), jnp.int32)
-    lg, cache = dec(p, {"token": tok}, cache)
+    lg, cache, _ = dec(p, {"token": tok}, cache)
     t0 = time.perf_counter()
     for _ in range(16):
-        lg, cache = dec(p, {"token": jnp.argmax(lg, -1)[:, None]
+        lg, cache, _ = dec(p, {"token": jnp.argmax(lg, -1)[:, None]
                             .astype(jnp.int32)}, cache)
     lg.block_until_ready()
     dt = (time.perf_counter() - t0) / 16 * 1e3

@@ -14,8 +14,9 @@ Every assigned architecture is expressed as a frozen ``ModelConfig``.  The
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Mapping
 from dataclasses import dataclass
-from typing import Tuple
+from typing import Any, Tuple
 
 
 @dataclass(frozen=True)
@@ -37,6 +38,10 @@ class ModelConfig:
     attn_window: int = 0             # 0 = full causal; >0 sliding window
     sink_blocks: int = 1             # Opt-KV SkipSet: KV pages always kept
     rope_theta: float = 10000.0
+    # published ``rope_scaling`` as sorted (key, value) pairs (a dict given
+    # here is converted: the config is a static, hashable jit argument);
+    # () = plain rope. Latent attention reads YaRN from it (models/mla.py)
+    rope_scaling: Tuple[Tuple[str, Any], ...] = ()
 
     # -- MoE --------------------------------------------------------------
     num_experts: int = 0             # routed experts
@@ -44,6 +49,8 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0                # per-expert hidden dim
     first_dense_layers: int = 0      # leading layers with dense FFN
+    norm_topk_prob: bool = True      # divide the top-k router weights by
+                                     # their sum (False: use them as they are)
 
     # -- MLA (deepseek-v2) -------------------------------------------------
     kv_lora_rank: int = 0
@@ -69,6 +76,11 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        if isinstance(self.rope_scaling, Mapping):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
 
     # ------------------------------------------------------------------
     @property

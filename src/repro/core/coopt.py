@@ -30,10 +30,6 @@ class CoOptConfig:
     # exists (and for B == 1 or B > visits.MAX_VISIT_LANES). Kernel path
     # only; the jnp reference gathers per lane regardless.
     share_visits: bool = True
-    # MoE serving knob: expert capacity = ceil(S * top_k / E * cf). Decode
-    # (S=1) is inherently dropless; cf >= E/top_k makes prefill dropless too
-    # (exact teacher-forcing consistency) at proportional dispatch cost.
-    moe_capacity_factor: float = 1.25
 
     @property
     def kv_dtype(self):

@@ -46,6 +46,7 @@ from repro.kernels import flash_chunk_prefill as _fc
 from repro.kernels import flash_prefill as _fp
 from repro.kernels import kv_cache_write as _kw
 from repro.kernels import latent_chunk_prefill as _lc
+from repro.kernels import moe_experts as _me
 from repro.kernels import paged_gqa_decode as _pd
 from repro.kernels import paged_latent_decode as _ld
 from repro.kernels import sharded as _sh
@@ -374,3 +375,25 @@ def paged_chunk_prefill(q, positions, kv_pages, scale_pages, layer,
         q, positions, kv_pages, scale_pages, layer, phys_table, seg_q,
         page_seg, page_base, opt_kv=opt_kv, opt_gqa=opt_gqa, window=window,
         sink_pages=sink_pages, interpret=interpret_mode())
+
+
+@partial(jax.jit, static_argnames=("tm", "interpret"))
+def _moe_experts(xs, wg, wu, wd, layer, tile_expert, num_tiles, *, tm: int,
+                 interpret: bool):
+    return _me.moe_experts(xs, wg, wu, wd, layer, tile_expert, num_tiles,
+                           tm=tm, interpret=interpret)
+
+
+def moe_experts(xs, wg, wu, wd, layer, tile_expert, num_tiles, group_sizes,
+                *, tm: int):
+    """The routed experts' grouped SwiGLU over pairs sorted by expert
+    (``kernels.moe_experts``): xs (M, d); wg/wu (L, E, d, f), wd (L, E, f,
+    d) the stacked weights and ``layer`` the int32 layer to run;
+    tile_expert (M // tm,) the expert of each tile of ``tm`` rows,
+    num_tiles (1,) the tiles that carry pairs, group_sizes (E,) each
+    expert's rows. Under a mesh ctx the experts' weights are GSPMD-sharded,
+    so the ``ragged_dot`` form runs in the kernel's place."""
+    if _MESH_CTX is not None:
+        return _me.moe_experts_ref(xs, wg, wu, wd, layer, group_sizes)
+    return _moe_experts(xs, wg, wu, wd, layer, tile_expert, num_tiles, tm=tm,
+                        interpret=interpret_mode())

@@ -85,10 +85,10 @@ def first_step_logits(model, params, tokens: np.ndarray, max_len: int,
     prefill = jax.jit(partial(step, model.prefill))
     decode = jax.jit(partial(step, model.decode_step))
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    logits, cache = prefill(params, {
+    logits, cache, _ = prefill(params, {
         "tokens": jnp.asarray(tokens), "positions": pos,
         "cache_len": jnp.full((B,), S, jnp.int32)}, cache)
-    dlogits, _ = decode(params, {
+    dlogits, _, _ = decode(params, {
         "token": jnp.asarray(tokens[:, :1]),
         "positions": jnp.full((B, 1), S, jnp.int32),
         "cache_len": jnp.full((B,), S + 1, jnp.int32)}, cache)

@@ -231,7 +231,8 @@ def make_step(arch_id: str, shape_name: str, mesh: Mesh,
             from repro.kernels import ops
             with ops.mesh_ctx_scope(kctx), \
                     activation_sharding(mesh, act_rules):
-                return model.prefill(params, batch, cache, coopt)
+                logits, cache, _ = model.prefill(params, batch, cache, coopt)
+                return logits, cache
 
         return StepBundle(
             "prefill", prefill_step, (params_abs, batch_abs, cache_abs),
@@ -243,8 +244,9 @@ def make_step(arch_id: str, shape_name: str, mesh: Mesh,
         from repro.kernels import ops
         with ops.mesh_ctx_scope(kctx), \
                 activation_sharding(mesh, act_rules):
-            return model.decode_step(params, batch, cache, coopt,
-                                     long_window=lw)
+            logits, cache, _ = model.decode_step(params, batch, cache, coopt,
+                                                 long_window=lw)
+            return logits, cache
 
     return StepBundle(
         "decode", serve_step, (params_abs, batch_abs, cache_abs),

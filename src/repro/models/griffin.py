@@ -32,7 +32,8 @@ from repro.core.opt_kv import (identity_page_table, identity_slots,
                                kv_pool_shapes, pool_lines, write_kv)
 from repro.core.opt_pa import paged_chunk_attention, paged_decode_attention
 from repro.models.layers import (Spec, apply_rope, causal_attention, init_tree,
-                                 linear, repeat_kv, rmsnorm, shard_act)
+                                 linear, no_counters, repeat_kv, rmsnorm,
+                                 shard_act)
 
 _C = 8.0  # RG-LRU temperature
 
@@ -332,7 +333,7 @@ class GriffinModel:
                 h, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         else:
             h_last = h[:, -1]
-        return linear(h_last, params["lm_head"]), cache
+        return linear(h_last, params["lm_head"]), cache, no_counters()
 
     def decode_step(self, params, batch, cache, coopt: CoOptConfig = COOPT,
                     long_window: int = 0):
@@ -377,7 +378,7 @@ class GriffinModel:
                                      coopt, attn_fn)
         cache["length"] = new_len
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
-        return linear(h[:, 0], params["lm_head"]), cache
+        return linear(h[:, 0], params["lm_head"]), cache, no_counters()
 
     # ------------------------------------------------------------- caching --
     def cache_shape(self, batch: int, max_len: int, coopt: CoOptConfig,

@@ -20,6 +20,7 @@ from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 DEFAULT_RULES = {"d_in": "data", "d_out": "model", "vocab": "data",
@@ -125,6 +126,23 @@ def activation_sharding(mesh: Mesh, rules: dict):
         _ACT_CTX.reset(tok)
 
 
+def act_mesh_axes(axis: str, size: int):
+    """(mesh, mesh axes) that the logical activation ``axis`` maps to under
+    the active ``activation_sharding`` context, when the axes exist and
+    divide ``size``; None outside a context or when nothing shards it."""
+    ctx = _ACT_CTX.get()
+    if ctx is None:
+        return None
+    mesh, rules = ctx
+    m = rules.get(axis)
+    ms = (m,) if isinstance(m, str) else tuple(m or ())
+    ms = tuple(a for a in ms if a in mesh.shape)
+    n = math.prod(mesh.shape[a] for a in ms)
+    if n <= 1 or size % n:
+        return None
+    return mesh, ms
+
+
 def shard_act(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:
     ctx = _ACT_CTX.get()
     if ctx is None:
@@ -145,6 +163,12 @@ def shard_act(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:
         else:
             entries.append(None)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, PS(*entries)))
+
+
+def no_counters() -> jax.Array:
+    """The (2,) f32 expert counters a step program returns beside its
+    logits and cache, for a step without routed experts."""
+    return jnp.zeros(2, jnp.float32)
 
 
 # ----------------------------------------------------------------------------
@@ -183,18 +207,61 @@ def gelu_mlp(x, w1, b1, w2, b2):
 # ----------------------------------------------------------------------------
 # RoPE
 # ----------------------------------------------------------------------------
-def rope_angles(positions, head_dim, theta):
-    """positions (...,) -> cos/sin (..., head_dim/2)."""
+def yarn_get_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-temperature factor for a context stretched by
+    ``scale`` (1 when nothing is stretched)."""
+    return 0.1 * mscale * math.log(scale) + 1.0 if scale > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, scaling: dict) -> np.ndarray:
+    """YaRN's rotary frequencies (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``): per pair j of ``dim``, a blend of
+    the extrapolated ``base^(-2j/dim)`` and the interpolated one divided by
+    ``factor``, ramped linearly between the correction dims that
+    ``beta_fast`` and ``beta_slow`` rotations over the original context
+    give."""
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(rotations):
+        return (dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction_dim(scaling.get("beta_slow", 1))),
+               dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def rope_angles(positions, head_dim, theta, scaling=()):
+    """positions (...,) -> cos/sin (..., head_dim/2). ``scaling``: the
+    config's ``rope_scaling`` pairs; () is plain rope, ``type`` "yarn" is
+    YaRN (``yarn_inv_freq``, cos and sin times the ratio of the two
+    mscales)."""
     half = head_dim // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2.0 / head_dim))
+    if not scaling:
+        freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
+                                 * 2.0 / head_dim))
+        ang = positions.astype(jnp.float32)[..., None] * freqs
+        return jnp.cos(ang), jnp.sin(ang)
+    sc = dict(scaling)
+    if sc.get("type", sc.get("rope_type")) != "yarn":
+        raise ValueError(f"rope scaling {sc} is not supported (only yarn)")
+    freqs = jnp.asarray(yarn_inv_freq(head_dim, theta, sc))
     ang = positions.astype(jnp.float32)[..., None] * freqs
-    return jnp.cos(ang), jnp.sin(ang)
+    m = (yarn_get_mscale(sc["factor"], sc.get("mscale", 1.0))
+         / yarn_get_mscale(sc["factor"], sc.get("mscale_all_dim", 0.0)))
+    return jnp.cos(ang) * m, jnp.sin(ang) * m
 
 
-def apply_rope(x, positions, theta):
+def apply_rope(x, positions, theta, scaling=()):
     """x: (..., S, H, D); positions: (..., S) broadcastable."""
     d = x.shape[-1]
-    cos, sin = rope_angles(positions, d, theta)   # (..., S, d/2)
+    cos, sin = rope_angles(positions, d, theta, scaling)   # (..., S, d/2)
     cos = cos[..., None, :]                        # (..., S, 1, d/2)
     sin = sin[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -208,18 +275,20 @@ def apply_rope(x, positions, theta):
 # sequences into manageable chunks" strategy applied to prefill.
 # ----------------------------------------------------------------------------
 def causal_attention(q, k, v, *, window: int = 0, chunk_q: int = 256,
-                     causal: bool = True, q_offset=0):
+                     causal: bool = True, q_offset=0, scale=None):
     """q: (B,S,Hq,D)  k,v: (B,T,Hkv,D)  -> (B,S,Hq,D).
 
     Grouped (Opt-GQA Eq. 7/8): q heads are folded to (Hkv, G) and share each
     KV head. ``window>0`` = sliding-window (mixtral/griffin local attention).
     ``q_offset``: absolute position of q[0] (prefill continuation).
+    ``scale``: the score scale (default 1/sqrt(D)).
     """
     B, S, Hq, D = q.shape
     T, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = Hq // Hkv
     qg = q.reshape(B, S, Hkv, G, D)
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     kpos = jnp.arange(T)
 
     nchunks = max(S // chunk_q, 1)

@@ -31,9 +31,27 @@ import jax.numpy as jnp
 from repro.core.coopt import CoOptConfig
 from repro.cache.quant import dequantize_latent
 from repro.models.layers import (apply_rope, causal_attention, linear,
-                                 rmsnorm, shard_act)
+                                 rmsnorm, shard_act, yarn_get_mscale)
 
 _NEG = -1e30
+
+
+def mla_rope(x, positions, cfg):
+    """The rotary embedding of the rope part of queries and keys, with the
+    config's rope scaling (DeepSeek-V2: YaRN)."""
+    return apply_rope(x, positions, cfg.rope_theta, cfg.rope_scaling)
+
+
+def mla_softmax_scale(cfg) -> float:
+    """The score scale: 1/sqrt(nope + rope), times YaRN's
+    ``yarn_get_mscale(factor, mscale_all_dim)`` squared where the config
+    scales rope (DeepSeek-V2-Lite: 192^-0.5 * 1.2608^2 = 0.11472)."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    sc = dict(cfg.rope_scaling)
+    if sc.get("mscale_all_dim"):
+        m = yarn_get_mscale(sc["factor"], sc["mscale_all_dim"])
+        scale *= m * m
+    return scale
 
 
 def mla_project(x, p, cfg, positions):
@@ -44,12 +62,12 @@ def mla_project(x, p, cfg, positions):
     B, S, _ = x.shape
     q = linear(x, p["wq"]).reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = mla_rope(q_rope, positions, cfg)
 
     ckv = linear(x, p["w_dkv"])                      # (B,S,R+dr)
     c, k_rope = ckv[..., :R], ckv[..., R:]
     c = rmsnorm(c, p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = mla_rope(k_rope[:, :, None, :], positions, cfg)[:, :, 0]
     latent = jnp.concatenate([c, k_rope], axis=-1)
     return q_nope, q_rope, latent
 
@@ -65,8 +83,8 @@ def mla_full_attention(q_nope, q_rope, latent, p, cfg, *, window: int = 0):
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope[:, :, None, :], (B, S, H, dr))], axis=-1)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    o = causal_attention(q, k, v, window=window)     # (B,S,H,dn+dr->dv? no:)
-    return o                                          # (B,S,H,dv)
+    return causal_attention(q, k, v, window=window,
+                            scale=mla_softmax_scale(cfg))   # (B,S,H,dv)
 
 
 def _absorb_q(q_nope, p, cfg):
@@ -117,7 +135,7 @@ def mla_chunk_attention(q_nope, q_rope, lat_pages, scale_pages, layer,
     if page_table is None:
         from repro.core.opt_kv import identity_page_table
         page_table = identity_page_table(B, P_total)
-    scale = 1.0 / math.sqrt(dn + dr)
+    scale = mla_softmax_scale(cfg)
     q_lat = _absorb_q(q_nope, p, cfg)                  # (B,S,H,R)
 
     if coopt.use_kernel:
@@ -190,7 +208,7 @@ def mla_paged_decode(q_nope, q_rope, lat_pages, scale_pages, layer, cache_len,
         from repro.core.opt_kv import identity_page_table
         page_table = identity_page_table(B, P_total)
     P = page_table.shape[1]
-    scale = 1.0 / math.sqrt(dn + dr)
+    scale = mla_softmax_scale(cfg)
     # absorb W_uk into q: score_h(t) = <q_lat_h, c_t> + <q_rope_h, k_rope_t>
     q_lat = _absorb_q(q_nope, p, cfg)                  # (B,H,R)
 

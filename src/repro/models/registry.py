@@ -5,6 +5,8 @@ Every model exposes the same engine-facing protocol:
   forward(params, batch, coopt)                — teacher-forced logits (+aux)
   prefill(params, batch, cache, coopt)         — last-token logits + filled cache
   decode_step(params, batch, cache, coopt, long_window) — one-token step
+      — both return (logits, cache, counters): the step's (2,) f32 expert
+        counters (zeros for a model without routed experts)
   cache_shape(batch, max_len, coopt, num_shards=1) / init_cache(...)
       — num_shards pads the paged-KV pages axis so it tiles evenly over
         the mesh (pod, data) shards of the sharded pool

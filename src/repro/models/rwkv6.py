@@ -29,7 +29,8 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.coopt import CoOptConfig, COOPT
-from repro.models.layers import (Spec, init_tree, linear, rmsnorm, shard_act)
+from repro.models.layers import (Spec, init_tree, linear, no_counters,
+                                 rmsnorm, shard_act)
 
 _LORA = 64        # low-rank dim of the data-dependent decay MLP
 _CHUNK = 32       # chunked-scan length — bounds the (C,C,H,D) pairwise-decay
@@ -271,12 +272,12 @@ class RWKV6Model:
                 h, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         else:
             h_last = h[:, -1]
-        return linear(h_last, params["lm_head"]), cache
+        return linear(h_last, params["lm_head"]), cache, no_counters()
 
     def decode_step(self, params, batch, cache, coopt: CoOptConfig = COOPT,
                     long_window: int = 0):
         h, cache = self._run(params, batch["token"], cache)
-        return linear(h[:, 0], params["lm_head"]), cache
+        return linear(h[:, 0], params["lm_head"]), cache, no_counters()
 
     # ------------------------------------------------------------- caching --
     def cache_shape(self, batch: int, max_len: int, coopt: CoOptConfig,

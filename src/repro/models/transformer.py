@@ -23,8 +23,14 @@ from repro.core.opt_kv import (identity_page_table, identity_slots,
 from repro.core.opt_pa import paged_chunk_attention, paged_decode_attention
 from repro.models import mla as mla_mod
 from repro.models.layers import (Spec, apply_rope, causal_attention, init_tree,
-                                 linear, repeat_kv, rmsnorm, shard_act, swiglu)
+                                 linear, no_counters, repeat_kv, rmsnorm,
+                                 shard_act, swiglu)
 from repro.models.moe import moe_ffn
+
+
+# routed experts' weights: the serving layer scan hands them to the expert
+# kernel stacked, with the layer's index (``_scan_with_cache``)
+EXPERT_LEAVES = ("wg_e", "wu_e", "wd_e")
 
 
 def _pages(seq_len: int, page_size: int) -> int:
@@ -96,8 +102,8 @@ class TransformerModel:
                 # expert-parallel: experts -> "data" when divisible (else
                 # d_in takes it), ff -> model. (§Perf P1: un-sharding d and
                 # putting ff on (data, model) replicated the expert compute
-                # 100x — refuted; the fix that held is the activation
-                # constraints inside moe_ffn.)
+                # 100x — refuted; what holds is keeping each batch shard's
+                # tokens on it, moe_ffn's per-shard dispatch.)
                 wg_e=Spec((L, E, d, ff), ("layers", "experts", "moe_d_in",
                                           "d_out")),
                 wu_e=Spec((L, E, d, ff), ("layers", "experts", "moe_d_in",
@@ -131,15 +137,26 @@ class TransformerModel:
         return init_tree(key, self.param_specs())
 
     # -------------------------------------------------------------- layers --
-    def _attention_full(self, p, x, positions, coopt: CoOptConfig):
+    def _attention_full(self, p, x, positions, coopt: CoOptConfig,
+                        as_cached: bool = False):
         """Full-sequence attention (train/prefill). Returns (out, k, v) —
-        k/v are the per-token cache entries (None head-expanded)."""
+        k/v are the per-token cache entries (None head-expanded).
+        ``as_cached`` (prefill): MLA attends the latent as the pool holds
+        it (the fp8 round trip under Opt-KV), as chunked prefill and decode
+        read it, so a monolithic prefill attends what a chunked one does."""
         cfg = self.cfg
         B, S, _ = x.shape
         H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         if cfg.family == "mla":
             qn, qr, latent = mla_mod.mla_project(x, p, cfg, positions)
-            o = mla_mod.mla_full_attention(qn, qr, latent, p, cfg,
+            seen = latent
+            if as_cached and coopt.opt_kv:
+                from repro.cache.quant import (dequantize_latent,
+                                               quantize_latent)
+                seen = dequantize_latent(
+                    *quantize_latent(latent, cfg.kv_lora_rank),
+                    cfg.kv_lora_rank, dtype=latent.dtype)
+            o = mla_mod.mla_full_attention(qn, qr, seen, p, cfg,
                                            window=cfg.attn_window)
             out = linear(o.reshape(B, S, -1), p["wo"])
             return out, latent, None
@@ -181,7 +198,7 @@ class TransformerModel:
             dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
             q = linear(x, p["wq"]).reshape(B, 1, H, dn + dr)
             qn, qr = q[..., :dn], q[..., dn:]
-            qr = apply_rope(qr, positions, cfg.rope_theta)
+            qr = mla_mod.mla_rope(qr, positions, cfg)
             o = mla_mod.mla_paged_decode(
                 qn[:, 0], qr[:, 0], kv_c, sc_c, layer,
                 new_len, p, cfg, coopt, window=window,
@@ -214,15 +231,31 @@ class TransformerModel:
         k = apply_rope(k, positions, cfg.rope_theta)
         return k, v
 
-    def _ffn(self, p, x, kind, coopt: CoOptConfig = COOPT):
+    def _ffn(self, p, x, kind, valid=None, serving: bool = False):
+        """valid: (B,S) bool of the real tokens (the MoE routes only
+        those); None = every token. Under ``_scan_with_cache`` the experts
+        come stacked for the segment with ``p["expert_layer"]`` the layer's
+        index in it (``EXPERT_LEAVES``). ``serving`` steps run the experts
+        through the grouped kernel, the training forward through its
+        differentiable reference."""
         cfg = self.cfg
         if kind == "dense":
             return swiglu(x, p["wg"], p["wu"], p["wd"]), None
         shared = ((p["wg_s"], p["wu_s"], p["wd_s"])
                   if cfg.num_shared_experts else None)
         return moe_ffn(x, p["wr"], p["wg_e"], p["wu_e"], p["wd_e"],
-                       top_k=cfg.top_k, shared=shared,
-                       capacity_factor=coopt.moe_capacity_factor)
+                       top_k=cfg.top_k, norm_topk_prob=cfg.norm_topk_prob,
+                       layer=p.get("expert_layer"), valid=valid,
+                       shared=shared, kernel=serving)
+
+    @staticmethod
+    def _layer_counters(aux):
+        """(2,) f32 of one layer: the most tokens one expert received and
+        the experts that received any (zeros for a dense layer)."""
+        if aux is None:
+            return no_counters()
+        return jnp.stack([aux.expert_tokens_max,
+                          aux.experts_hit]).astype(jnp.float32)
 
     # ------------------------------------------------------------- forward --
     def _embed(self, params, batch):
@@ -254,12 +287,11 @@ class TransformerModel:
                                                positions, coopt)
                 hh = hh + a
                 f, aux = self._ffn(pl, rmsnorm(hh, pl["ln2"], cfg.norm_eps),
-                                   kind, coopt)
+                                   kind)
                 hh = shard_act(hh + f, ("batch", "seq", None))
-                aux_v = (jnp.zeros(3, jnp.float32) if aux is None
+                aux_v = (jnp.zeros(2, jnp.float32) if aux is None
                          else jnp.stack([aux.load_balance_loss,
-                                         aux.router_z_loss,
-                                         aux.dropped_fraction]))
+                                         aux.router_z_loss]))
                 return hh, aux_v
             body = jax.checkpoint(body)
             h, aux = jax.lax.scan(body, h, seg_params)
@@ -270,8 +302,7 @@ class TransformerModel:
             h = h[:, off:]
         logits = linear(h, params["lm_head"])
         aux = jnp.sum(jnp.concatenate(auxes, 0), axis=0)
-        return logits, {"load_balance": aux[0], "router_z": aux[1],
-                        "dropped": aux[2]}
+        return logits, {"load_balance": aux[0], "router_z": aux[1]}
 
     # ------------------------------------------------------------ caching --
     def cache_shape(self, batch: int, max_len: int, coopt: CoOptConfig,
@@ -340,29 +371,47 @@ class TransformerModel:
         (L,P,ps,R+dr) / (L,P,ps,2)) are never sliced per layer nor restacked.
         ``step_fn(h, layer_params, kv, sc, layer, kind)`` gets the full pool
         and the traced global layer index (``start + i`` across segments),
-        and returns ``(h, kv, sc)``: the kernels address the layer in place
-        and the write updates the carried pool in place. ``new_len`` (B,) is
+        and returns ``(h, kv, sc, counters)``: the kernels address the layer
+        in place and the write updates the carried pool in place;
+        ``counters`` is the layer's ``_layer_counters``. ``new_len`` (B,) is
         the per-lane token count after this step — supplied by the engine
-        (global slots carry no length info)."""
+        (global slots carry no length info). Returns ``(h, cache,
+        counters)``, the step's (2,) f32 counters: the most tokens one
+        expert received in any MoE layer, and the mean over MoE layers of
+        the experts that received any (zeros without MoE layers)."""
         start = 0
         kv, sc = cache["kv"], (cache["scale"] if coopt.opt_kv else None)
+        moe = []
         for seg_params, (count, kind) in zip(params["segments"],
                                              self._segments()):
-            def body(carry, xs, kind=kind):
-                hh, kv, sc = carry
-                pl, layer = xs
-                return step_fn(hh, pl, kv, sc, layer, kind), None
+            # the experts stay whole: the kernel picks the layer in place
+            whole = {k: seg_params[k] for k in EXPERT_LEAVES
+                     if k in seg_params}
+            scanned = {k: v for k, v in seg_params.items() if k not in whole}
 
-            layers = start + jnp.arange(count, dtype=jnp.int32)
-            (h, kv, sc), _ = jax.lax.scan(body, (h, kv, sc),
-                                          (seg_params, layers))
+            def body(carry, xs, kind=kind, whole=whole):
+                hh, kv, sc = carry
+                pl, layer, i = xs
+                if whole:
+                    pl = dict(pl, expert_layer=i, **whole)
+                hh, kv, sc, ctr = step_fn(hh, pl, kv, sc, layer, kind)
+                return (hh, kv, sc), ctr
+
+            local = jnp.arange(count, dtype=jnp.int32)
+            (h, kv, sc), ctr = jax.lax.scan(body, (h, kv, sc),
+                                            (scanned, start + local, local))
+            if kind == "moe":
+                moe.append(ctr)
             start += count
         cache = dict(cache)
         cache["kv"] = kv
         if coopt.opt_kv:
             cache["scale"] = sc
         cache["length"] = new_len
-        return h, cache
+        if not moe:
+            return h, cache, jnp.zeros(2, jnp.float32)
+        ctr = jnp.concatenate(moe, axis=0)
+        return h, cache, jnp.stack([jnp.max(ctr[:, 0]), jnp.mean(ctr[:, 1])])
 
     def _attention_chunk(self, p, x, positions, kv_c, sc_c, layer,
                          page_table, coopt, long_window: int = 0, seg_q=None,
@@ -386,7 +435,7 @@ class TransformerModel:
             dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
             q = linear(x, p["wq"]).reshape(B, S, H, dn + dr)
             qn, qr = q[..., :dn], q[..., dn:]
-            qr = apply_rope(qr, positions, cfg.rope_theta)
+            qr = mla_mod.mla_rope(qr, positions, cfg)
             o = mla_mod.mla_chunk_attention(
                 qn, qr, kv_c, sc_c, layer, positions, page_table, p, cfg,
                 coopt, window=window, sink_pages=cfg.sink_blocks,
@@ -415,7 +464,10 @@ class TransformerModel:
     def prefill(self, params, batch, cache, coopt: CoOptConfig = COOPT,
                 long_window: int = 0):
         """Full-prompt forward + cache population. Returns
-        (last-token logits (B,V), cache).
+        (last-token logits (B,V), cache, the step's (2,) f32 expert
+        counters (``_scan_with_cache``)). Only the tokens
+        ``batch["pad_mask"]`` marks (all without one) are routed to
+        experts.
 
         Chunked-prefill continuation (Sarathi-style / mixed decode+prefill
         step — the engine's ONE ragged step path): pass
@@ -459,6 +511,7 @@ class TransformerModel:
         seg_q = batch.get("seg_q")
         page_seg = batch.get("page_seg")
         page_base = batch.get("page_base")
+        valid = batch.get("pad_mask")
 
         def step(hh, pl, kv_c, sc_c, layer, kind):
             x = rmsnorm(hh, pl["ln1"], cfg.norm_eps)
@@ -473,32 +526,37 @@ class TransformerModel:
                                           page_base=page_base)
             else:
                 a, new_a, new_b = self._attention_full(pl, x, positions,
-                                                       coopt)
+                                                       coopt, as_cached=True)
                 kv_c, sc_c = self._write_layer(kv_c, sc_c, layer, new_a,
                                                new_b, slots, coopt)
             hh = hh + a
-            f, _ = self._ffn(pl, rmsnorm(hh, pl["ln2"], cfg.norm_eps), kind,
-                             coopt)
-            return shard_act(hh + f, ("batch", "seq", None)), kv_c, sc_c
+            f, aux = self._ffn(pl, rmsnorm(hh, pl["ln2"], cfg.norm_eps),
+                               kind, valid=valid, serving=True)
+            return (shard_act(hh + f, ("batch", "seq", None)), kv_c, sc_c,
+                    self._layer_counters(aux))
 
-        h, cache = self._scan_with_cache(params, cache, h, new_len, coopt,
-                                         step)
+        h, cache, ctr = self._scan_with_cache(params, cache, h, new_len,
+                                              coopt, step)
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
         last = batch.get("last_pos", jnp.full((B,), S - 1, jnp.int32))
         if last.ndim == 2:
             # packed rows sample SEVERAL columns per row (one per finished
             # segment): last (B, G) -> logits (B, G, V)
             h_last = jnp.take_along_axis(h, last[..., None], axis=1)
-            return linear(h_last, params["lm_head"]), cache
-        h_last = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
-        return linear(h_last, params["lm_head"]), cache
+        else:
+            h_last = jnp.take_along_axis(h, last[:, None, None],
+                                         axis=1)[:, 0]
+        logits = linear(h_last, params["lm_head"])
+        return logits, cache, ctr
 
     def decode_step(self, params, batch, cache, coopt: CoOptConfig = COOPT,
                     long_window: int = 0):
         """ONE token (B,1) against the paged cache. Returns (logits (B,V),
-        cache). The engine supplies ``positions``/``slot_idx``/``page_table``
-        /``cache_len``; direct callers fall back to the per-lane ``length``
-        leaf and the lane-identity pool partition."""
+        cache, the step's expert counters) as ``prefill``. The engine supplies ``positions``/``slot_idx``/
+        ``page_table``/``cache_len``; direct callers fall back to the
+        per-lane ``length`` leaf and the lane-identity pool partition. A
+        lane whose ``cache_len`` is 0 is idle: its token is not routed to
+        experts."""
         cfg = self.cfg
         h = params["embed"][batch["token"]].astype(jnp.bfloat16)  # (B,1,d)
         B = h.shape[0]
@@ -525,14 +583,16 @@ class TransformerModel:
                                        new_len, page_table, coopt,
                                        long_window)
             hh = hh + a
-            f, _ = self._ffn(pl, rmsnorm(hh, pl["ln2"], cfg.norm_eps), kind,
-                             coopt)
-            return hh + f, kv_c, sc_c
+            f, aux = self._ffn(pl, rmsnorm(hh, pl["ln2"], cfg.norm_eps),
+                               kind, valid=(new_len > 0)[:, None],
+                               serving=True)
+            return hh + f, kv_c, sc_c, self._layer_counters(aux)
 
-        h, cache = self._scan_with_cache(params, cache, h, new_len, coopt,
-                                         step)
+        h, cache, ctr = self._scan_with_cache(params, cache, h, new_len,
+                                              coopt, step)
         h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
-        return linear(h[:, 0], params["lm_head"]), cache
+        logits = linear(h[:, 0], params["lm_head"])
+        return logits, cache, ctr
 
     # -------------------------------------------------------------- specs --
     def input_specs(self, shape) -> Dict[str, jax.ShapeDtypeStruct]:

@@ -33,7 +33,8 @@ from repro.core.opt_kv import (identity_page_table, identity_slots,
 from repro.core.opt_pa import paged_chunk_attention, paged_decode_attention
 from repro.cache.quant import quantize_fp8, dequantize_fp8
 from repro.models.layers import (Spec, causal_attention, gelu_mlp, init_tree,
-                                 layernorm, linear, repeat_kv, shard_act)
+                                 layernorm, linear, no_counters, repeat_kv,
+                                 shard_act)
 
 _MAX_POS = 32768 * 2   # learned decoder positions (stress shapes included)
 
@@ -313,7 +314,7 @@ class WhisperModel:
                 h, last_pos[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         else:
             h_last = h[:, -1]
-        return linear(h_last, params["lm_head"]), cache
+        return linear(h_last, params["lm_head"]), cache, no_counters()
 
     def decode_step(self, params, batch, cache, coopt: CoOptConfig = COOPT,
                     long_window: int = 0):
@@ -332,7 +333,7 @@ class WhisperModel:
                                  long_window=long_window,
                                  page_table=batch.get("page_table"),
                                  cache_len=batch.get("cache_len"))
-        return linear(h[:, 0], params["lm_head"]), cache
+        return linear(h[:, 0], params["lm_head"]), cache, no_counters()
 
     # ------------------------------------------------------------- caching --
     def cache_shape(self, batch: int, max_len: int, coopt: CoOptConfig,

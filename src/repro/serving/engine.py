@@ -561,23 +561,27 @@ class Engine:
         # steps never touch it, so any increment after warmup IS a retrace
         self.trace_counts[kind] = self.trace_counts.get(kind, 0) + 1
 
-    def _prefill_impl(self, params, batch, cache, lane_mask):
+    def _run_model(self, fn, params, batch, cache):
+        """The model's step ``fn`` (its ``prefill`` or ``decode_step``):
+        (logits, new cache, the step's (2,) f32 expert counters: the most
+        tokens one expert received in any MoE layer and the mean over MoE
+        layers of the experts hit; zeros for a model without experts)."""
         from repro.kernels import ops
-        self._count_trace("prefill")
         with ops.mesh_ctx_scope(self._kernel_ctx):   # trace-scoped
-            logits, new_cache = self.model.prefill(
-                params, batch, cache, self.coopt,
-                long_window=self.ecfg.long_window)
-            return logits, self._mask_lanes(new_cache, cache, lane_mask)
+            return fn(params, batch, cache, self.coopt,
+                      long_window=self.ecfg.long_window)
+
+    def _prefill_impl(self, params, batch, cache, lane_mask):
+        self._count_trace("prefill")
+        logits, new_cache, ctr = self._run_model(self.model.prefill, params,
+                                                 batch, cache)
+        return logits, self._mask_lanes(new_cache, cache, lane_mask), ctr
 
     def _decode_impl(self, params, batch, cache, lane_mask):
-        from repro.kernels import ops
         self._count_trace("decode")
-        with ops.mesh_ctx_scope(self._kernel_ctx):   # trace-scoped
-            logits, new_cache = self.model.decode_step(
-                params, batch, cache, self.coopt,
-                long_window=self.ecfg.long_window)
-            return logits, self._mask_lanes(new_cache, cache, lane_mask)
+        logits, new_cache, ctr = self._run_model(self.model.decode_step,
+                                                 params, batch, cache)
+        return logits, self._mask_lanes(new_cache, cache, lane_mask), ctr
 
     def _prefill_packed_impl(self, params, batch, cache, lane_mask):
         """Packed rows are DECOUPLED from lanes (R != num_lanes), so no
@@ -586,14 +590,11 @@ class Engine:
         families is ``length``, which is restored from the input cache
         (the engine passes explicit ``cache_len`` every step, so the leaf
         is bookkeeping only)."""
-        from repro.kernels import ops
         self._count_trace("packed")
-        with ops.mesh_ctx_scope(self._kernel_ctx):   # trace-scoped
-            logits, new_cache = self.model.prefill(
-                params, batch, cache, self.coopt,
-                long_window=self.ecfg.long_window)
-            new_cache["length"] = cache["length"]
-            return logits, new_cache
+        logits, new_cache, ctr = self._run_model(self.model.prefill, params,
+                                                 batch, cache)
+        new_cache["length"] = cache["length"]
+        return logits, new_cache, ctr
 
     def _async_step_impl(self, params, batch, cache, lane_mask, lane_tok,
                          key, feed, row_lane, scatter_lane, *, kind: str):
@@ -601,7 +602,10 @@ class Engine:
         tokens, run the model, SAMPLE ON DEVICE, and scatter the sampled
         tokens back into the per-lane ``lane_tok`` feed — so the host can
         build and dispatch step N+1 from metadata alone while step N
-        executes, deferring the host sync to the emit worker."""
+        executes, deferring the host sync to the emit worker. Returns
+        ((sampled tokens, expert counters), new cache, lane_tok): the
+        counters (``_run_model``) come back with the tokens, in the same
+        host sync."""
         self._count_trace("async_" + kind)
         tok_key = "token" if kind == "decode" else "tokens"
         batch = dict(batch)
@@ -616,21 +620,16 @@ class Engine:
         t0 = jnp.where(feed == -1, lane_tok[row_lane],
                        jnp.where(feed >= 0, feed, t0))
         batch[tok_key] = batch[tok_key].at[:, 0].set(t0)
-        if kind == "decode":
-            logits, new_cache = self._decode_impl(params, batch, cache,
-                                                  lane_mask)
-        elif kind == "packed":
-            logits, new_cache = self._prefill_packed_impl(
-                params, batch, cache, lane_mask)
-        else:
-            logits, new_cache = self._prefill_impl(params, batch, cache,
-                                                   lane_mask)
+        body = {"decode": self._decode_impl,
+                "packed": self._prefill_packed_impl,
+                "prefill": self._prefill_impl}[kind]
+        logits, new_cache, ctr = body(params, batch, cache, lane_mask)
         sp = self.ecfg.sampling
         toks = sample(logits, key, temperature=sp.temperature,
                       top_k=sp.top_k, top_p=sp.top_p)
         lane_tok = lane_tok.at[scatter_lane].set(
             toks.reshape(-1).astype(jnp.int32), mode="drop")
-        return toks, new_cache, lane_tok
+        return (toks, ctr), new_cache, lane_tok
 
     def serve_step_decode(self, *args):
         return self._async_step_impl(*args, kind="decode")
@@ -1133,8 +1132,8 @@ class Engine:
               "packed": self._packed_fn}[sb.kind]
         traces = sum(self.trace_counts.values())
         t0 = time.perf_counter()
-        logits, self.cache = fn(self.params, sb.batch, self.cache,
-                                self._dev_const(sb.lane_mask))
+        logits, self.cache, _ = fn(self.params, sb.batch, self.cache,
+                                   self._dev_const(sb.lane_mask))
         t_dispatched = time.perf_counter()
         logits.block_until_ready()
         t_fetched = time.perf_counter()
@@ -1249,7 +1248,8 @@ class Engine:
         executable warmed up for this shape (zero traces in steady state);
         fall back to the jit path and count the miss. Books the step
         counters and logs the step (``sb.record``); its time is booked
-        when its tokens are applied."""
+        when its tokens are applied. Returns ((tokens, expert counters),
+        lane_tok), still on the device."""
         if self.faults is not None:
             self.faults.before_execute(sb)
         if self.ecfg.sampling.temperature > 0:

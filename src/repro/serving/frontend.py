@@ -20,8 +20,10 @@ exploits JAX's async dispatch to overlap them:
     (the scheduler's pool state advances at DISPATCH time, not emission
     time — ``Request.inflight`` tracks the gap).
   * The EMIT worker (background thread) owns the only host sync: it drains
-    the dispatch queue in device order, blocks on ``np.asarray(tokens)``,
-    and hands the host tokens back to the loop, which routes them to the
+    the dispatch queue in device order, blocks on
+    ``jax.device_get((tokens, counters))`` (the step's routing counters
+    come back in the same sync and go to its step-log record), and hands
+    the host tokens back to the loop, which routes them to the
     per-request stream queues ("detokenize/emit off the critical path").
   * ``warmup()`` AOT-compiles (``jax.jit(...).lower().compile()``) the
     async step executable for EVERY shape in the bucket lattice — prefill
@@ -99,6 +101,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
@@ -297,16 +300,19 @@ class AsyncEngine:
             item = self._emit_q.get()
             if item is None:
                 return
-            sb, toks_dev = item
+            sb, out_dev = item
             try:
                 faults = self.engine.faults
                 if faults is not None:
                     faults.on_emit()
                 with TraceAnnotation("serve.fetch"):
-                    toks = np.asarray(toks_dev)
-                # the record's one field this thread writes; the loop
-                # reads it only after taking the step off _done_q
-                sb.record.t_fetched = time.perf_counter()
+                    toks, ctr = jax.device_get(out_dev)
+                # the record's fields this thread writes; the loop reads
+                # them only after taking the step off _done_q
+                rec = sb.record
+                rec.t_fetched = time.perf_counter()
+                rec.expert_tokens_max = int(ctr[0])
+                rec.experts_hit = float(ctr[1])
                 self._done_q.put((sb, toks))
             except WorkerKilled:
                 return                  # silent death: the watchdog fires

@@ -13,10 +13,14 @@ where the host's time went around it, on ``time.perf_counter()``:
                     ``block_until_ready``)
   * ``apply_s``     token routing, retirement, pool stats  (``serve.apply``)
 
+and, for a model with routed experts, the two routing counters that the
+step program returns beside its sampled tokens (``expert_tokens_max``,
+``experts_hit``; set by the emit worker in the same host sync).
+
 The names in brackets are the ``jax.profiler.TraceAnnotation`` spans around
 the same phases, so a device trace and the log describe one timeline.
-Every field is a host value the loop already holds: writing a record never
-waits on the device.
+Every other field is a host value the loop already holds: writing a record
+never waits on the device.
 
 Records go to ``RECENT``, one bounded ring per process. It is process-wide
 on purpose, like a metrics registry: an exporter (the benchmark's metric
@@ -54,6 +58,12 @@ class StepRecord:
     dispatch_s: float = 0.0
     t_fetched: float = 0.0                  # 0.0 until the tokens arrive
     apply_s: float = 0.0
+    # routing counters the step program returns with its tokens (written
+    # with t_fetched; 0 for a model without experts, and in the sync loop)
+    expert_tokens_max: int = 0              # most tokens one expert took in
+                                            # any MoE layer
+    experts_hit: float = 0.0                # experts that took any token,
+                                            # mean over MoE layers
 
     @property
     def slots(self) -> int:

@@ -33,8 +33,7 @@ def loss_fn(model, params, batch, coopt: CoOptConfig,
         loss = loss + moe_lb_weight * aux["load_balance"] \
                     + moe_z_weight * aux["router_z"]
         metrics.update(load_balance=aux["load_balance"],
-                       router_z=aux["router_z"],
-                       dropped=aux.get("dropped", jnp.zeros(())))
+                       router_z=aux["router_z"])
     metrics["loss"] = loss
     return loss, metrics
 

@@ -64,10 +64,10 @@ def test_prefill_decode_roundtrip(arch):
     batch = _batch(m, cfg, B, S, jax.random.PRNGKey(1))
     batch.pop("labels", None)
     cache = m.init_cache(B, S + 4, COOPT)
-    logits, cache = m.prefill(p, batch, cache, COOPT)
+    logits, cache, _ = m.prefill(p, batch, cache, COOPT)
     assert logits.shape == (B, cfg.vocab_size)
     tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-    logits2, cache = m.decode_step(p, {"token": tok}, cache, COOPT)
+    logits2, cache, _ = m.decode_step(p, {"token": tok}, cache, COOPT)
     assert logits2.shape == (B, cfg.vocab_size)
     assert not np.isnan(np.asarray(logits2, np.float32)).any()
     # input_specs already folds the vlm patch prefix into S
@@ -88,11 +88,6 @@ def test_decode_consistency_with_forward(arch):
     full_tokens = batch["tokens"]
 
     coopt = COOPT
-    if cfg.num_experts:
-        # capacity-MoE drops are S-dependent; dropless capacity
-        # (cf >= E / top_k) makes teacher forcing == serving exactly
-        coopt = COOPT.replace(
-            moe_capacity_factor=float(cfg.num_experts) / cfg.top_k)
 
     fwd_logits, _ = m.forward(p, dict(batch), coopt)
 
@@ -100,7 +95,7 @@ def test_decode_consistency_with_forward(arch):
     pre["tokens"] = full_tokens[:, :-1]
     S_text = pre["tokens"].shape[1]
     cache = m.init_cache(B, S + 8, coopt)
-    pl_logits, cache = m.prefill(p, pre, cache, coopt)
+    pl_logits, cache, _ = m.prefill(p, pre, cache, coopt)
     # prefill last-token logits == forward logits at position S_text-1
     a = np.asarray(fwd_logits[:, S_text - 1], np.float32)
     b = np.asarray(pl_logits, np.float32)
@@ -109,7 +104,7 @@ def test_decode_consistency_with_forward(arch):
 
     # decode of the held-out token == forward logits at position S_text
     tok = full_tokens[:, -1:].astype(jnp.int32)
-    de_logits, _ = m.decode_step(p, {"token": tok}, cache, coopt)
+    de_logits, _, _ = m.decode_step(p, {"token": tok}, cache, coopt)
     a2 = np.asarray(fwd_logits[:, S_text], np.float32)
     b2 = np.asarray(de_logits, np.float32)
     np.testing.assert_allclose(a2, b2, atol=atol)

@@ -25,7 +25,7 @@ def test_chunked_equals_monolithic_prefill(arch, coopt):
                               cfg.vocab_size)
 
     mono_cache = m.init_cache(B, S + 8, coopt)
-    mono_logits, mono_cache = m.prefill(p, {"tokens": toks}, mono_cache,
+    mono_logits, mono_cache, _ = m.prefill(p, {"tokens": toks}, mono_cache,
                                         coopt)
 
     ch_cache = m.init_cache(B, S + 8, coopt)
@@ -34,7 +34,7 @@ def test_chunked_equals_monolithic_prefill(arch, coopt):
     for i in range(0, S, C):
         pos = jnp.broadcast_to(jnp.arange(i, i + C), (B, C)).astype(jnp.int32)
         slots = identity_slots(B, pos, P_total, coopt.page_size)
-        ch_logits, ch_cache = m.prefill(
+        ch_logits, ch_cache, _ = m.prefill(
             p, {"tokens": toks[:, i:i + C], "positions": pos,
                 "slot_idx": slots}, ch_cache, coopt)
 
@@ -49,8 +49,8 @@ def test_chunked_equals_monolithic_prefill(arch, coopt):
 
     # decode continues identically from either cache
     tok = jnp.argmax(mono_logits, -1)[:, None].astype(jnp.int32)
-    d1, _ = m.decode_step(p, {"token": tok}, mono_cache, coopt)
-    d2, _ = m.decode_step(p, {"token": tok}, ch_cache, coopt)
+    d1, _, _ = m.decode_step(p, {"token": tok}, mono_cache, coopt)
+    d2, _, _ = m.decode_step(p, {"token": tok}, ch_cache, coopt)
     np.testing.assert_allclose(np.asarray(d1, np.float32),
                                np.asarray(d2, np.float32), atol=atol)
 
@@ -67,16 +67,16 @@ def test_mixed_step_decode_lane_matches_pure_decode():
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
                               cfg.vocab_size)
     cache = m.init_cache(B, S + 8, coopt)
-    logits, cache = m.prefill(p, {"tokens": toks}, cache, coopt)
+    logits, cache, _ = m.prefill(p, {"tokens": toks}, cache, coopt)
     tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
 
     P_total = cache["kv"].shape[2]
     pos = jnp.full((B, 1), S, jnp.int32)
     slots = identity_slots(B, pos, P_total, coopt.page_size)
-    via_decode, _ = m.decode_step(
+    via_decode, _, _ = m.decode_step(
         p, {"token": tok, "positions": pos, "slot_idx": slots,
             "cache_len": jnp.full((B,), S + 1, jnp.int32)}, cache, coopt)
-    via_chunk, _ = m.prefill(
+    via_chunk, _, _ = m.prefill(
         p, {"tokens": tok, "positions": pos, "slot_idx": slots,
             "cache_len": jnp.full((B,), S + 1, jnp.int32),
             "last_pos": jnp.zeros((B,), jnp.int32)}, cache, coopt)
@@ -97,16 +97,16 @@ def test_mixed_step_decode_lane_matches_pure_decode_mla():
     toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
                               cfg.vocab_size)
     cache = m.init_cache(B, S + 8, coopt)
-    logits, cache = m.prefill(p, {"tokens": toks}, cache, coopt)
+    logits, cache, _ = m.prefill(p, {"tokens": toks}, cache, coopt)
     tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
 
     P_total = cache["kv"].shape[1]
     pos = jnp.full((B, 1), S, jnp.int32)
     slots = identity_slots(B, pos, P_total, coopt.page_size)
-    via_decode, _ = m.decode_step(
+    via_decode, _, _ = m.decode_step(
         p, {"token": tok, "positions": pos, "slot_idx": slots,
             "cache_len": jnp.full((B,), S + 1, jnp.int32)}, cache, coopt)
-    via_chunk, _ = m.prefill(
+    via_chunk, _, _ = m.prefill(
         p, {"tokens": tok, "positions": pos, "slot_idx": slots,
             "cache_len": jnp.full((B,), S + 1, jnp.int32),
             "last_pos": jnp.zeros((B,), jnp.int32)}, cache, coopt)

@@ -42,8 +42,8 @@ def _first_token_logits(pack, prompts, use_kernel=False):
     plan = eng.scheduler.schedule_step()
     sb = eng._build_step(plan)
     fn = eng._packed_fn if sb.kind == "packed" else eng._prefill_fn
-    logits, _ = fn(eng.params, sb.batch, eng.cache,
-                   eng._dev_const(sb.lane_mask))
+    logits, _, _ = fn(eng.params, sb.batch, eng.cache,
+                      eng._dev_const(sb.lane_mask))
     logits = np.asarray(logits)
     return {req.req_id: logits[idx] for req, _, idx in sb.samples}, sb
 

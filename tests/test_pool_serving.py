@@ -51,7 +51,7 @@ def test_prefix_cache_model_level_bit_identical_logits():
         slots = np.full((B, n), -1, np.int32)
         slots[lane] = np.asarray(
             identity_slots(B, jnp.asarray(pos), P_total, ps))[lane]
-        logits, cache = m.prefill(
+        logits, cache, _ = m.prefill(
             p, {"tokens": jnp.asarray(toks),
                 "positions": jnp.asarray(pos.astype(np.int32)),
                 "slot_idx": jnp.asarray(slots),

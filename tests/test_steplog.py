@@ -152,6 +152,38 @@ def test_sync_generate_logs_steps_too():
         assert r.schedule_s > 0 and r.build_s > 0 and r.apply_s > 0
 
 
+def test_steps_of_a_model_without_experts_count_none(served):
+    eng, _, _ = served
+    recs = _records(eng)
+    assert recs and all(r.expert_tokens_max == 0 and r.experts_hit == 0.0
+                        for r in recs)
+
+
+def test_step_log_holds_the_routing_counters_of_each_step():
+    """A model with routed experts: every step's record carries what its
+    step program counted, the most tokens one expert took (at least the
+    even share of the step's pairs, at most its tokens) and the experts
+    that took any."""
+    cfg = get_config("deepseek-v2-lite-16b-reduced")
+    eng = Engine(cfg, MODES["coopt"],
+                 EngineConfig(num_lanes=2, max_len=128, prefill_buckets=(32,),
+                              sampling=SamplingParams(temperature=0.0)))
+    fe = AsyncEngine(eng, warmup=False)
+    rng = np.random.default_rng(5)
+    for n in (20, 9):
+        fe.submit(rng.integers(0, cfg.vocab_size, n, dtype=np.int32),
+                  max_new_tokens=3)
+    fe.run_until_idle()
+    fe.close()
+    recs = _records(eng)
+    assert recs
+    E, K = cfg.num_experts, cfg.top_k
+    for r in recs:
+        assert -(-r.real_tokens * K // E) <= r.expert_tokens_max \
+            <= r.real_tokens, r
+        assert 1 <= r.experts_hit <= min(E, r.real_tokens * K), r
+
+
 # ------------------------------------------------- one name per kind ----
 @pytest.mark.parametrize("pack", [False, True])
 def test_each_step_kind_lowers_to_its_own_named_module(pack):

@@ -8,8 +8,9 @@ are passed.
 Widths: the dense pooled kernels at qwen3-4b (Hq 32, Hkv 8, D 128, page 64)
 in bf16 and fp8, each over a pool of several layers with the layer a traced
 scalar; ``flash_prefill`` at the same heads; the latent kernels at
-deepseek-v2-lite (H 16, kv_lora 512, rope 64); and one ``shard_map``'d
-paged decode over a 4-device described mesh.
+deepseek-v2-lite (H 16, kv_lora 512, rope 64), and its grouped experts (64
+of 1408 over d 2048); one ``shard_map``'d paged decode over a 4-device
+described mesh, and the MoE layer's per-batch-shard dispatch on one.
 
 The step guard compiles the dense model's decode step and chunked prefill
 at qwen3-4b widths (a few layers, an fp8 pool with scales, donated as the
@@ -28,6 +29,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
@@ -39,10 +41,12 @@ from repro.kernels import flash_chunk_prefill as fc
 from repro.kernels import flash_prefill as fp
 from repro.kernels import kv_cache_write as kw
 from repro.kernels import latent_chunk_prefill as lc
+from repro.kernels import moe_experts as me
 from repro.kernels import paged_gqa_decode as pd
 from repro.kernels import paged_latent_decode as ld
 from repro.kernels import sharded
 from repro.models import get_model
+from repro.models.moe import buffer_rows, tile_rows
 
 FP8 = jnp.float8_e4m3fn
 B, HQ, HKV, D, PS, POOL, NSEL, CHUNK = 8, 32, 8, 128, 64, 129, 8, 256
@@ -200,6 +204,24 @@ def test_latent_chunk_prefill_compiles(one_chip, dtype):
         s((2, NSEL), jnp.int32))
 
 
+@pytest.mark.parametrize("tokens", [32, 512], ids=["decode", "prefill"])
+def test_moe_experts_compiles(one_chip, tokens):
+    """The grouped SwiGLU experts at DeepSeek-V2-Lite's widths (64 experts
+    of 1408 over d 2048, six layers stacked, one picked by a scalar), in
+    the buffer a step of 32 decode tokens or 512 prompt tokens routes to,
+    top 6 each: an expert's three matrices, double buffered, fit the
+    kernel's VMEM at decode's and prefill's tile heights."""
+    s = _spec(one_chip)
+    L, E, d, f = 6, 64, 2048, 1408
+    tm = tile_rows(tokens * 6)
+    M = buffer_rows(tokens * 6, E, tm)
+    _compile(lambda xs, wg, wu, wd, lyr, te, nt: me.moe_experts(
+        xs, wg, wu, wd, lyr, te, nt, tm=tm),
+        s((M, d), jnp.bfloat16), s((L, E, d, f), jnp.bfloat16),
+        s((L, E, d, f), jnp.bfloat16), s((L, E, f, d), jnp.bfloat16),
+        s((), jnp.int32), s((M // tm,), jnp.int32), s((1,), jnp.int32))
+
+
 def test_sharded_paged_decode_compiles(topo):
     """The pages-sharded pool over four chips: the per-shard kernel and the
     log-sum-exp merge's collectives compile as one program."""
@@ -217,6 +239,47 @@ def test_sharded_paged_decode_compiles(topo):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text
+
+
+def test_moe_per_shard_on_described_mesh_gathers_one_layer(topo):
+    """DeepSeek-V2-Lite's MoE layer (64 experts of 1408 over d 2048, top 6
+    unrenormalised) on a (data 2, model 2) mesh of described chips under
+    the training activation rules, the experts of two layers stacked and
+    one picked: it compiles, each batch shard sorting and running its own
+    pairs. Its collectives gather that one layer's experts (ff stays on
+    ``model``) and the shard's sequence, and sum no more than the shard's
+    own tokens: none carries the whole batch's tokens or sorted buffer."""
+    from repro.launch.steps import ACT_RULES_SEQ
+    from repro.models.layers import activation_sharding
+    from repro.models.moe import moe_ffn
+
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(2, 2), ("data", "model"))
+    Bm, S, d, E, f = 4, 512, 2048, 64, 1408
+
+    def s(shape, spec):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def layer(x, wr, wg, wu, wd):
+        with activation_sharding(mesh, ACT_RULES_SEQ):
+            y, aux = moe_ffn(x, wr, wg, wu, wd, top_k=6,
+                             norm_topk_prob=False, layer=jnp.int32(1))
+        return y, aux.load_balance_loss
+
+    ew = P(None, "data", None, "model")
+    text = jax.jit(layer).lower(
+        s((Bm, S, d), P("data", "model", None)), s((d, E), P()),
+        s((2, E, d, f), ew), s((2, E, d, f), ew),
+        s((2, E, f, d), P(None, "data", "model", None))).compile().as_text()
+    ops = [(op, [int(v) for v in dims.split(",") if v]) for dims, op in
+           re.findall(r"= \w+\[([\d,]*)\]\S* (all-gather|all-reduce|"
+                      r"all-to-all|collective-permute|reduce-scatter)", text)]
+    assert ops and {op for op, _ in ops} <= {"all-gather", "all-reduce"}
+    for op, shape in ops:
+        if op == "all-gather" and len(shape) == 4:
+            assert shape[:2] == [1, E] and sorted(shape[2:]) == [f // 2, d]
+        else:
+            assert math.prod(shape) <= Bm // 2 * S * d, (op, shape)
 
 
 # ------------------------------------------------------------- step guard --

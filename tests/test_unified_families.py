@@ -110,7 +110,7 @@ def test_recurrent_state_threads_across_chunks(arch):
                               cfg.vocab_size)
 
     mono_cache = m.init_cache(B, S + 16, coopt)
-    mono_logits, mono_cache = m.prefill(p, {"tokens": toks}, mono_cache,
+    mono_logits, mono_cache, _ = m.prefill(p, {"tokens": toks}, mono_cache,
                                         coopt)
 
     ch_cache = m.init_cache(B, S + 16, coopt)
@@ -119,7 +119,7 @@ def test_recurrent_state_threads_across_chunks(arch):
     for i in range(0, S, C):
         pos = jnp.broadcast_to(jnp.arange(i, i + C), (B, C)).astype(jnp.int32)
         slots = identity_slots(B, pos, P_total, coopt.page_size)
-        ch_logits, ch_cache = m.prefill(
+        ch_logits, ch_cache, _ = m.prefill(
             p, {"tokens": toks[:, i:i + C], "positions": pos,
                 "slot_idx": slots,
                 "cache_len": jnp.full((B,), i + C, jnp.int32)},
@@ -227,22 +227,105 @@ def test_mixed_step_timing_attribution_and_latency_metrics():
 
 
 # ------------------------------------------------- MLA fused latent path --
+def _served_logits_and_routes(cfg, coopt, prompts, monkeypatch,
+                              forced=None):
+    """Serve ``prompts`` greedily (8 tokens each) and record, per step, the
+    logits of each sampled row at its sampled position and, per MoE layer,
+    every token's top-k experts and the router-logit gap between its k-th
+    and (k+1)-th expert (a spy around ``moe._moe_route``). ``forced``: the
+    tokens another run sampled, fed back step by step (teacher forcing), so
+    both runs see the same inputs at every step."""
+    import jax
+    import jax.numpy as jnp
+    from repro.models import moe
+
+    routes, steps = [], []
+    real = moe._moe_route
+
+    def route_spy(x, wr, valid, **kw):
+        lg = jnp.dot(x, wr, preferred_element_type=jnp.float32)
+        top, exp = jax.lax.top_k(lg, kw["top_k"] + 1)
+        jax.debug.callback(
+            lambda *a: routes.append(tuple(map(np.asarray, a))),
+            exp[:, :-1], top[:, -2] - top[:, -1], valid, ordered=True)
+        return real(x, wr, valid, **kw)
+
+    monkeypatch.setattr(moe, "_moe_route", route_spy)
+    eng = Engine(cfg, coopt, EngineConfig(num_lanes=2, max_len=256,
+                                          prefill_buckets=(16, 32, 64, 128)))
+    sample, post = eng._sample, eng._postprocess
+
+    def sample_spy(logits):
+        toks = sample(logits)
+        jax.effects_barrier()
+        steps.append({"logits": np.asarray(logits, np.float32),
+                      "toks": toks, "routes": list(routes)})
+        routes.clear()
+        return toks if forced is None else forced[len(steps) - 1]["toks"]
+
+    def post_spy(sb, toks, now):
+        tok = sb.batch["tokens" if "tokens" in sb.batch else "token"]
+        S = tok.shape[1]
+        last = np.asarray(sb.batch.get("last_pos",
+                                       np.full(tok.shape[0], S - 1)))
+        # (row, flat token index of the sampled position in the step)
+        steps[-1]["rows"] = [(i[0], i[0] * S + int(last[i[0]]))
+                             for _, _, i in sb.samples]
+        return post(sb, toks, now)
+
+    eng._sample, eng._postprocess = sample_spy, post_spy
+    out = eng.generate(prompts, max_new_tokens=8)
+    monkeypatch.setattr(moe, "_moe_route", real)
+    assert all(len(o) == 8 for o in out)
+    return steps
+
+
 @pytest.mark.parametrize("mode", ["coopt", "original"])
-def test_mla_engine_use_kernel_greedy_identical(mode):
+def test_mla_engine_use_kernel_greedy_identical(mode, monkeypatch):
     """End-to-end MLA serving through the fused latent Pallas kernels
     (absorbed decode + chunk prefill straight off the paged latent pool)
-    must be greedy-identical to the jnp parity reference — fp8 (coopt) and
-    bf16 (original), across multi-chunk prefill, prefix reuse and decode."""
+    must match the jnp parity reference — fp8 (coopt) and bf16 (original),
+    across multi-chunk prefill, prefix reuse and decode — with the
+    published dropless top-k routing. The kernel run is teacher-forced
+    with the reference's greedy tokens, so every step sees the same inputs,
+    and at each sampled row the two paths' logits agree within 0.1 and
+    their greedy tokens agree wherever the reference's top-1 margin is
+    clear. The two paths round differently in bf16, so where the router's
+    k-th and (k+1)-th experts nearly tie the kernel run may pick the other
+    one: that flip may happen only at such a near-tie, and the row whose
+    own token flipped is held to 1.0 instead (a flipped expert moves a
+    logit by ~0.5 here; a softmax scale off by 10% moves every row's
+    logits by 0.3-1.1, one without the YaRN mscale by 2-4)."""
     cfg = _cfg("deepseek-v2-lite-16b")
     prompts = [_prompt(cfg, 100, seed=21), _prompt(cfg, 45, seed=22)]
-    outs = []
-    for uk in (False, True):
-        eng = Engine(cfg, MODES[mode].replace(use_kernel=uk),
-                     EngineConfig(num_lanes=2, max_len=256,
-                                  prefill_buckets=(16, 32, 64, 128)))
-        outs.append(eng.generate(prompts, max_new_tokens=8))
-        assert all(len(o) == 8 for o in outs[-1])
-    assert outs[0] == outs[1]
+    ref = _served_logits_and_routes(cfg, MODES[mode], prompts, monkeypatch)
+    ker = _served_logits_and_routes(
+        cfg, MODES[mode].replace(use_kernel=True), prompts, monkeypatch,
+        forced=ref)
+    assert len(ker) == len(ref) == 9
+    checked = flipped = 0
+    for r, k in zip(ref, ker):
+        assert k["rows"] == r["rows"]
+        assert len(k["routes"]) == len(r["routes"]) > 0
+        flips = np.zeros(len(r["routes"][0][0]), bool)
+        for (e_r, gap, valid), (e_k, _, _) in zip(r["routes"], k["routes"]):
+            moved = valid & np.any(np.sort(e_r, -1) != np.sort(e_k, -1), -1)
+            # a token's first flip is at a near-tie (the paths' gaps differ
+            # by < 0.01); after it, its later layers see another input
+            assert np.all(gap[moved & ~flips] < 0.05), gap[moved & ~flips]
+            flips |= moved
+        for row, tok in r["rows"]:
+            a, b = r["logits"][row], k["logits"][row]
+            if flips[tok]:
+                flipped += 1
+                assert np.max(np.abs(a - b)) < 1.0
+                continue
+            checked += 1
+            assert np.max(np.abs(a - b)) < 0.1
+            top2 = np.sort(a)[-2:]
+            if top2[1] - top2[0] > 0.2:
+                assert a.argmax() == b.argmax()
+    assert checked >= 14 and checked + flipped == 16
 
 
 def test_mla_engine_use_kernel_windowed_greedy_identical():
